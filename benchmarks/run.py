@@ -28,6 +28,7 @@ import argparse
 import inspect
 import json
 import os
+import pathlib
 import sys
 import time
 
@@ -661,6 +662,10 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     if args.json is None:
         args.json = "" if args.only else "BENCH_platform.json"
+
+    from repro.platform.compile_cache import enable_compile_cache
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1]
+                         / ".jax_cache")
 
     from benchmarks import (bench_approx, bench_balance, bench_cache,
                             bench_elasticity, bench_faults, bench_hetero,

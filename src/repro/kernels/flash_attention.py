@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -82,7 +81,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bh, sq, hd = q.shape
     _, skv, _ = k.shape
@@ -109,7 +108,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),       # l (running denom)
             pltpu.VMEM((bq, hd), jnp.float32),    # acc
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, chunk: int):
@@ -51,7 +50,7 @@ def rglru_scan(
     *,
     chunk: int = 128,
     width_block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bsz, s, w = a.shape
     chunk = min(chunk, s)
@@ -67,7 +66,7 @@ def rglru_scan(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((wb,), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

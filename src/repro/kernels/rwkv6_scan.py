@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
@@ -74,7 +73,7 @@ def rwkv6_chunked(
     u: jax.Array,             # [H, hd]
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, s, hd = r.shape
     chunk = min(chunk, s)
@@ -90,7 +89,7 @@ def rwkv6_chunked(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u)

@@ -1,33 +1,36 @@
 """Subsample-gather kernels (Pallas, TPU target) — the paper's map task.
 
 Random-subsample statistics need ``rows = data[indices]; stats(rows)`` where
-``indices`` are random (the cache-hostile pattern of thesis Fig 2).  Two
-TPU-native adaptations live here:
+``indices`` are random (the cache-hostile pattern of thesis Fig 2).
 
-``subsample_gather`` — **scalar prefetch**
-(``pltpu.PrefetchScalarGridSpec``): the index vector is available to the
-BlockSpec ``index_map`` *before* the grid runs, so the pipeline issues the
-HBM→VMEM DMA for row ``indices[i+1]`` while row ``indices[i]`` is being
-reduced — exactly the thesis' "prefetch data for the next k tasks while the
-current task executes" (§3.5), with the Pallas pipeline playing the role of
-the two-phase scheduler's queue.  Each grid step is a tiny task: one
-gathered row, reduced into VMEM-resident accumulators (sum, sum of squares)
-that persist across the sequential grid; the final step writes the
-``[2, D]`` statistics block.  A scalar ``n_valid`` masks trailing padded
-indices out of the accumulator so the caller can round the index count up
-(one compiled kernel serves every draw count).
+``subsample_stats_wave`` — the platform's kernel, serving the ``pallas``
+engine of the ``moments`` statistic.  It batches a whole *wave* of tasks
+behind one leading grid dimension and returns only the per-task
+statistics (Σrow, Σrow²): the gathered rows are never written back.
+Its data live in the **lane layout** ``[S, N, L, 128]``: each padded row
+of ``W = 128·L`` values is stored as ``L`` lane rows of 128, so a drawn
+row is a slice of the leading, untiled ``N`` dimension and its DMA is
+tile-aligned (a ``[1, W]`` slice of a ``[N, W]`` array is not: Mosaic
+tiles the last two dimensions by (8, 128)).  The platform's block arena
+is packed in this layout (:func:`to_lane_layout`) and the wave's slot
+vector is a prefetched scalar, so a wave reads the drawn rows straight
+out of the arena, with no relayout and no gathered copy of its blocks.
+Each grid step DMAs ``ROWS_PER_STEP`` drawn
+rows HBM→VMEM back-to-back and folds them into the output block, which
+stays resident across the step axis.  A row wider than the VMEM budget
+is split over a column-block grid axis (:func:`column_block`); every
+column still sees its rows in index order, so a task's result does not
+depend on the wave it rides in or on the column split — a wave is
+bit-identical to the same tasks run as waves of one.
 
-``subsample_stats_wave`` — the **stats-only wave variant**: statistics
-consumers (the ``moments`` map engine) immediately discard the ``[T, D]``
-gathered array, so this kernel never writes it — pure HBM write bandwidth
-saved.  It gathers ``rows_per_step`` rows per grid step with explicit
-HBM→VMEM DMAs issued back-to-back (fewer, larger transfers in flight at
-once) and batches a whole *wave* of tasks behind one leading grid
-dimension: ``data [B, N, D]`` + ``indices [B, T]`` → ``stats [B, 2, D]``,
-one device dispatch for B map tasks.  Per-task accumulation order is
-independent of B, so a wave is bit-identical to B separate calls.
+``subsample_gather`` — the gathered-output variant (scalar-prefetch
+``PrefetchScalarGridSpec`` row pipeline writing ``[T, D]`` plus the
+statistics).  Its ``(1, D)`` blocks break Mosaic's (8, 128) block rule,
+so it runs in interpret mode only; the platform never calls it.
 
-Validated in interpret mode against ``ref.subsample_stats_ref``.
+Both are validated in interpret mode against ``ref.subsample_stats_ref``
+and :func:`subsample_stats_wave` compiles for TPU v5e at the platform's
+widths (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -36,6 +39,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -66,7 +70,7 @@ def subsample_gather(
     indices: jax.Array,       # [T] int32 random row ids (may be padded)
     n_valid: jax.Array,       # [1] int32: only indices[:n_valid] accumulate
     *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Returns (gathered [T, D], stats [2, D]) with stats = (Σrow, Σrow²)
     over the first ``n_valid`` rows.  Rows past ``n_valid`` are still
@@ -100,84 +104,131 @@ def subsample_gather(
     )(indices, n_valid, data)
 
 
-def _stats_wave_kernel(idx_ref, data_ref, stats_ref, acc_ref, rows_ref,
-                       sems, *, rows_per_step: int, n_idx: int, steps: int):
+LANES = 128                  # last-dimension tile of a TPU vreg
+SUBLANES = 8                 # second-to-last tile of a float32 vreg
+ROWS_PER_STEP = 8            # drawn rows DMA'd per grid step
+# VMEM for one grid step's drawn-row buffer; the output block (double
+# buffered, 2 × 2 × block) adds half as much again, which keeps a step
+# well inside v5e's 16 MiB default scoped VMEM
+ROW_BUFFER_BYTES = 4 << 20
+
+
+def column_block(lane_rows: int) -> int:
+    """Lane rows per column block: the whole row when ``ROWS_PER_STEP``
+    of them fit :data:`ROW_BUFFER_BYTES`, else the largest multiple of
+    the sublane tile that does."""
+    fit = ROW_BUFFER_BYTES // (ROWS_PER_STEP * LANES * 4)
+    if lane_rows <= fit:
+        return lane_rows
+    return fit // SUBLANES * SUBLANES
+
+
+def lane_width(d: int) -> int:
+    """Columns a row of ``d`` values occupies in the lane layout: ``d``
+    rounded up to whole lane rows, and to whole column blocks when the
+    row is split (a no-op for the power-of-two widths the platform
+    pads to)."""
+    rows = -(-d // LANES)
+    block = column_block(rows)
+    return -(-rows // block) * block * LANES
+
+
+def to_lane_layout(x):
+    """``[..., D]`` → ``[..., L, 128]``, zero-padding columns up to
+    :func:`lane_width`.  Works on NumPy and JAX arrays alike; the padded
+    columns' statistics are sliced off by the caller."""
+    d = x.shape[-1]
+    w = lane_width(d)
+    if w != d:
+        pad = [(0, 0)] * (x.ndim - 1) + [(0, w - d)]
+        x = jnp.pad(x, pad) if isinstance(x, jax.Array) else np.pad(x, pad)
+    return x.reshape(*x.shape[:-1], w // LANES, LANES)
+
+
+def _stats_wave_kernel(slots_ref, idx_ref, data_ref, stats_ref, rows_ref,
+                       sems, *, n_idx: int, block: int, split: bool,
+                       chunk: int):
     b = pl.program_id(0)                            # task within the wave
-    s = pl.program_id(1)                            # row group within task
+    c = pl.program_id(1)                            # column block
+    s = pl.program_id(2)                            # row group within task
 
     @pl.when(s == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        stats_ref[...] = jnp.zeros_like(stats_ref)
 
-    # R explicit HBM→VMEM row DMAs issued back-to-back, then awaited: the
-    # copies are all in flight at once (fewer, larger transfer windows than
-    # the one-row-per-step pipeline) while ``data`` itself never leaves HBM
+    # the drawn row of the task's arena slot is a leading-dimension slice
+    # ([L, 128], tile-aligned); a split row takes column block c of it
     def row_dma(j: int):
-        return pltpu.make_async_copy(
-            data_ref.at[b, pl.ds(idx_ref[b, s * rows_per_step + j], 1), :],
-            rows_ref.at[pl.ds(j, 1), :],
-            sems.at[j])
+        src = data_ref.at[slots_ref[b], idx_ref[b, s * ROWS_PER_STEP + j]]
+        if split:
+            src = src.at[pl.ds(pl.multiple_of(c * block, SUBLANES), block)]
+        return pltpu.make_async_copy(src, rows_ref.at[j], sems.at[j])
 
-    for j in range(rows_per_step):
+    for j in range(ROWS_PER_STEP):
         row_dma(j).start()
-    for j in range(rows_per_step):
+    for j in range(ROWS_PER_STEP):
         row_dma(j).wait()
 
-    rows = rows_ref[...].astype(jnp.float32)        # [R, D]
-    valid = (s * rows_per_step
-             + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)) < n_idx
-    rows = jnp.where(valid, rows, 0.0)              # mask the padded tail
-    acc_ref[0, :] += jnp.sum(rows, axis=0)
-    acc_ref[1, :] += jnp.sum(rows * rows, axis=0)
+    n_valid = n_idx - s * ROWS_PER_STEP             # < R only on the tail
 
-    @pl.when(s == steps - 1)
-    def _finalize():
-        stats_ref[0] = acc_ref[...].astype(stats_ref.dtype)
+    def fold(k, carry):
+        cols = pl.ds(pl.multiple_of(k * chunk, chunk), chunk)
+        rows = rows_ref[:, cols, :]                 # [R, chunk, 128]
+        valid = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) < n_valid
+        rows = jnp.where(valid, rows, 0.0)
+        stats_ref[0, 0, cols, :] += jnp.sum(rows, axis=0)
+        stats_ref[0, 1, cols, :] += jnp.sum(rows * rows, axis=0)
+        return carry
+
+    jax.lax.fori_loop(0, block // chunk, fold, 0)
 
 
 def subsample_stats_wave(
-    data: jax.Array,          # [B, N, D] one padded block per wave task
+    data: jax.Array,          # [S, N, L, 128] float32 task blocks, lane layout
+    slots: jax.Array,         # [B] int32: the block of each task in the wave
     indices: jax.Array,       # [B, T] int32 random row ids per task
     *,
-    rows_per_step: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """Stats-only wave gather: returns stats [B, 2, D] = per-task
-    (Σrow, Σrow²) with no gathered output.  ``T`` is rounded up to a
-    multiple of ``rows_per_step`` internally (tail masked), and each task's
-    accumulation order is fixed (R-row groups in index order) regardless of
-    B — so any wave partition of the same tasks is bit-identical."""
-    bsz, n, d = data.shape
-    b2, t = indices.shape
-    assert b2 == bsz, (b2, bsz)
-    t_pad = -(-t // rows_per_step) * rows_per_step
+    """Stats-only wave gather: returns stats ``[B, 2, L, 128]`` =
+    per-task (Σrow, Σrow²) over rows ``indices[b]`` of block
+    ``data[slots[b]]``, in the lane layout.  The slots are prefetched
+    scalars, so the kernel reads the drawn rows straight out of the
+    whole ``data`` (the platform's resident arena) and no ``[B, ...]``
+    copy of the wave's blocks is ever made.  ``T`` is rounded up to a
+    multiple of :data:`ROWS_PER_STEP` internally (tail masked)."""
+    _s, _n, lane_rows, lanes = data.shape
+    assert lanes == LANES, data.shape
+    bsz, t = indices.shape
+    assert slots.shape == (bsz,), (slots.shape, bsz)
+    block = column_block(lane_rows)
+    assert lane_rows % block == 0, (lane_rows, block)
+    t_pad = -(-t // ROWS_PER_STEP) * ROWS_PER_STEP
     if t_pad != t:
         indices = jnp.pad(indices, ((0, 0), (0, t_pad - t)))
-    steps = t_pad // rows_per_step
-    kernel = functools.partial(_stats_wave_kernel,
-                               rows_per_step=rows_per_step, n_idx=t,
-                               steps=steps)
+    kernel = functools.partial(
+        _stats_wave_kernel, n_idx=t, block=block,
+        split=block != lane_rows,
+        chunk=SUBLANES if block % SUBLANES == 0 else block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz, steps),
+        num_scalar_prefetch=2,
+        grid=(bsz, lane_rows // block, t_pad // ROWS_PER_STEP),
         in_specs=[
-            # the wave arena stays device-resident in HBM; rows are pulled
-            # by the kernel's own DMAs, so no [B, N, D] VMEM residency
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            # the arena stays in HBM; the kernel DMAs the drawn rows itself
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 2, d), lambda b, s, idx_ref: (b, 0, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, 2, block, LANES),
+                               lambda b, c, s, *_: (b, 0, c, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, d), jnp.float32),
-            pltpu.VMEM((rows_per_step, d), jnp.float32),
-            pltpu.SemaphoreType.DMA((rows_per_step,)),
+            pltpu.VMEM((ROWS_PER_STEP, block, LANES), jnp.float32),
+            pltpu.SemaphoreType.DMA((ROWS_PER_STEP,)),
         ],
     )
-    (stats,) = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bsz, 2, d), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((bsz, 2, lane_rows, LANES),
+                                       jnp.float32),
         interpret=interpret,
-    )(indices, data)
-    return stats
+        name="subsample_stats_wave",
+    )(slots, indices, data)

@@ -17,14 +17,9 @@ from repro.config.base import MeshConfig
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    ``AxisType`` enum itself) only exist on newer jax; older releases
-    take just (shape, axis_names) and default every axis to Auto."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -22,21 +22,13 @@ from jax import lax
 from repro.parallel.compression import dequantize_int8, quantize_int8
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mapped-axis size; ``lax.axis_size`` only exists on newer
-    jax — ``psum(1, axis)`` is the classic equivalent (constant-folded)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     """Reduce-scatter + all-gather ring over ``axis_name``.
 
     x is the per-device shard [N, ...] with N divisible by the axis size.
     Equivalent to lax.psum(x, axis_name).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -93,7 +85,7 @@ def gather_shards(x: jax.Array):
 
 def reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     """psum followed by keeping this device's shard (ZeRO grad shard)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     full = lax.psum(x, axis_name)
     shard = x.shape[0] // n

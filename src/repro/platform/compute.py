@@ -4,17 +4,14 @@ The driver resolves ONE engine per job so every backend executes the exact
 same per-task computation (this is what makes the threaded and simulated
 backends bit-identical for a fixed seed):
 
-  ``pallas``  — the TPU Pallas ``subsample_gather`` kernel (scalar-prefetch
-                row gather + VMEM-resident moment accumulators) for the
-                row-subsampling ``moments`` statistic; interpret mode on
-                CPU, compiled on TPU.
+  ``pallas``  — the Pallas ``subsample_stats_wave`` kernel (per-row HBM→VMEM
+                DMAs + moment accumulation) for the row-subsampling
+                ``moments`` statistic; compiled on TPU, interpreted on CPU.
   ``jnp``     — the jitted ``repro.core.subsample.map_task`` engine for the
-                paper workloads (ALOD / monthly means); on TPU its gather
-                is served by the same kernel family.
-  ``numpy``   — pure-NumPy reference path, used when JAX is unavailable
-                (hermetic containers) or forced for debugging.  Mirrors the
-                jnp semantics but draws indices from NumPy's RNG, so it is
-                statistically — not bitwise — equivalent to ``jnp``.
+                paper workloads (ALOD / monthly means).
+  ``numpy``   — pure-NumPy path, only when asked for by name.  Mirrors
+                the jnp semantics but draws indices from NumPy's RNG, so
+                it is statistically — not bitwise — equivalent to ``jnp``.
 
 Hardware adaptation (DESIGN.md §2): block building pads samples to a
 common power-of-two length so one compiled kernel serves every task —
@@ -23,8 +20,9 @@ compilation is startup cost (thesis Fig 5), never a per-task cost.
 Wave execution (DESIGN.md §7): a *wave* is a batch of same-shape ready
 tasks executed in ONE device dispatch.  :class:`BlockArena` packs the
 job's padded blocks into a device-resident ``[n_tasks, count, len]`` array
-per distinct shape (uploaded once); :func:`run_map_wave` folds per-task
-seeds in with ``jax.vmap`` / a batched Pallas grid so one compiled kernel
+per distinct shape (uploaded once; the ``pallas`` engine's arena is kept
+in the kernel's ``[n_tasks, count, len/128, 128]`` lane layout);
+:func:`run_map_wave` folds per-task seeds in with ``jax.vmap`` / a batched Pallas grid so one compiled kernel
 serves the whole wave.  Per-task accumulation order is independent of the
 wave partition, so wave and per-task execution are bit-identical for a
 fixed seed.
@@ -48,12 +46,7 @@ from typing import (Any, Callable, Dict, List, MutableSequence, Optional,
 
 import numpy as np
 
-try:  # JAX is the primary engine but the platform must degrade gracefully
-    import jax  # noqa: F401
-
-    HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised only in JAX-less images
-    HAVE_JAX = False
+from repro.kernels.subsample_gather import to_lane_layout
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,16 +115,12 @@ def wave_profitable(workload) -> bool:
 def resolve_engine(statistic: str, prefer: str = "auto") -> str:
     """Pick the compute engine once per job (never per task)."""
     if prefer != "auto":
-        if prefer in ("pallas", "jnp") and not HAVE_JAX:
-            raise RuntimeError(f"engine {prefer!r} requires JAX")
         if prefer == "pallas" and statistic != "moments":
             raise ValueError(
                 "engine 'pallas' computes the row-subsample 'moments' "
                 f"statistic; workload statistic is {statistic!r} — use "
                 "engine 'jnp' (or 'auto')")
         return prefer
-    if not HAVE_JAX:
-        return "numpy"
     return "pallas" if statistic == "moments" else "jnp"
 
 
@@ -143,7 +132,7 @@ def resolve_engine(statistic: str, prefer: str = "auto") -> str:
 def pow2_ceil(n: int) -> int:
     """Round up to a power of two — the padding primitive shared by block
     lengths (:func:`padded_len`) and wave widths.  Kept in sync with
-    ``repro.kernels.ops._pow2`` (this module must import without jax)."""
+    ``repro.kernels.ops._pow2``."""
     return 1 << (max(n, 1) - 1).bit_length()
 
 
@@ -196,9 +185,36 @@ def build_block(samples: Dict[int, np.ndarray],
 # ---------------------------------------------------------------------------
 
 
+def _stack_blocks(tasks: Sequence, build: Callable, lanes: bool
+                  ) -> Tuple[np.ndarray, Optional[np.ndarray], int]:
+    """Materialize ``build(task)`` for each task straight into one
+    preallocated bucket array (no per-task copies held alongside it);
+    ``lanes`` stores the blocks in the kernel's lane layout and drops
+    the months plane, which the moments kernel never reads.  Returns
+    ``(data, months, block row length)``."""
+    data = months = None
+    width = 0
+    for i, task in enumerate(tasks):
+        block, mo = build(task)
+        width = block.shape[-1]
+        if lanes:
+            block = to_lane_layout(block)
+        if data is None:
+            data = np.empty((len(tasks),) + block.shape, block.dtype)
+            if not lanes:
+                months = np.empty((len(tasks),) + mo.shape, mo.dtype)
+        data[i] = block
+        if months is not None:
+            months[i] = mo
+    return data, months, width
+
+
 class BlockArena:
     """The job's padded task blocks, packed per distinct block shape into
     one ``[n_tasks, count, len]`` array and uploaded to the device ONCE.
+    For the ``pallas`` engine (``lanes=True``) each bucket is stored in
+    the wave kernel's lane layout ``[n_tasks, count, len/128, 128]``, so
+    a wave hands the kernel the arena's own rows with no relayout.
 
     Per-task execution re-uploads every block; the arena replaces that
     with a single upload plus a device-side row gather per wave (the slot
@@ -207,19 +223,21 @@ class BlockArena:
     """
 
     def __init__(self):
-        self._data: Dict[Any, Any] = {}      # shape key -> [B, count, len]
+        self._data: Dict[Any, Any] = {}      # shape key -> stacked blocks
         self._months: Dict[Any, Any] = {}
+        self._width: Dict[Any, int] = {}    # shape key -> block row length
         self._slot: Dict[int, Tuple[Any, int]] = {}   # task_id -> (key, row)
         self.nbytes = 0.0
 
     @classmethod
     def pack(cls, tasks: Sequence, shape_key: Callable, build: Callable,
-             with_months: bool = True) -> "BlockArena":
+             lanes: bool = False) -> "BlockArena":
         """Bucket ``tasks`` by ``shape_key``, materialize each task's
         padded block via ``build(task) -> (block, months)``, stack each
-        bucket and upload it once.  ``with_months=False`` skips the
-        months plane (the moments/pallas wave never reads it — packing
-        it would double the upload and skew ``bytes_uploaded``)."""
+        bucket and upload it once.  ``lanes=True`` packs for the moments
+        kernel: lane layout, and no months plane (the kernel never reads
+        it — packing it would double the upload and skew
+        ``bytes_uploaded``)."""
         import jax.numpy as jnp
 
         arena = cls()
@@ -227,19 +245,19 @@ class BlockArena:
         for task in tasks:
             buckets.setdefault(shape_key(task), []).append(task)
         for key, group in buckets.items():
-            pairs = [build(t) for t in group]
-            data = np.stack([p[0] for p in pairs])
-            arena._data[key] = jnp.asarray(data)
-            arena.nbytes += float(data.nbytes)
-            if with_months:
-                months = np.stack([p[1] for p in pairs])
-                arena._months[key] = jnp.asarray(months)
-                arena.nbytes += float(months.nbytes)
-            else:
-                arena._months[key] = None
+            arena._put(key, *_stack_blocks(group, build, lanes),
+                       upload=jnp.asarray)
             for row, task in enumerate(group):
                 arena._slot[task.task_id] = (key, row)
         return arena
+
+    def _put(self, key, data: np.ndarray, months: Optional[np.ndarray],
+             width: int, *, upload: Callable) -> None:
+        self._width[key] = width
+        self._data[key] = upload(data)
+        self._months[key] = None if months is None else upload(months)
+        self.nbytes += float(data.nbytes) + (
+            0.0 if months is None else float(months.nbytes))
 
     def keys(self) -> List[Any]:
         return list(self._data)
@@ -249,6 +267,10 @@ class BlockArena:
 
     def bucket_size(self, key) -> int:
         return int(self._data[key].shape[0])
+
+    def width(self, key) -> int:
+        """Row length of the bucket's blocks (before any lane padding)."""
+        return self._width[key]
 
     def slots(self, tasks: Sequence) -> Tuple[Any, np.ndarray]:
         """Arena rows for a wave.  Waves are drained same-shape by the
@@ -314,7 +336,7 @@ class ShardedBlockArena(BlockArena):
 
     @classmethod
     def pack(cls, tasks: Sequence, shape_key: Callable, build: Callable,
-             mesh=None, with_months: bool = True) -> "ShardedBlockArena":
+             mesh=None, lanes: bool = False) -> "ShardedBlockArena":
         assert mesh is not None, "ShardedBlockArena.pack needs a mesh"
         import jax
 
@@ -327,21 +349,14 @@ class ShardedBlockArena(BlockArena):
         for task in tasks:
             buckets.setdefault(shape_key(task), []).append(task)
         for key, group in buckets.items():
-            pairs = [build(t) for t in group]
             b = len(group)
             per_dev = -(-b // n_dev)
             # physical order: device-major over the interleaved placement
             order = [unshard_slot(dev, local, n_dev) % b
                      for dev in range(n_dev) for local in range(per_dev)]
-            data = np.stack([pairs[i][0] for i in order])
-            arena._data[key] = jax.device_put(data, sharding)
-            arena.nbytes += float(data.nbytes)
-            if with_months:
-                months = np.stack([pairs[i][1] for i in order])
-                arena._months[key] = jax.device_put(months, sharding)
-                arena.nbytes += float(months.nbytes)
-            else:
-                arena._months[key] = None
+            arena._put(key, *_stack_blocks([group[i] for i in order],
+                                            build, lanes),
+                       upload=lambda x: jax.device_put(x, sharding))
             arena._per_dev[key] = per_dev
             for i, task in enumerate(group):
                 dev, local = shard_slot(i, n_dev)
@@ -392,16 +407,18 @@ def _moments_pallas(block: np.ndarray, seed: int,
     agree to the last bit for the same per-task seed."""
     import jax.numpy as jnp
 
+    n_idx = workload.draws * workload.draw_size
     stats = _moments_wave_device(
-        jnp.asarray(block)[None], np.zeros(1, np.int32),
-        np.asarray([seed], np.int32),
-        n_idx=workload.draws * workload.draw_size)
-    return _split_moments(np.asarray(stats, np.float32),
-                          workload.draws * workload.draw_size)[0]
+        jnp.asarray(to_lane_layout(block)[None]), np.zeros(1, np.int32),
+        np.asarray([seed], np.int32), n_idx=n_idx)
+    return _split_moments(np.asarray(stats), n_idx, block.shape[-1])[0]
 
 
-def _split_moments(stats: np.ndarray, n_idx: int) -> List[Dict[str, np.ndarray]]:
-    """[B, 2, D] kernel stats → per-task reduce-tree partials."""
+def _split_moments(stats: np.ndarray, n_idx: int,
+                   width: int) -> List[Dict[str, np.ndarray]]:
+    """``[B, 2, L, 128]`` lane-layout kernel stats → per-task
+    reduce-tree partials over the block's ``width`` columns."""
+    stats = stats.reshape(len(stats), 2, -1)[:, :, :width]
     return [{"sum": s[0], "sumsq": s[1],
              "count": np.asarray(float(n_idx), np.float32)}
             for s in stats]
@@ -409,9 +426,10 @@ def _split_moments(stats: np.ndarray, n_idx: int) -> List[Dict[str, np.ndarray]]
 
 def _moments_wave_jit():
     """Module-cached jitted wave pipeline (one compile per arena/wave
-    shape, reused across every wave of the job): slot gather out of the
-    resident arena → per-task index derivation (vmapped over the folded
-    seeds) → batched stats-only Pallas kernel."""
+    shape, reused across every wave of the job): per-task index
+    derivation (vmapped over the folded seeds) → batched stats-only
+    Pallas kernel, which reads each task's drawn rows straight out of
+    the resident arena at the task's slot."""
     import functools
 
     import jax
@@ -421,12 +439,11 @@ def _moments_wave_jit():
 
     @functools.partial(jax.jit, static_argnames=("n",))
     def wave(arena, rows, seeds, *, n):
-        data = jnp.take(arena, rows, axis=0)          # [B, count, len]
-        ns = data.shape[1]
+        ns = arena.shape[1]                         # [slots, count, L, 128]
         idx = jax.vmap(
             lambda s: jax.random.randint(jax.random.PRNGKey(s), (n,), 0,
                                          ns, dtype=jnp.int32))(seeds)
-        return ops.subsample_stats(data, idx)
+        return ops.subsample_stats_lanes(arena, rows, idx)
 
     return wave
 
@@ -458,15 +475,14 @@ def _jnp_wave_jit():
 
 def _moments_wave_sharded_jit(mesh):
     """Sharded moments wave: the per-device body is the SAME pipeline as
-    :func:`_moments_wave_jit` (local-slot gather → vmapped PRNG index
-    derivation → stats-only Pallas kernel), wrapped in ``shard_map`` so
-    one dispatch drives every device.  ``check_rep=False`` because Pallas
-    has no SPMD replication rule; outputs are per-device anyway."""
+    :func:`_moments_wave_jit` (vmapped PRNG index derivation → stats-only
+    Pallas kernel reading the local slots), wrapped in ``shard_map`` so
+    one dispatch drives every device.  ``check_vma=False`` because Pallas
+    has no varying-manual-axes rule; outputs are per-device anyway."""
     import functools
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ops
@@ -474,16 +490,15 @@ def _moments_wave_sharded_jit(mesh):
     @functools.partial(jax.jit, static_argnames=("n",))
     def wave(arena, rows, seeds, *, n):
         def per_device(a, r, s):
-            # a: [per_dev, count, len]; r, s: [1, width]
-            data = jnp.take(a, r[0], axis=0)
-            ns = data.shape[1]
+            # a: [per_dev, count, L, 128]; r, s: [1, width]
+            ns = a.shape[1]
             idx = jax.vmap(
                 lambda k: jax.random.randint(jax.random.PRNGKey(k), (n,),
                                              0, ns, dtype=jnp.int32))(s[0])
-            return ops.subsample_stats_shard(data, idx)[None]
-        return shard_map(per_device, mesh=mesh,
-                         in_specs=(P("wave"), P("wave"), P("wave")),
-                         out_specs=P("wave"), check_rep=False)(
+            return ops.subsample_stats_lanes(a, r[0], idx)[None]
+        return jax.shard_map(per_device, mesh=mesh,
+                             in_specs=(P("wave"), P("wave"), P("wave")),
+                             out_specs=P("wave"), check_vma=False)(
             arena, rows, seeds)
 
     return wave
@@ -497,7 +512,6 @@ def _jnp_wave_sharded_jit(mesh):
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import subsample as ss
@@ -514,9 +528,9 @@ def _jnp_wave_sharded_jit(mesh):
                 d, mo, k, draws=draws, draw_size=draw_size, grid=grid,
                 statistic=statistic))(data, months, keys)
             return jax.tree.map(lambda x: x[None], out)
-        return shard_map(per_device, mesh=mesh,
-                         in_specs=(P("wave"),) * 4,
-                         out_specs=P("wave"), check_rep=False)(
+        return jax.shard_map(per_device, mesh=mesh,
+                             in_specs=(P("wave"),) * 4,
+                             out_specs=P("wave"), check_vma=False)(
             arena, arena_mo, rows, seeds)
 
     return wave
@@ -600,11 +614,10 @@ def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
     if engine == "pallas":
         n_idx = workload.draws * workload.draw_size
         stats = np.asarray(
-            _moments_wave_device(data, rows, seeds, n_idx=n_idx),
-            np.float32)
-        return _split_moments(stats[:b], n_idx)
+            _moments_wave_device(data, rows, seeds, n_idx=n_idx))
+        return _split_moments(stats[:b], n_idx, arena.width(key))
     if engine == "jnp":
-        assert months is not None, "jnp waves need pack(with_months=True)"
+        assert months is not None, "jnp waves need pack(lanes=False)"
         out = _jnp_wave_device(data, months, rows, seeds, workload)
         out = jax.tree.map(np.asarray, out)
         return [jax.tree.map(lambda a: a[i], out) for i in range(b)]
@@ -668,11 +681,11 @@ def run_map_wave_sharded(arena: ShardedBlockArena, tasks: Sequence,
         n_idx = workload.draws * workload.draw_size
         out = _sharded_wave_fn("moments", arena.mesh)(
             data, rows_dev, seeds_dev, n=n_idx)
-        stats = np.asarray(col.gather_shards(out), np.float32)
+        stats = col.gather_shards(out)
         picked = np.stack([stats[d, lane] for d, lane in place])
-        return _split_moments(picked, n_idx)
+        return _split_moments(picked, n_idx, arena.width(key))
     if engine == "jnp":
-        assert months is not None, "jnp waves need pack(with_months=True)"
+        assert months is not None, "jnp waves need pack(lanes=False)"
         out = _sharded_wave_fn("jnp", arena.mesh)(
             data, months, rows_dev, seeds_dev, draws=workload.draws,
             draw_size=workload.draw_size, grid=workload.grid,

@@ -549,11 +549,11 @@ def build_wave_context(plan: JobPlan, workload, *, n_exec: int,
     if mesh is not None:
         arena: pc.BlockArena = pc.ShardedBlockArena.pack(
             plan.tasks, plan.task_shape, plan.build_block, mesh,
-            with_months=(plan.engine == "jnp"))
+            lanes=(plan.engine == "pallas"))
     else:
         arena = pc.BlockArena.pack(plan.tasks, plan.task_shape,
                                    plan.build_block,
-                                   with_months=(plan.engine == "jnp"))
+                                   lanes=(plan.engine == "pallas"))
     by_key: Dict[Any, List[sch.Task]] = {}
     for task in plan.tasks:
         by_key.setdefault(plan.task_shape(task), []).append(task)

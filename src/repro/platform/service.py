@@ -688,12 +688,17 @@ class PlatformService:
         pool = self._pool
         adm = self.admission
         with self._lock:
-            active = len(self._active) + len(self._waiting) + waiting_adjust
+            running = len(self._active)
+            active = running + len(self._waiting) + waiting_adjust
         pending = pool.pending_tasks() if pool is not None else 0
         if active >= adm.max_active_jobs:
             return ("capacity", f"active jobs {active} ≥ max_active_jobs "
                     f"{adm.max_active_jobs}")
-        if pending + ticket.n_tasks > adm.max_pending_tasks:
+        # a job larger than the bound is admitted only into an idle
+        # service (no running job, empty ready queue): waiting for room
+        # any later would never end, and shedding it would shed it always
+        if ((running or pending)
+                and pending + ticket.n_tasks > adm.max_pending_tasks):
             return ("capacity", f"ready queue {pending}+{ticket.n_tasks} > "
                     f"max_pending_tasks {adm.max_pending_tasks}")
         if (adm.slo_aware and deadline is not None and pool is not None

@@ -179,7 +179,9 @@ def test_fused_results_match_sequential_results():
 
 
 def test_drr_small_job_not_starved_by_big_job():
-    samples, months = _dataset(256)
+    # the big job must outlast the second submit's latency on a loaded
+    # host, or it finishes before the small job even arrives
+    samples, months = _dataset(1024)
     small_samples, _ = _dataset(32)
     with PlatformService(_spec(n_workers=1)) as svc:
         big_h = svc.register_dataset(samples, months)
@@ -189,7 +191,7 @@ def test_drr_small_job_not_starved_by_big_job():
         # warm both classes so the measured run is execution only
         svc.submit(big_h, WL, seed=90).result(timeout=300)
         svc.submit(small_h, WL, seed=91).result(timeout=300)
-        big = svc.submit(big_h, WL, seed=1)       # 64 tasks
+        big = svc.submit(big_h, WL, seed=1)       # 256 tasks
         small = svc.submit(small_h, WL, seed=2)   # 8 tasks
         small.result(timeout=300)
         big.result(timeout=300)
@@ -383,6 +385,45 @@ def test_admission_queue_admits_when_capacity_frees():
         r2 = queued.result(timeout=120)
     assert r1["count"] == r2["count"]
     assert queued.queue_wait is not None and queued.queue_wait >= 0
+
+
+def test_admission_idle_pool_admits_job_larger_than_queue_bound():
+    # a job with more tasks than max_pending_tasks used to wait for a
+    # drain that could never make room, so result() hung forever
+    samples, months = _dataset(32)
+    policy = AdmissionPolicy(max_pending_tasks=4, mode="queue")
+    with PlatformService(_spec(n_workers=1), admission=policy) as svc:
+        handle = svc.register_dataset(samples, months)
+        big = svc.submit(handle, WL, seed=0)
+        assert big.n_tasks > policy.max_pending_tasks
+        assert big.result(timeout=120)["count"] == big.n_tasks * 64
+
+
+def test_admission_shed_admits_oversized_job_only_into_idle_service():
+    samples, months = _dataset(64)
+    policy = AdmissionPolicy(max_pending_tasks=4, mode="shed")
+    with PlatformService(_spec(n_workers=1), admission=policy) as svc:
+        handle = svc.register_dataset(samples, months)
+        first = svc.submit(handle, WL, seed=0)     # idle service: admitted
+        shed = svc.submit(handle, WL, seed=1)      # first still running
+        assert first.n_tasks > policy.max_pending_tasks
+        first.result(timeout=120)
+    assert shed.status == "rejected"
+    with pytest.raises(AdmissionError):
+        shed.result(timeout=5)
+
+
+def test_admission_queue_runs_oversized_jobs_one_at_a_time():
+    # each oversized job waits for the service to go idle; the FIFO
+    # drain must then admit the head even while others wait behind it
+    samples, months = _dataset(64)
+    policy = AdmissionPolicy(max_pending_tasks=4, mode="queue")
+    with PlatformService(_spec(n_workers=1), admission=policy) as svc:
+        handle = svc.register_dataset(samples, months)
+        tickets = [svc.submit(handle, WL, seed=s) for s in range(3)]
+        assert [t.status for t in tickets[1:]] == ["queued", "queued"]
+        counts = [t.result(timeout=120)["count"] for t in tickets]
+    assert counts == [tickets[0].n_tasks * 64] * 3
 
 
 def test_slo_aware_admission_rejects_unmeetable_deadline():

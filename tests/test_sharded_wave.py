@@ -28,6 +28,7 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from repro.core.subsample import EAGLET, NETFLIX_HIGH  # noqa: E402
+from repro.kernels.subsample_gather import to_lane_layout  # noqa: E402
 from repro.platform import compute as pc  # noqa: E402
 from repro.platform.compute import MomentsSpec  # noqa: E402
 from repro.platform.driver import Platform, PlatformSpec  # noqa: E402
@@ -152,8 +153,7 @@ def test_sharded_arena_physical_layout(mesh_devices):
                     engine="pallas", n_exec=2, knee_bytes=1024.0)
     mesh = make_wave_mesh(4)
     arena = pc.ShardedBlockArena.pack(plan.tasks, plan.task_shape,
-                                      plan.build_block, mesh,
-                                      with_months=False)
+                                      plan.build_block, mesh, lanes=True)
     for key in arena.keys():
         data = np.asarray(arena.bucket(key)[0])
         per_dev = arena._per_dev[key]
@@ -162,7 +162,7 @@ def test_sharded_arena_physical_layout(mesh_devices):
         key, dev, local = arena._dev_slot[task.task_id]
         per_dev = arena._per_dev[key]
         assert arena._slot[task.task_id] == (key, dev * per_dev + local)
-        want = plan.build_block(task)[0]
+        want = to_lane_layout(plan.build_block(task)[0])
         got = np.asarray(arena.bucket(key)[0])[dev * per_dev + local]
         np.testing.assert_array_equal(want, got)
 

@@ -89,28 +89,20 @@ def _local_mesh():
 
 
 def test_ring_all_reduce_matches_psum_single_device():
-    try:
-        from jax import shard_map
-    except ImportError:           # renamed from jax.experimental < 0.7
-        from jax.experimental.shard_map import shard_map
     from repro.parallel.collectives import ring_all_reduce
     mesh = _local_mesh()
     x = jnp.arange(16.0).reshape(4, 4)
-    f = shard_map(lambda v: ring_all_reduce(v, "data"), mesh=mesh,
-                  in_specs=P("data"), out_specs=P("data"))
+    f = jax.shard_map(lambda v: ring_all_reduce(v, "data"), mesh=mesh,
+                      in_specs=P("data"), out_specs=P("data"))
     np.testing.assert_allclose(np.asarray(f(x)), np.asarray(x))
 
 
 def test_compressed_psum_error_bounded():
-    try:
-        from jax import shard_map
-    except ImportError:           # renamed from jax.experimental < 0.7
-        from jax.experimental.shard_map import shard_map
     from repro.parallel.collectives import compressed_psum
     mesh = _local_mesh()
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 32))
-    f = shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
-                  in_specs=P(), out_specs=P())
+    f = jax.shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
+                      in_specs=P(), out_specs=P())
     out = f(x)
     _, scale = quantize_int8(x)
     bound = float(jnp.max(scale)) / 2 + 1e-7

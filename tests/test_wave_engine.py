@@ -123,7 +123,7 @@ def test_wave_on_rejects_unsupported_combination():
 @pytest.mark.parametrize("n,d,t,b", [(32, 16, 21, 3), (64, 128, 64, 1),
                                      (16, 8, 5, 4)])
 def test_subsample_stats_matches_ref(n, d, t, b):
-    """Tail masking (t not a multiple of rows_per_step) and batching must
+    """Tail masking (t not a multiple of ROWS_PER_STEP) and batching must
     both agree with the oracle."""
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
     data = jax.random.normal(keys[0], (b, n, d), jnp.float32)
@@ -162,6 +162,49 @@ def test_wave_kernel_partition_invariant():
     np.testing.assert_array_equal(whole, singles)
 
 
+def test_wave_kernel_column_split_bit_identical(monkeypatch):
+    """A row wider than the VMEM budget is split over column blocks; the
+    split changes no column's accumulation order, so the stats equal
+    the unsplit kernel's bit for bit (and the oracle)."""
+    from repro.kernels import subsample_gather as sg
+
+    keys = jax.random.split(jax.random.PRNGKey(4), 2)
+    data = jax.random.normal(keys[0], (2, 16, 4096), jnp.float32)
+    idx = jax.random.randint(keys[1], (2, 13), 0, 16, jnp.int32)
+    lanes = sg.to_lane_layout(data)
+    slots = jnp.arange(2, dtype=jnp.int32)
+    whole = np.asarray(sg.subsample_stats_wave(lanes, slots, idx,
+                                               interpret=True))
+    monkeypatch.setattr(sg, "ROW_BUFFER_BYTES",
+                        sg.ROWS_PER_STEP * sg.SUBLANES * sg.LANES * 4)
+    assert sg.column_block(4096 // sg.LANES) == sg.SUBLANES
+    split = np.asarray(sg.subsample_stats_wave(lanes, slots, idx,
+                                               interpret=True))
+    np.testing.assert_array_equal(whole, split)
+    for i in range(2):
+        _, want = ref.subsample_stats_ref(data[i], idx[i])
+        np.testing.assert_allclose(split[i].reshape(2, -1), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_wave_kernel_reads_tasks_at_their_slots():
+    """The kernel reads each lane's rows from block ``data[slots[b]]`` of
+    the whole arena: repeated and permuted slots give, bit for bit, the
+    stats of the same blocks handed over one by one."""
+    from repro.kernels import subsample_gather as sg
+
+    keys = jax.random.split(jax.random.PRNGKey(5), 2)
+    data = jax.random.normal(keys[0], (5, 16, 256), jnp.float32)
+    slots = jnp.asarray([3, 0, 3, 4], jnp.int32)
+    idx = jax.random.randint(keys[1], (4, 11), 0, 16, jnp.int32)
+    lanes = sg.to_lane_layout(data)
+    got = np.asarray(ops.subsample_stats_lanes(lanes, slots, idx))
+    for b in range(4):
+        one = np.asarray(ops.subsample_stats(data[slots[b]][None],
+                                             idx[b][None]))[0]
+        np.testing.assert_array_equal(got[b].reshape(2, -1), one)
+
+
 def test_vmapped_seed_derivation_bit_identical():
     """The wave engine folds per-task seeds with jax.vmap; the derived
     index streams must match the per-task derivation bitwise."""
@@ -196,8 +239,6 @@ def test_subsample_gather_pow2_padding_correct():
 def test_subsample_gather_shares_one_trace_across_draw_counts():
     """Index counts 5..8 all round up to 8, so they must share ONE
     compiled kernel instead of retracing per length."""
-    if not hasattr(ops._subsample_gather_padded, "_cache_size"):
-        pytest.skip("jit cache introspection unavailable")
     data = jax.random.normal(jax.random.PRNGKey(1), (16, 8), jnp.float32)
     ops._subsample_gather_padded._clear_cache()
     for t in (5, 6, 7, 8):
