@@ -24,6 +24,7 @@ so job statistics are bit-identical across backends for a fixed seed.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -31,6 +32,7 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, Sequence, Tupl
 
 from repro.core import recovery as rec
 from repro.core import scheduler as sch
+from repro.platform import telemetry as tel
 
 Emit = Callable[[int, Any], None]
 Compute = Callable[[sch.Task], Any]
@@ -98,6 +100,20 @@ class PlatformBackend(Protocol):
 # ---------------------------------------------------------------------------
 
 
+def _emulated_sleep(seconds: float) -> None:
+    """Spend one of the platform profile's emulated costs as a real
+    sleep, in its own host span."""
+    if seconds > 0:
+        with tel.span("platform.emulated_overhead"):
+            time.sleep(seconds)
+
+
+def _tax_seconds(plat, took: float) -> float:
+    """The emulated runtime taxes that scale with a dispatch's real
+    compute: the DFS tax and task-level monitoring (Fig 6)."""
+    return plat.dfs_tax * took + (0.20 * took if plat.monitoring else 0.0)
+
+
 class ThreadedBackend:
     name = "threaded"
 
@@ -112,15 +128,10 @@ class ThreadedBackend:
         assert compute is not None, "threaded backend needs real compute"
 
         def run_task(task: sch.Task):
-            if plat.launch_overhead:
-                time.sleep(plat.launch_overhead)
+            _emulated_sleep(plat.launch_overhead)
             t0 = time.perf_counter()
             value = compute(task)
-            took = time.perf_counter() - t0
-            if plat.dfs_tax:
-                time.sleep(plat.dfs_tax * took)
-            if plat.monitoring:
-                time.sleep(0.20 * took)           # Fig 6 monitoring tax
+            _emulated_sleep(_tax_seconds(plat, time.perf_counter() - t0))
             emit(task.task_id, value)
             return value
 
@@ -129,15 +140,11 @@ class ThreadedBackend:
             # one launch + one device dispatch amortized over the wave;
             # runtime taxes (DFS, monitoring) still scale with real compute
             def run_wave(batch: List[sch.Task]) -> List[Any]:
-                if plat.launch_overhead:
-                    time.sleep(plat.launch_overhead)
+                _emulated_sleep(plat.launch_overhead)
                 t0 = time.perf_counter()
                 values = compute_wave(batch)
-                took = time.perf_counter() - t0
-                if plat.dfs_tax:
-                    time.sleep(plat.dfs_tax * took)
-                if plat.monitoring:
-                    time.sleep(0.20 * took)
+                _emulated_sleep(_tax_seconds(plat,
+                                             time.perf_counter() - t0))
                 # one partial per claimed task, in claim order — the
                 # sharded wave path pads per-device lanes, and a
                 # mis-stripped pad would otherwise emit a wrong partial
@@ -258,6 +265,9 @@ class ServicePool:
         self._cond = threading.Condition()
         self._threads: Dict[int, threading.Thread] = {}
         self._respawns: Dict[int, int] = {}
+        # workers that died of an injected crash, reported under _cond so
+        # the supervisor handles them even if the pool stops first
+        self._crashed: set = set()
         self._monitor: Optional[threading.Thread] = None
         self._stop = False
         self.started = False
@@ -300,11 +310,13 @@ class ServicePool:
         with self._cond:
             self._stop = True
             self._cond.notify_all()
-        for th in list(self._threads.values()):
-            th.join(timeout=30.0)
+        # the supervisor first: its last pass may still respawn a worker
+        # whose crash was reported before the stop
         if self._monitor is not None:
             self._monitor.join(timeout=30.0)
             self._monitor = None
+        for th in list(self._threads.values()):
+            th.join(timeout=30.0)
         self._threads = {}
         if self.prefetcher is not None:
             self.prefetcher.close()
@@ -316,14 +328,16 @@ class ServicePool:
         worker id (per-task seeds make the re-execution bit-identical).
         Respawns are bounded by ``max_respawns`` per worker slot; an
         exhausted slot stays down and its share of the pool is served by
-        the surviving workers."""
+        the surviving workers.  Once the pool stops, a thread is dead
+        only if it reported a crash (the others leave on the stop), and
+        the supervisor makes that one last pass before it returns."""
         while True:
             with self._cond:
-                if self._stop:
-                    return
                 for w, th in list(self._threads.items()):
-                    if th.is_alive():
+                    if ((th.is_alive() or self._stop)
+                            and w not in self._crashed):
                         continue
+                    self._crashed.discard(w)
                     self.sched.on_worker_dead(w)
                     if self._respawns.get(w, 0) < self.max_respawns:
                         self._respawns[w] = self._respawns.get(w, 0) + 1
@@ -338,6 +352,8 @@ class ServicePool:
                     else:
                         self._threads.pop(w, None)
                 self._cond.notify_all()
+                if self._stop:
+                    return
             time.sleep(0.02)
 
     # -- job intake ----------------------------------------------------------
@@ -386,7 +402,11 @@ class ServicePool:
             failed_ids: List[int] = []
             upcoming: List[Tuple[PoolJob, sch.Task]] = []
             is_spec = False                 # batch came from speculation
-            with self._cond:
+            # claim time is the lock wait and the claim work, not the
+            # idle waits between attempts
+            claiming = contextlib.ExitStack()
+            claiming.enter_context(tel.span("platform.claim", worker=wid))
+            with claiming, self._cond:
                 try:
                     batch = []
                     while not self._stop:
@@ -405,7 +425,10 @@ class ServicePool:
                         # idle worker = free capacity for lease recovery:
                         # requeue claims whose lease lapsed (§12)
                         self.sched.reclaim_expired(time.monotonic())
+                        claiming.close()
                         self._cond.wait(0.02)
+                        claiming.enter_context(
+                            tel.span("platform.claim", worker=wid))
                 except Exception as e:      # noqa: BLE001
                     # a scheduler-policy bug must fail jobs, not kill the
                     # worker thread (a dead worker hangs every outstanding
@@ -443,6 +466,7 @@ class ServicePool:
                 except rec.WorkerCrash:
                     with self._cond:
                         self.sched.on_worker_dead(wid)
+                        self._crashed.add(wid)
                         self._cond.notify_all()
                     return
             if not pool_batch:
@@ -462,8 +486,7 @@ class ServicePool:
             for pj in {pj.job_id: pj for pj in fresh}.values():
                 if pj.on_start is not None:
                     pj.on_start(now)
-            if plat.launch_overhead:
-                time.sleep(plat.launch_overhead)
+            _emulated_sleep(plat.launch_overhead)
             try:
                 if self.prefetcher is not None and upcoming:
                     # per-job resident predicates drop cache-resident
@@ -514,86 +537,86 @@ class ServicePool:
                 else:
                     self._fail_batch(batch, e)
                 continue
-            if plat.dfs_tax:
-                time.sleep(plat.dfs_tax * took)
-            if plat.monitoring:
-                time.sleep(0.20 * took)
-            emit_failed: Dict[int, BaseException] = {}
-            for (pj, task), value in zip(pool_batch, values):
-                if pj.job_id in emit_failed:
-                    continue
-                try:
-                    pj.emit(task.task_id, value)
-                except BaseException as e:  # noqa: BLE001
-                    # an emit that throws (e.g. an injected
-                    # checkpoint-write crash, §12) must fail ITS job —
-                    # letting it unwind would kill this worker thread
-                    # and, once respawns are exhausted, hang the job
-                    emit_failed[pj.job_id] = e
-            for jid, e in emit_failed.items():
-                self._fail_jobs([jid], e)
-            # average over the tasks that actually ran; a job missing from
-            # pool_batch (defensive — see the not-pool_batch branch above)
-            # settles without a sample (its tasks never executed, and
-            # charging them would dilute the EMA toward zero)
-            exec_each = took / max(len(pool_batch), 1)
-            if self.prefetcher is not None:
-                self.prefetcher.observe_exec(exec_each)
-            executed = {pj.job_id for pj, _ in pool_batch}
-            finished: List[PoolJob] = []
-            drained: set = set()
-            with self._cond:
-                for job, _task in batch:
-                    sample = (exec_each if job.job_id in executed else None)
-                    if self.sched.on_task_complete(job.job_id, sample,
-                                                   _task.task_id,
-                                                   speculative=is_spec,
-                                                   worker=wid,
-                                                   fetch_seconds=(
-                                                       fetch_each
-                                                       if job.job_id
-                                                       in executed
-                                                       else None)):
-                        pj = self._jobs.pop(job.job_id, None)
-                        self._started_jobs.discard(job.job_id)
-                        if pj is not None:
-                            finished.append(pj)
-                # wave-settlement stopping check (DESIGN.md §10): a job
-                # whose estimate converged DRAINs — its queued tasks are
-                # dropped through the multi-job cancel plumbing, and the
-                # freed capacity goes to peer jobs on the very next
-                # claim; its in-flight tasks (possibly fused into peers'
-                # waves on other workers) settle normally
-                for pj in {p.job_id: p for p, _ in pool_batch}.values():
-                    jid = pj.job_id
-                    if (pj.stopper is None or jid not in self.sched.jobs
-                            or not pj.stopper.should_stop()):
+            _emulated_sleep(_tax_seconds(plat, took))
+            jobs = ",".join(map(str, dict.fromkeys(pj.job_id
+                                                   for pj, _ in pool_batch)))
+            with tel.span("platform.settle", worker=wid, jobs=jobs):
+                emit_failed: Dict[int, BaseException] = {}
+                for (pj, task), value in zip(pool_batch, values):
+                    if pj.job_id in emit_failed:
                         continue
-                    dropped = self.sched.cancel_job(jid)
-                    if dropped:
-                        drained.add(jid)
-                        self.telemetry.emit("job_draining", job_id=jid,
-                                            n_cancelled=len(dropped))
-                        if pj.on_cancelled is not None:
-                            pj.on_cancelled(len(dropped))
-                    if jid not in self.sched.jobs and jid in self._jobs:
-                        # nothing left in flight anywhere: the drain
-                        # itself completed the job
-                        self._jobs.pop(jid, None)
-                        self._started_jobs.discard(jid)
-                        finished.append(pj)
-                self._cond.notify_all()
-            if self.prefetcher is not None and drained:
-                # evict the drained jobs' prefetched-but-never-claimed
-                # fetches (their tasks will never execute)
-                self.prefetcher.discard(lambda k: k[0] in drained)
-            if self.prefetcher is not None and finished:
-                # evict finished jobs' never-claimed prefetches (a peer
-                # can ensure() a task inline before our peeked prefetch
-                # lands — without this sweep those futures leak for the
-                # life of the service)
-                gone = {pj.job_id for pj in finished}
-                self.prefetcher.discard(lambda k: k[0] in gone)
+                    try:
+                        pj.emit(task.task_id, value)
+                    except BaseException as e:  # noqa: BLE001
+                        # an emit that throws (e.g. an injected
+                        # checkpoint-write crash, §12) must fail ITS job —
+                        # letting it unwind would kill this worker thread
+                        # and, once respawns are exhausted, hang the job
+                        emit_failed[pj.job_id] = e
+                for jid, e in emit_failed.items():
+                    self._fail_jobs([jid], e)
+                # average over the tasks that actually ran; a job missing from
+                # pool_batch (defensive — see the not-pool_batch branch above)
+                # settles without a sample (its tasks never executed, and
+                # charging them would dilute the EMA toward zero)
+                exec_each = took / max(len(pool_batch), 1)
+                if self.prefetcher is not None:
+                    self.prefetcher.observe_exec(exec_each)
+                executed = {pj.job_id for pj, _ in pool_batch}
+                finished: List[PoolJob] = []
+                drained: set = set()
+                with self._cond:
+                    for job, _task in batch:
+                        sample = (exec_each if job.job_id in executed else None)
+                        if self.sched.on_task_complete(job.job_id, sample,
+                                                       _task.task_id,
+                                                       speculative=is_spec,
+                                                       worker=wid,
+                                                       fetch_seconds=(
+                                                           fetch_each
+                                                           if job.job_id
+                                                           in executed
+                                                           else None)):
+                            pj = self._jobs.pop(job.job_id, None)
+                            self._started_jobs.discard(job.job_id)
+                            if pj is not None:
+                                finished.append(pj)
+                    # wave-settlement stopping check (DESIGN.md §10): a job
+                    # whose estimate converged DRAINs — its queued tasks are
+                    # dropped through the multi-job cancel plumbing, and the
+                    # freed capacity goes to peer jobs on the very next
+                    # claim; its in-flight tasks (possibly fused into peers'
+                    # waves on other workers) settle normally
+                    for pj in {p.job_id: p for p, _ in pool_batch}.values():
+                        jid = pj.job_id
+                        if (pj.stopper is None or jid not in self.sched.jobs
+                                or not pj.stopper.should_stop()):
+                            continue
+                        dropped = self.sched.cancel_job(jid)
+                        if dropped:
+                            drained.add(jid)
+                            self.telemetry.emit("job_draining", job_id=jid,
+                                                n_cancelled=len(dropped))
+                            if pj.on_cancelled is not None:
+                                pj.on_cancelled(len(dropped))
+                        if jid not in self.sched.jobs and jid in self._jobs:
+                            # nothing left in flight anywhere: the drain
+                            # itself completed the job
+                            self._jobs.pop(jid, None)
+                            self._started_jobs.discard(jid)
+                            finished.append(pj)
+                    self._cond.notify_all()
+                if self.prefetcher is not None and drained:
+                    # evict the drained jobs' prefetched-but-never-claimed
+                    # fetches (their tasks will never execute)
+                    self.prefetcher.discard(lambda k: k[0] in drained)
+                if self.prefetcher is not None and finished:
+                    # evict finished jobs' never-claimed prefetches (a peer
+                    # can ensure() a task inline before our peeked prefetch
+                    # lands — without this sweep those futures leak for the
+                    # life of the service)
+                    gone = {pj.job_id for pj in finished}
+                    self.prefetcher.discard(lambda k: k[0] in gone)
             for pj in finished:
                 pj.on_done()
 

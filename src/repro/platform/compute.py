@@ -47,6 +47,7 @@ from typing import (Any, Callable, Dict, List, MutableSequence, Optional,
 import numpy as np
 
 from repro.kernels.subsample_gather import to_lane_layout
+from repro.platform import telemetry as tel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -382,24 +383,64 @@ class ShardedBlockArena(BlockArena):
 
 
 def run_map_task(block: np.ndarray, months: np.ndarray, seed: int,
-                 workload, engine: str) -> Dict[str, np.ndarray]:
+                 workload, engine: str,
+                 clock: Optional[tel.SpanClock] = None
+                 ) -> Dict[str, np.ndarray]:
     """One map task: subsample the block, compute the statistic partial.
 
     Partials are plain dicts of NumPy arrays so the reduce tree can combine
     them with element-wise addition regardless of engine or backend.
+    The device engines time their stages in host spans of ``clock``:
+    upload, dispatch, device wait, result fetch.
     """
     if engine == "jnp":
-        from repro.core import subsample as ss
-        return ss.run_map_task_np(block, months, seed, workload)
+        return _map_task_jnp(block, months, seed, workload,
+                             clock or tel.SpanClock())
     if engine == "pallas":
-        return _moments_pallas(block, seed, workload)
+        return _moments_pallas(block, seed, workload,
+                               clock or tel.SpanClock())
     if engine == "numpy":
         return _map_task_numpy(block, months, seed, workload)
     raise ValueError(f"unknown engine {engine!r}")
 
 
-def _moments_pallas(block: np.ndarray, seed: int,
-                    workload) -> Dict[str, np.ndarray]:
+def _wait(clock: tel.SpanClock, uploaded, out):
+    """Wait for a dispatch in two spans: its inputs landing on the device
+    (more ``platform.upload``: ``jnp.asarray`` returns once a copy is
+    enqueued, on a TPU v5e after 1-2 ms of a 1 GiB block's 100 ms, and
+    the device starts the dispatch as soon as they land), then its result
+    (``platform.device_wait``), so the host copy that follows times the
+    copy alone."""
+    import jax
+
+    with clock.span("platform.upload"):
+        jax.block_until_ready(uploaded)
+    with clock.span("platform.device_wait"):
+        return jax.block_until_ready(out)
+
+
+def _map_task_jnp(block: np.ndarray, months: np.ndarray, seed: int,
+                  workload, clock: tel.SpanClock) -> Dict[str, np.ndarray]:
+    """The jitted ``subsample.map_task`` on one uploaded block."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import subsample as ss
+
+    with clock.span("platform.upload"):
+        data, mo = jnp.asarray(block), jnp.asarray(months)
+    with clock.span("platform.dispatch"):
+        out = ss.map_task(data, mo, jax.random.PRNGKey(seed),
+                          draws=workload.draws,
+                          draw_size=workload.draw_size, grid=workload.grid,
+                          statistic=workload.statistic)
+    out = _wait(clock, (data, mo), out)
+    with clock.span("platform.fetch_result"):
+        return jax.tree.map(np.asarray, out)
+
+
+def _moments_pallas(block: np.ndarray, seed: int, workload,
+                    clock: tel.SpanClock) -> Dict[str, np.ndarray]:
     """Route the Pallas kernel in as the map-task compute: the random row
     gather + (Σ, Σ²) accumulation happen inside the stats-only
     ``repro.kernels.subsample_gather`` wave kernel, as a wave of one —
@@ -408,10 +449,15 @@ def _moments_pallas(block: np.ndarray, seed: int,
     import jax.numpy as jnp
 
     n_idx = workload.draws * workload.draw_size
-    stats = _moments_wave_device(
-        jnp.asarray(to_lane_layout(block)[None]), np.zeros(1, np.int32),
-        np.asarray([seed], np.int32), n_idx=n_idx)
-    return _split_moments(np.asarray(stats), n_idx, block.shape[-1])[0]
+    with clock.span("platform.upload"):
+        args = (jnp.asarray(to_lane_layout(block)[None]),
+                jnp.asarray(np.zeros(1, np.int32)),
+                jnp.asarray(np.asarray([seed], np.int32)))
+    with clock.span("platform.dispatch"):
+        stats = _wave_fn("moments")(*args, n=n_idx)
+    stats = _wait(clock, args, stats)
+    with clock.span("platform.fetch_result"):
+        return _split_moments(np.asarray(stats), n_idx, block.shape[-1])[0]
 
 
 def _split_moments(stats: np.ndarray, n_idx: int,
@@ -438,14 +484,14 @@ def _moments_wave_jit():
     from repro.kernels import ops
 
     @functools.partial(jax.jit, static_argnames=("n",))
-    def wave(arena, rows, seeds, *, n):
+    def moments_wave(arena, rows, seeds, *, n):
         ns = arena.shape[1]                         # [slots, count, L, 128]
         idx = jax.vmap(
             lambda s: jax.random.randint(jax.random.PRNGKey(s), (n,), 0,
                                          ns, dtype=jnp.int32))(seeds)
         return ops.subsample_stats_lanes(arena, rows, idx)
 
-    return wave
+    return moments_wave
 
 
 def _jnp_wave_jit():
@@ -461,8 +507,8 @@ def _jnp_wave_jit():
 
     @functools.partial(jax.jit, static_argnames=("draws", "draw_size",
                                                  "grid", "statistic"))
-    def wave(arena, arena_mo, rows, seeds, *, draws, draw_size, grid,
-             statistic):
+    def jnp_wave(arena, arena_mo, rows, seeds, *, draws, draw_size, grid,
+                 statistic):
         data = jnp.take(arena, rows, axis=0)
         months = jnp.take(arena_mo, rows, axis=0)
         keys = jax.vmap(jax.random.PRNGKey)(seeds)
@@ -470,7 +516,7 @@ def _jnp_wave_jit():
             d, m, k, draws=draws, draw_size=draw_size, grid=grid,
             statistic=statistic))(data, months, keys)
 
-    return wave
+    return jnp_wave
 
 
 def _moments_wave_sharded_jit(mesh):
@@ -488,7 +534,7 @@ def _moments_wave_sharded_jit(mesh):
     from repro.kernels import ops
 
     @functools.partial(jax.jit, static_argnames=("n",))
-    def wave(arena, rows, seeds, *, n):
+    def moments_wave_sharded(arena, rows, seeds, *, n):
         def per_device(a, r, s):
             # a: [per_dev, count, L, 128]; r, s: [1, width]
             ns = a.shape[1]
@@ -501,7 +547,7 @@ def _moments_wave_sharded_jit(mesh):
                              out_specs=P("wave"), check_vma=False)(
             arena, rows, seeds)
 
-    return wave
+    return moments_wave_sharded
 
 
 def _jnp_wave_sharded_jit(mesh):
@@ -518,8 +564,8 @@ def _jnp_wave_sharded_jit(mesh):
 
     @functools.partial(jax.jit, static_argnames=("draws", "draw_size",
                                                  "grid", "statistic"))
-    def wave(arena, arena_mo, rows, seeds, *, draws, draw_size, grid,
-             statistic):
+    def jnp_wave_sharded(arena, arena_mo, rows, seeds, *, draws, draw_size,
+                         grid, statistic):
         def per_device(a, m, r, s):
             data = jnp.take(a, r[0], axis=0)
             months = jnp.take(m, r[0], axis=0)
@@ -533,7 +579,7 @@ def _jnp_wave_sharded_jit(mesh):
                              out_specs=P("wave"), check_vma=False)(
             arena, arena_mo, rows, seeds)
 
-    return wave
+    return jnp_wave_sharded
 
 
 _WAVE_FNS: Dict[str, Any] = {}
@@ -562,26 +608,10 @@ def _sharded_wave_fn(kind: str, mesh):
     return _SHARDED_WAVE_FNS[key]
 
 
-def _moments_wave_device(arena_data, rows, seeds, *, n_idx: int):
-    import jax.numpy as jnp
-
-    return _wave_fn("moments")(arena_data, jnp.asarray(rows),
-                               jnp.asarray(seeds), n=n_idx)
-
-
-def _jnp_wave_device(arena_data, arena_months, rows, seeds, workload):
-    import jax.numpy as jnp
-
-    return _wave_fn("jnp")(arena_data, arena_months, jnp.asarray(rows),
-                           jnp.asarray(seeds), draws=workload.draws,
-                           draw_size=workload.draw_size,
-                           grid=workload.grid,
-                           statistic=workload.statistic)
-
-
 def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
-                 workload, engine: str,
-                 pad_to: Optional[int] = None) -> List[Dict[str, np.ndarray]]:
+                 workload, engine: str, pad_to: Optional[int] = None,
+                 clock: Optional[tel.SpanClock] = None,
+                 ) -> List[Dict[str, np.ndarray]]:
     """Execute a wave of same-shape tasks in one device dispatch and split
     the batched result back into per-task reduce-tree partials.
 
@@ -594,39 +624,58 @@ def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
     A :class:`ShardedBlockArena` routes to the multi-device dispatch —
     same signature, bit-identical results — so the wave closures in the
     driver, service pool and threaded backend need not know whether the
-    arena is sharded.
+    arena is sharded.  Both time their stages in host spans of ``clock``:
+    upload (the slot and seed vectors, built and sent), dispatch, device
+    wait, and result fetch (the copy and the split).
     """
     import jax
+    import jax.numpy as jnp
 
+    if engine not in ("pallas", "jnp"):
+        raise ValueError(
+            f"engine {engine!r} does not support wave execution")
     if isinstance(arena, ShardedBlockArena):
         return run_map_wave_sharded(arena, tasks, seeds, workload, engine,
-                                    pad_to=pad_to)
+                                    pad_to=pad_to, clock=clock)
 
-    key, rows = arena.slots(tasks)
-    b = len(rows)
-    b_pad = max(pad_to, b) if pad_to is not None else pow2_ceil(b)
-    seeds = np.asarray(seeds, np.int32)
-    if b_pad != b:
-        rows = np.concatenate([rows, np.repeat(rows[:1], b_pad - b)])
-        seeds = np.concatenate([seeds, np.repeat(seeds[:1], b_pad - b)])
+    clock = clock or tel.SpanClock()
+    with clock.span("platform.upload"):
+        key, rows = arena.slots(tasks)
+        b = len(rows)
+        b_pad = max(pad_to, b) if pad_to is not None else pow2_ceil(b)
+        seeds = np.asarray(seeds, np.int32)
+        if b_pad != b:
+            rows = np.concatenate([rows, np.repeat(rows[:1], b_pad - b)])
+            seeds = np.concatenate([seeds,
+                                    np.repeat(seeds[:1], b_pad - b)])
+        rows, seeds = jnp.asarray(rows), jnp.asarray(seeds)
     data, months = arena.bucket(key)
 
     if engine == "pallas":
         n_idx = workload.draws * workload.draw_size
-        stats = np.asarray(
-            _moments_wave_device(data, rows, seeds, n_idx=n_idx))
-        return _split_moments(stats[:b], n_idx, arena.width(key))
-    if engine == "jnp":
-        assert months is not None, "jnp waves need pack(lanes=False)"
-        out = _jnp_wave_device(data, months, rows, seeds, workload)
+        with clock.span("platform.dispatch"):
+            stats = _wave_fn("moments")(data, rows, seeds, n=n_idx)
+        stats = _wait(clock, (rows, seeds), stats)
+        with clock.span("platform.fetch_result"):
+            return _split_moments(np.asarray(stats)[:b], n_idx,
+                                  arena.width(key))
+    assert months is not None, "jnp waves need pack(lanes=False)"
+    with clock.span("platform.dispatch"):
+        out = _wave_fn("jnp")(data, months, rows, seeds,
+                              draws=workload.draws,
+                              draw_size=workload.draw_size,
+                              grid=workload.grid,
+                              statistic=workload.statistic)
+    out = _wait(clock, (rows, seeds), out)
+    with clock.span("platform.fetch_result"):
         out = jax.tree.map(np.asarray, out)
         return [jax.tree.map(lambda a: a[i], out) for i in range(b)]
-    raise ValueError(f"engine {engine!r} does not support wave execution")
 
 
 def run_map_wave_sharded(arena: ShardedBlockArena, tasks: Sequence,
                          seeds: np.ndarray, workload, engine: str,
                          pad_to: Optional[int] = None,
+                         clock: Optional[tel.SpanClock] = None,
                          ) -> List[Dict[str, np.ndarray]]:
     """Execute a wave across the arena's device mesh in one dispatch.
 
@@ -650,51 +699,60 @@ def run_map_wave_sharded(arena: ShardedBlockArena, tasks: Sequence,
     from repro.parallel import collectives as col
     from repro.parallel.sharding import wave_sharding
 
-    key, devs, local_rows = arena.dev_slots(tasks)
-    n_dev = arena.n_dev
-    b = len(tasks)
-    seeds = np.asarray(seeds, np.int32)
-    cap = pad_to if pad_to is not None else b
-    width = shard_wave_width(cap, n_dev)
-    occupancy = np.bincount(devs, minlength=n_dev)
-    if occupancy.max() > width:
-        width = pow2_ceil(int(occupancy.max()))
+    if engine not in ("pallas", "jnp"):
+        raise ValueError(
+            f"engine {engine!r} does not support wave execution")
+    clock = clock or tel.SpanClock()
+    with clock.span("platform.upload"):
+        key, devs, local_rows = arena.dev_slots(tasks)
+        n_dev = arena.n_dev
+        b = len(tasks)
+        seeds = np.asarray(seeds, np.int32)
+        cap = pad_to if pad_to is not None else b
+        width = shard_wave_width(cap, n_dev)
+        occupancy = np.bincount(devs, minlength=n_dev)
+        if occupancy.max() > width:
+            width = pow2_ceil(int(occupancy.max()))
 
-    rows = np.zeros((n_dev, width), np.int32)
-    lane_seeds = np.full((n_dev, width), seeds[0], np.int32)
-    fill = np.zeros(n_dev, np.int32)
-    place: List[Tuple[int, int]] = []
-    for i in range(b):
-        d = int(devs[i])
-        lane = int(fill[d])
-        fill[d] += 1
-        rows[d, lane] = local_rows[i]
-        lane_seeds[d, lane] = seeds[i]
-        place.append((d, lane))
+        rows = np.zeros((n_dev, width), np.int32)
+        lane_seeds = np.full((n_dev, width), seeds[0], np.int32)
+        fill = np.zeros(n_dev, np.int32)
+        place: List[Tuple[int, int]] = []
+        for i in range(b):
+            d = int(devs[i])
+            lane = int(fill[d])
+            fill[d] += 1
+            rows[d, lane] = local_rows[i]
+            lane_seeds[d, lane] = seeds[i]
+            place.append((d, lane))
 
-    sharding = wave_sharding(arena.mesh)
-    rows_dev = jax.device_put(rows, sharding)
-    seeds_dev = jax.device_put(lane_seeds, sharding)
+        sharding = wave_sharding(arena.mesh)
+        rows_dev = jax.device_put(rows, sharding)
+        seeds_dev = jax.device_put(lane_seeds, sharding)
     data, months = arena.bucket(key)
 
     if engine == "pallas":
         n_idx = workload.draws * workload.draw_size
-        out = _sharded_wave_fn("moments", arena.mesh)(
-            data, rows_dev, seeds_dev, n=n_idx)
-        stats = col.gather_shards(out)
-        picked = np.stack([stats[d, lane] for d, lane in place])
-        return _split_moments(picked, n_idx, arena.width(key))
-    if engine == "jnp":
-        assert months is not None, "jnp waves need pack(lanes=False)"
+        with clock.span("platform.dispatch"):
+            out = _sharded_wave_fn("moments", arena.mesh)(
+                data, rows_dev, seeds_dev, n=n_idx)
+        out = _wait(clock, (rows_dev, seeds_dev), out)
+        with clock.span("platform.fetch_result"):
+            stats = col.gather_shards(out)
+            picked = np.stack([stats[d, lane] for d, lane in place])
+            return _split_moments(picked, n_idx, arena.width(key))
+    assert months is not None, "jnp waves need pack(lanes=False)"
+    with clock.span("platform.dispatch"):
         out = _sharded_wave_fn("jnp", arena.mesh)(
             data, months, rows_dev, seeds_dev, draws=workload.draws,
             draw_size=workload.draw_size, grid=workload.grid,
             statistic=workload.statistic)
-        host = jax.tree.map(col.gather_shards, out)   # leaves [n_dev, w, ...]
+    out = _wait(clock, (rows_dev, seeds_dev), out)
+    with clock.span("platform.fetch_result"):
+        host = jax.tree.map(col.gather_shards, out)  # leaves [n_dev, w, ...]
         return [jax.tree.map(lambda a, d=d, lane=lane: np.asarray(a[d, lane]),
                              host)
                 for d, lane in place]
-    raise ValueError(f"engine {engine!r} does not support wave execution")
 
 
 def _map_task_numpy(block: np.ndarray, months: np.ndarray, seed: int,

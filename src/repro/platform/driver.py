@@ -518,11 +518,12 @@ class WaveContext:
         """The fixed padded wave width of this task's shape bucket."""
         return self.wave_pad[self.task_shape(task)]
 
-    def run(self, tasks: List[sch.Task],
-            seeds: np.ndarray) -> List[Dict[str, np.ndarray]]:
+    def run(self, tasks: List[sch.Task], seeds: np.ndarray,
+            clock: Optional[tel.SpanClock] = None
+            ) -> List[Dict[str, np.ndarray]]:
         return pc.run_map_wave(self.arena, tasks, seeds, self.workload,
-                               self.engine,
-                               pad_to=self.cap(tasks[0]))
+                               self.engine, pad_to=self.cap(tasks[0]),
+                               clock=clock)
 
     def wave_bytes(self, n: int) -> float:
         """Host→device traffic of an n-task wave: slot + seed vectors

@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from repro.platform import telemetry as tel
+
 
 def tree_add(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:
     """Default combine: element-wise sum of dict-of-array partials."""
@@ -39,13 +41,20 @@ class StreamingReduceTree:
     it is combined in; :meth:`estimate` then surfaces the running
     online-aggregation snapshot (value + CI + tasks_in) without
     disturbing the bit-identical full-reduce path (DESIGN.md §10).
+
+    ``clock`` — a :class:`~repro.platform.telemetry.SpanClock` — times
+    each leaf's observe and combines in a ``platform.reduce`` span; its
+    seconds are complete once :meth:`result` returns.
     """
 
     def __init__(self, n_leaves: int,
                  combine: Callable[[Any, Any], Any] = tree_add,
-                 estimator: Optional[Any] = None):
+                 estimator: Optional[Any] = None,
+                 clock: Optional[tel.SpanClock] = None):
         assert n_leaves >= 1
         self.n_leaves = n_leaves
+        # each leaf's combines run in a ``platform.reduce`` host span
+        self.clock = clock or tel.SpanClock()
         self._combine = combine
         self._estimator = estimator
         # level sizes: n, ceil(n/2), ... 1
@@ -84,11 +93,12 @@ class StreamingReduceTree:
                 if leaf in seen:               # speculative re-execution dup
                     continue
                 seen.add(leaf)
-                if self._estimator is not None:
-                    self._estimator.observe(leaf, partial)
-                with self._node_lock:
-                    self._insert(0, leaf, partial)
-                    self.leaves_seen = len(seen)
+                with self.clock.span("platform.reduce"):
+                    if self._estimator is not None:
+                        self._estimator.observe(leaf, partial)
+                    with self._node_lock:
+                        self._insert(0, leaf, partial)
+                        self.leaves_seen = len(seen)
                 with self._leaf_cond:
                     self._leaf_cond.notify_all()
         except BaseException as e:             # noqa: BLE001

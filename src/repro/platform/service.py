@@ -47,7 +47,7 @@ import itertools
 import threading
 import time
 import warnings
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -207,38 +207,41 @@ class DatasetHandle:
             qc = self._classes.get(key)
             if qc is not None:
                 return qc, False
-        knee_res, knee = self.cached_knee(
-            workload, engine=engine, sizing=sizing,
-            kneepoint_sizes=spec.kneepoint_sizes)
-        with self._lock:
-            qc = self._classes.get(key)
-            if qc is not None:                     # raced: peer built it
-                return qc, False
-            plan = plan_job(self.samples, self.months, workload,
-                            sizing=sizing, engine=engine, n_exec=n_exec,
-                            knee_bytes=knee,
-                            kneepoint_sizes=spec.kneepoint_sizes)
-            plan.knee_res = plan.knee_res or knee_res
-            qc = QueryClass(uid=next(_CLASS_UID), plan=plan,
-                            workload=workload, engine=engine)
-            if wave_on:
-                qc.wave_ctx = build_wave_context(
-                    plan, workload, n_exec=n_exec, max_wave=spec.max_wave,
-                    warm_seed=spec.seed,
-                    mesh=resolve_wave_mesh(spec, wave_on))
-                qc.arena_bytes = qc.wave_ctx.arena.nbytes
-            elif engine in ("jnp", "pallas"):
-                # per-task warmup: compile one kernel per distinct shape
-                seen = set()
-                for task in plan.tasks:
-                    shape = plan.task_shape(task)
-                    if shape not in seen:
-                        seen.add(shape)
-                        block, mo = qc.block(task)
-                        pc.run_map_task(block, mo, spec.seed + task.task_id,
-                                        workload, engine)
-            self._classes[key] = qc
-            return qc, True
+        # set-up, once per class: the knee, plan_job, the arena's pack
+        # and upload, and the warm-up compile
+        with tel.span("platform.query_class"):
+            knee_res, knee = self.cached_knee(
+                workload, engine=engine, sizing=sizing,
+                kneepoint_sizes=spec.kneepoint_sizes)
+            with self._lock:
+                qc = self._classes.get(key)
+                if qc is not None:                     # raced: peer built it
+                    return qc, False
+                plan = plan_job(self.samples, self.months, workload,
+                                sizing=sizing, engine=engine, n_exec=n_exec,
+                                knee_bytes=knee,
+                                kneepoint_sizes=spec.kneepoint_sizes)
+                plan.knee_res = plan.knee_res or knee_res
+                qc = QueryClass(uid=next(_CLASS_UID), plan=plan,
+                                workload=workload, engine=engine)
+                if wave_on:
+                    qc.wave_ctx = build_wave_context(
+                        plan, workload, n_exec=n_exec, max_wave=spec.max_wave,
+                        warm_seed=spec.seed,
+                        mesh=resolve_wave_mesh(spec, wave_on))
+                    qc.arena_bytes = qc.wave_ctx.arena.nbytes
+                elif engine in ("jnp", "pallas"):
+                    # per-task warmup: compile one kernel per distinct shape
+                    seen = set()
+                    for task in plan.tasks:
+                        shape = plan.task_shape(task)
+                        if shape not in seen:
+                            seen.add(shape)
+                            block, mo = qc.block(task)
+                            pc.run_map_task(block, mo, spec.seed + task.task_id,
+                                            workload, engine)
+                self._classes[key] = qc
+                return qc, True
 
 
 class PartialEstimate(dict):
@@ -285,6 +288,10 @@ class JobTicket:
         self.finished_at: Optional[float] = None
         self.bytes_uploaded = 0.0
         self.device_dispatches = 0               # waves this job rode in
+        # host seconds of this job's spans per span name (a shared wave's
+        # split by task share); finalize lands just after completion
+        self.span_seconds: Dict[str, float] = {}
+        self._span_lock = threading.Lock()
         self.tree: Optional[StreamingReduceTree] = None
         self.cancel_requested = False      # set before pool.cancel fires
         # error-bounded approximate execution (DESIGN.md §10)
@@ -301,6 +308,13 @@ class JobTicket:
         self.final_ci: Optional[Dict[str, Any]] = None
         self._result: Optional[dict] = None
         self._done = threading.Event()
+
+    def charge(self, seconds: Dict[str, float], share: float = 1.0) -> None:
+        """Add ``share`` of a span clock's seconds to this job's."""
+        with self._span_lock:
+            for name, sec in seconds.items():
+                self.span_seconds[name] = (self.span_seconds.get(name, 0.0)
+                                           + sec * share)
 
     # -- poll ---------------------------------------------------------------
     def progress(self) -> Tuple[int, int]:
@@ -464,6 +478,7 @@ class PlatformService:
         self._active: Dict[int, JobTicket] = {}
         self._waiting: deque = deque()         # (ticket, submit closure args)
         self._job_seq = itertools.count()
+        self._wave_seq = itertools.count()     # span id of each pool wave
         self._ds_seq = itertools.count()
         self._closed = False
         self._register_sampler_providers()
@@ -613,68 +628,72 @@ class PlatformService:
                                           checkpoint_dir=checkpoint_dir,
                                           resume_from=resume_from)
 
-        wave_on = wave_enabled(self.spec, engine, workload)
-        # validated on EVERY submit (not just the arena-building one):
-        # mesh_devices without wave execution must error, never silently
-        # run an unsharded per-task job
-        resolve_wave_mesh(self.spec, wave_on)
-        qc, built_now = handle.query_class(
-            workload, spec=self.spec, engine=engine,
-            sizing=self.plat.task_sizing, n_exec=self.spec.n_workers,
-            wave_on=wave_on)
-        # resume (DESIGN.md §12): restore committed leaf partials up
-        # front — a stale checkpoint must fail the submit, not a pool
-        # worker — and hand only the missing tasks to the pool
-        restored: Dict[int, Dict[str, Any]] = {}
-        if resume_from is not None:
-            restored, ckpt_n = JobCheckpointer.load(resume_from)
-            if ckpt_n is not None and ckpt_n != len(qc.plan.tasks):
-                raise ValueError(
-                    f"checkpoint at {resume_from!r} holds partials for "
-                    f"{ckpt_n} tasks but this query class has "
-                    f"{len(qc.plan.tasks)} — resume needs the same "
-                    "dataset, workload, sizing and knee")
-        ticket = JobTicket(next(self._job_seq), handle, workload,
-                           len(qc.plan.tasks), workload.statistic, seed)
-        ticket.epsilon, ticket.confidence = eff_epsilon, eff_conf
-        ticket.min_tasks = eff_min
-        if built_now:
-            self.telemetry.emit("arena_upload", nbytes=qc.arena_bytes,
-                                job_id=ticket.job_id)
-            ticket.bytes_uploaded += qc.arena_bytes
-        self._tickets[ticket.job_id] = ticket
+        # the admission verdict and query-class lookup (on a class's first
+        # query, its plan, pack, upload and warm-up) in one host span
+        job_id = next(self._job_seq)
+        with self.telemetry.span("platform.admit", job=job_id):
+            wave_on = wave_enabled(self.spec, engine, workload)
+            # validated on EVERY submit (not just the arena-building one):
+            # mesh_devices without wave execution must error, never silently
+            # run an unsharded per-task job
+            resolve_wave_mesh(self.spec, wave_on)
+            qc, built_now = handle.query_class(
+                workload, spec=self.spec, engine=engine,
+                sizing=self.plat.task_sizing, n_exec=self.spec.n_workers,
+                wave_on=wave_on)
+            # resume (DESIGN.md §12): restore committed leaf partials up
+            # front — a stale checkpoint must fail the submit, not a pool
+            # worker — and hand only the missing tasks to the pool
+            restored: Dict[int, Dict[str, Any]] = {}
+            if resume_from is not None:
+                restored, ckpt_n = JobCheckpointer.load(resume_from)
+                if ckpt_n is not None and ckpt_n != len(qc.plan.tasks):
+                    raise ValueError(
+                        f"checkpoint at {resume_from!r} holds partials for "
+                        f"{ckpt_n} tasks but this query class has "
+                        f"{len(qc.plan.tasks)} — resume needs the same "
+                        "dataset, workload, sizing and knee")
+            ticket = JobTicket(job_id, handle, workload,
+                               len(qc.plan.tasks), workload.statistic, seed)
+            ticket.epsilon, ticket.confidence = eff_epsilon, eff_conf
+            ticket.min_tasks = eff_min
+            if built_now:
+                self.telemetry.emit("arena_upload", nbytes=qc.arena_bytes,
+                                    job_id=ticket.job_id)
+                ticket.bytes_uploaded += qc.arena_bytes
+            self._tickets[ticket.job_id] = ticket
 
-        abs_deadline = (None if deadline is None
-                        else time.monotonic() + deadline)
-        with self._admission_lock:
-            if self._closed:       # close() raced the entry check above
-                self._tickets.pop(ticket.job_id, None)
-                raise RuntimeError("service is closed")
-            verdict = self._admission_verdict(ticket, deadline)
-            # an slo verdict is final (waiting longer cannot meet the
-            # deadline); capacity verdicts queue unless the mode sheds
-            reject_now = (verdict is not None
-                          and (self.admission.mode == "shed"
-                               or verdict[0] == "slo"))
+            abs_deadline = (None if deadline is None
+                            else time.monotonic() + deadline)
+            with self._admission_lock:
+                if self._closed:       # close() raced the entry check above
+                    self._tickets.pop(ticket.job_id, None)
+                    raise RuntimeError("service is closed")
+                verdict = self._admission_verdict(ticket, deadline)
+                # an slo verdict is final (waiting longer cannot meet the
+                # deadline); capacity verdicts queue unless the mode sheds
+                reject_now = (verdict is not None
+                              and (self.admission.mode == "shed"
+                                   or verdict[0] == "slo"))
+                if verdict is None:
+                    with self._lock:               # reserve the slot atomically
+                        self._active[ticket.job_id] = ticket
+                elif not reject_now:
+                    with self._lock:
+                        self._waiting.append(
+                            (ticket,
+                             (handle, qc, priority, abs_deadline, weight,
+                              checkpoint_dir, restored)))
             if verdict is None:
-                with self._lock:               # reserve the slot atomically
-                    self._active[ticket.job_id] = ticket
-            elif not reject_now:
-                with self._lock:
-                    self._waiting.append(
-                        (ticket,
-                         (handle, qc, priority, abs_deadline, weight,
-                          checkpoint_dir, restored)))
-        if verdict is None:
-            self._admit(ticket, handle, qc, priority, abs_deadline, weight,
-                        checkpoint_dir=checkpoint_dir,
-                        restored=restored)
-        elif reject_now:
-            self._finish(ticket, REJECTED, reason=verdict[1])
-        else:
-            self.telemetry.emit("job_queued", job_id=ticket.job_id,
-                                reason=verdict[1])
-        return ticket
+                self._admit(ticket, handle, qc, priority, abs_deadline, weight,
+                            checkpoint_dir=checkpoint_dir,
+                            restored=restored)
+            elif reject_now:
+                self._finish(ticket, REJECTED, reason=verdict[1])
+            else:
+                self.telemetry.emit("job_queued", job_id=ticket.job_id,
+                                    reason=verdict[1])
+            return ticket
 
     def _admission_verdict(self, ticket: JobTicket,
                            deadline: Optional[float], *,
@@ -743,8 +762,9 @@ class PlatformService:
         # for free); only an epsilon target adds the stopping rule
         ticket.estimator = est_mod.SubsampleEstimator(ticket.statistic,
                                                       ticket.confidence)
-        ticket.tree = StreamingReduceTree(len(qc.plan.tasks),
-                                          estimator=ticket.estimator)
+        ticket.tree = StreamingReduceTree(
+            len(qc.plan.tasks), estimator=ticket.estimator,
+            clock=tel.SpanClock(job=ticket.job_id))
         if ticket.epsilon is not None:
             ticket.stopper = est_mod.StoppingController(
                 ticket.estimator, ticket.epsilon,
@@ -888,20 +908,25 @@ class PlatformService:
                 tasks = [t for _, t in items]
                 seeds = np.asarray([pj.seed + t.task_id
                                     for pj, t in items], np.int32)
+                jobs = tuple(pj.job_id for pj, _ in items)
+                clock = tel.SpanClock(
+                    jobs=",".join(map(str, dict.fromkeys(jobs))),
+                    wave=next(self._wave_seq))
                 t_wave = self.telemetry.now()
-                values = qc.wave_ctx.run(tasks, seeds)
+                values = qc.wave_ctx.run(tasks, seeds, clock=clock)
                 nbytes = qc.wave_ctx.wave_bytes(len(items))
                 self.telemetry.emit(
                     "wave_dispatched", ts=t_wave, wave_size=len(items),
                     nbytes=nbytes,
                     seconds=self.telemetry.now() - t_wave,
-                    job_ids=tuple(pj.job_id for pj, _ in items),
+                    job_ids=jobs,
                     task_ids=tuple(t.task_id for _, t in items))
-                for jid in dict.fromkeys(pj.job_id for pj, _ in items):
+                for jid, n in Counter(jobs).items():
                     t = self._tickets.get(jid)
                     if t is not None:
                         t.device_dispatches += 1
                         t.bytes_uploaded += nbytes
+                        t.charge(clock.seconds, n / len(items))
                 return values
             return run_batch
 
@@ -920,8 +945,14 @@ class PlatformService:
                     if t is not None:
                         t.device_dispatches += 1
                         t.bytes_uploaded += nbytes
-                out.append(pc.run_map_task(block, mo, pj.seed + task.task_id,
-                                           qc.workload, qc.engine))
+                clock = tel.SpanClock(job=pj.job_id, task=task.task_id)
+                out.append(pc.run_map_task(block, mo,
+                                           pj.seed + task.task_id,
+                                           qc.workload, qc.engine,
+                                           clock=clock))
+                t = self._tickets.get(pj.job_id)
+                if t is not None:
+                    t.charge(clock.seconds)
             return out
         return run_batch
 
@@ -929,6 +960,14 @@ class PlatformService:
     def _on_job_done(self, ticket: JobTicket) -> None:
         if ticket.status != RUNNING:       # cancelled while in flight
             return
+        clock = tel.SpanClock(job=ticket.job_id)
+        with clock.span("platform.finalize"):
+            self._finalize(ticket)
+        ticket.charge(clock.seconds)
+
+    def _finalize(self, ticket: JobTicket) -> None:
+        """The root result, the finalized statistic, and the ticket's
+        completion."""
         try:
             if ticket.checkpointer is not None:
                 # surface any parked async-save error: a job that "ran"
@@ -947,6 +986,8 @@ class PlatformService:
                 tree.close()
             else:
                 root = tree.result(timeout=600.0)
+            # a copy: after a drain the combiner thread may still add
+            ticket.charge(tree.clock.seconds.copy())
             ticket._result = finalize_stats(root, ticket.statistic)
         except BaseException as e:         # noqa: BLE001
             self._on_job_error(ticket, e)

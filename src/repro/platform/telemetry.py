@@ -39,6 +39,12 @@ On top of the recorded stream:
 * :func:`render_report` — a dependency-free, self-contained HTML report
   per job / service session.
 
+Host spans (DESIGN.md §13.7) are the second view: :func:`span` names a
+stage of the served path from the closed :data:`SPAN_NAMES` as a
+``jax.profiler.TraceAnnotation``, so a profiler trace holds them on the
+device's timeline, and :class:`SpanClock` sums each span's seconds per
+unit of work whether a trace is active or not.
+
 Clocks: the default timestamp is wall time relative to bus creation
 (``time.perf_counter``).  The simulated backend runs in *virtual* time,
 so its emit sites pass ``ts=`` explicitly and the bus is built with
@@ -49,6 +55,7 @@ wall time, keeping per-seed event streams deterministic.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import html as _html
 import json
@@ -63,9 +70,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 EVENT_KINDS = frozenset((
     # task lifecycle (both schedulers)
-    "task_claimed", "task_started", "task_settled",
+    "task_claimed", "task_settled",
     # device dispatches (driver + service compute closures)
-    "task_dispatched", "wave_dispatched", "wave_settled", "arena_upload",
+    "task_dispatched", "wave_dispatched", "arena_upload",
     "prefetch_stats",
     # data plane, per replica
     "fetch_start", "fetch_done", "fetch_failed", "node_state_change",
@@ -76,7 +83,7 @@ EVENT_KINDS = frozenset((
     "checkpoint_saved", "checkpoint_restored", "fault_fired",
     # job / service lifecycle
     "job_planned", "job_admitted", "job_queued", "job_rejected",
-    "job_draining", "job_degraded", "job_done", "job_failed",
+    "job_draining", "job_done", "job_failed",
     "job_cancelled",
     # error-bounded execution (§10)
     "ci_snapshot",
@@ -85,6 +92,63 @@ EVENT_KINDS = frozenset((
     # sampler rows
     "sample",
 ))
+
+# host spans of the served path (DESIGN.md §13.7), one per layer
+# boundary; recorded by the JAX profiler on the device trace's timeline
+SPAN_NAMES = frozenset((
+    "platform.admit", "platform.query_class", "platform.claim",
+    "platform.emulated_overhead", "platform.upload", "platform.dispatch",
+    "platform.device_wait", "platform.fetch_result", "platform.settle",
+    "platform.reduce", "platform.finalize",
+))
+
+_NO_JAX = object()
+_annotation: Any = None        # jax.profiler.TraceAnnotation, or _NO_JAX
+
+
+def span(name: str, **ids: Any):
+    """A named host span: ``jax.profiler.TraceAnnotation(name, **ids)``,
+    which the profiler records (on the device trace's clock) only while
+    a trace is active — otherwise it costs a name check and a no-op
+    ``TraceMe``.  ``ids`` (``job``/``jobs``, ``wave``, ``worker``, ...)
+    land as the event's stats, so the spans of one job share an
+    identifier.  A null context where JAX is absent."""
+    global _annotation
+    if name not in SPAN_NAMES:
+        raise ValueError(f"unknown telemetry span name {name!r}")
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = _NO_JAX
+    if _annotation is _NO_JAX:
+        return contextlib.nullcontext()
+    return _annotation(name, **ids)
+
+
+class SpanClock:
+    """The host spans of one unit of work (a task, a wave, a job's reduce
+    tree): each is entered with the unit's ``ids``, and the seconds each
+    span name took are summed in :attr:`seconds` whether a trace is
+    active or not — the spans' aggregation path, as ``_aggregate`` is
+    the events'.  One thread enters a clock's spans at a time."""
+
+    def __init__(self, **ids: Any):
+        self.ids = ids
+        self.seconds: Dict[str, float] = {}
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        annotation = span(name, **self.ids)     # raises on an unknown name
+        t0 = time.perf_counter()
+        try:
+            with annotation:
+                yield
+        finally:
+            self.seconds[name] = (self.seconds.get(name, 0.0)
+                                  + time.perf_counter() - t0)
+
 
 # fixed histogram buckets (seconds) — powers of ~4 from 100 µs to 25 s;
 # fixed so snapshots from different runs are mergeable/comparable
@@ -324,6 +388,15 @@ class TelemetryBus:
                       else (self.now() if taps else 0.0))
         for tap in taps:
             tap(kind, tap_ts, fields)
+
+    def span(self, name: str, **ids: Any):
+        """The module's :func:`span`, or a null context on a virtual bus
+        (simulated time has no place on the device trace's clock)."""
+        if self.virtual:
+            if name not in SPAN_NAMES:
+                raise ValueError(f"unknown telemetry span name {name!r}")
+            return contextlib.nullcontext()
+        return span(name, **ids)
 
     # -- the ONE aggregation path -------------------------------------------
     def _aggregate(self, kind: str, f: Dict[str, Any]) -> None:

@@ -10,6 +10,8 @@ import numpy as np
 import pytest
 
 from repro.core import scheduler as sch
+from repro.core.subsample import SubsampleWorkload
+from repro.platform import telemetry as tel
 from repro.platform import (
     EVENT_KINDS,
     Event,
@@ -504,7 +506,97 @@ def test_event_kinds_table_matches_design_doc():
     with open(path) as fh:
         doc = fh.read()
     section = doc.split("### §13.6 EVENT_KINDS reference", 1)[1]
-    section = section.split("\n## ", 1)[0]
+    section = section.split("\n### ", 1)[0].split("\n## ", 1)[0]
     documented = set(re.findall(r"^\| `([a-z_]+)` \|", section,
                                 flags=re.MULTILINE))
     assert documented == set(EVENT_KINDS)
+
+
+def test_span_names_table_matches_design_doc():
+    import re
+    path = os.path.join(os.path.dirname(__file__), "..", "DESIGN.md")
+    with open(path) as fh:
+        doc = fh.read()
+    section = doc.split("### §13.7 SPAN_NAMES reference", 1)[1]
+    section = section.split("\n### ", 1)[0].split("\n## ", 1)[0]
+    documented = set(re.findall(r"^\| `([a-z_.]+)` \|", section,
+                                flags=re.MULTILINE))
+    assert documented == set(tel.SPAN_NAMES)
+
+
+# -- host spans on the device trace (DESIGN.md §13.7) -------------------------
+
+
+@pytest.mark.parametrize("make", [lambda: tel.span("platform.uploads"),
+                                  lambda: null_bus().span("dispatch"),
+                                  lambda: TelemetryBus(virtual=True).span(
+                                      "platform.nope"),
+                                  lambda: tel.SpanClock().span(
+                                      "bench.window").__enter__()])
+def test_span_rejects_unknown_name(make):
+    with pytest.raises(ValueError, match="unknown telemetry span"):
+        make()
+
+
+def test_span_is_a_null_context_on_a_virtual_bus():
+    import contextlib
+
+    import jax
+
+    assert isinstance(TelemetryBus(virtual=True).span("platform.upload"),
+                      contextlib.nullcontext)
+    assert isinstance(null_bus().span("platform.upload", job=1),
+                      jax.profiler.TraceAnnotation)
+
+
+def test_span_clock_sums_seconds_per_name():
+    import time
+
+    clock = tel.SpanClock(job=3)
+    for _ in range(2):
+        with clock.span("platform.upload"):
+            time.sleep(0.01)
+    with pytest.raises(RuntimeError):
+        with clock.span("platform.dispatch"):
+            raise RuntimeError("a failed dispatch still took its time")
+    assert set(clock.seconds) == {"platform.upload", "platform.dispatch"}
+    assert clock.seconds["platform.upload"] >= 0.02
+
+
+PER_TASK_ALOD = SubsampleWorkload("alod", "alod", draws=30, draw_size=256,
+                                  grid=16)
+
+
+@pytest.mark.parametrize("workload", [PER_TASK_ALOD, WL],
+                         ids=["per-task-jnp", "pallas-wave"])
+def test_served_path_emits_every_span_on_the_trace(workload, tmp_path):
+    """One threaded service job of each kind under ``jax.profiler`` on the
+    CPU: its ``.xplane.pb`` holds every span name, and the job's ticket
+    is charged the stages the benchmark reads."""
+    import glob
+
+    import jax
+
+    from bench import program_spans
+
+    samples, months = _dataset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with PlatformService(_spec()) as svc:
+            h = svc.register_dataset(samples, months)
+            first = svc.submit(h, workload, seed=1)
+            first.result(timeout=300)
+            ticket = svc.submit(h, workload, seed=2)
+            ticket.result(timeout=300)
+    finally:
+        jax.profiler.stop_trace()
+    # one dispatch per task on the per-task path, fewer on the wave path
+    assert ((ticket.device_dispatches == ticket.n_tasks)
+            == (workload is PER_TASK_ALOD))
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {name for name, _, _ in program_spans.read_spans(path)}
+    assert names == set(tel.SPAN_NAMES)
+    for name in ("platform.upload", "platform.fetch_result",
+                 "platform.reduce", "platform.finalize"):
+        assert ticket.span_seconds[name] > 0, name
+
