@@ -40,7 +40,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import threading
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -172,6 +172,45 @@ class EstimateSnapshot:
         return bool(np.all(ok))
 
 
+_BLOCK = 1 << 15
+"""Columns per block of :meth:`SubsampleEstimator.estimate`: 2^15
+float64 components are 256 KiB a buffer, so a block's working set stays
+in a core's L2 cache."""
+
+
+def _block_mean_sd(thetas: List[np.ndarray], lo: int, hi: int,
+                   value: np.ndarray, sd: Optional[np.ndarray],
+                   acc: np.ndarray, tmp: np.ndarray) -> None:
+    """``value[lo:hi]`` and, unless ``sd`` is None, ``sd[lo:hi]`` of the
+    estimates over columns ``[lo, hi)``.  This is the ufunc sequence of
+    numpy's ``_mean`` and ``_var`` (ddof=1) along axis 0 of the stacked
+    ``[k, D > 1]`` array, which sums each column row by row in row
+    order, so the bits are theirs."""
+    if value.size == 1:
+        # numpy sums a lone column pairwise, not row by row: reduce it
+        # as the one [k, 1] array it is
+        col = np.stack(thetas)
+        col.mean(axis=0, out=value)
+        if sd is not None:
+            col.std(axis=0, ddof=1, out=sd)
+        return
+    k, v = len(thetas), value[lo:hi]
+    np.copyto(acc, thetas[0][lo:hi])
+    for theta in thetas[1:]:
+        np.add(acc, theta[lo:hi], out=acc)
+    np.true_divide(acc, k, out=v)
+    if sd is None:
+        return
+    np.subtract(thetas[0][lo:hi], v, out=acc)
+    np.multiply(acc, acc, out=acc)
+    for theta in thetas[1:]:
+        np.subtract(theta[lo:hi], v, out=tmp)
+        np.multiply(tmp, tmp, out=tmp)
+        np.add(acc, tmp, out=acc)
+    np.true_divide(acc, k - 1, out=acc)
+    np.sqrt(acc, out=sd[lo:hi])
+
+
 class SubsampleEstimator:
     """Incremental cross-task estimate accumulator.
 
@@ -220,30 +259,53 @@ class SubsampleEstimator:
 
     def estimate(self) -> Optional[EstimateSnapshot]:
         """The current snapshot, or ``None`` before the first usable
-        task (or for an unsupported statistic)."""
+        task (or for an unsupported statistic).
+
+        Computed in column blocks of :data:`_BLOCK` with scratch reused
+        across blocks, never through a stacked ``[k, D]`` copy: every
+        component is reduced on its own, in sorted-id order, so the
+        snapshot is bit for bit what ``np.mean``/``np.std(ddof=1)`` over
+        the stacked estimates give."""
         with self._lock:
             if not self._theta:
                 return None
-            thetas = np.stack([self._theta[i] for i in sorted(self._theta)])
-        k = thetas.shape[0]
-        # a component only has a variance estimate when EVERY observed
-        # task produced it; partially-supported components stay NaN
-        value = thetas.mean(axis=0)
-        if k < 2:
-            half = np.full_like(value, np.inf)
-        else:
-            sd = thetas.std(axis=0, ddof=1)
-            # simultaneous band: Bonferroni over the D valid components
-            # (D=1 reduces to the plain two-sided z)
-            d = int(np.count_nonzero(~np.isnan(sd)))
-            z = (normal_ppf(1.0 - (1.0 - self.confidence) / (2.0 * d))
-                 if d else self._z)
-            half = z * sd / math.sqrt(k)
-        valid = ~np.isnan(half)
-        width = float(np.max(half[valid])) if valid.any() else math.inf
+            # observe() replaces entries and never writes into an array,
+            # so these references stay valid outside the lock
+            thetas = [self._theta[i] for i in sorted(self._theta)]
+        k, n = len(thetas), thetas[0].size
+        value, ci_low, ci_high = np.empty(n), np.empty(n), np.empty(n)
+        sd = ci_low                 # holds s(θ̂ᵢ) until pass 2 replaces it
+        acc, tmp = np.empty(min(n, _BLOCK)), np.empty(min(n, _BLOCK))
+        blocks = [(lo, min(lo + _BLOCK, n)) for lo in range(0, n, _BLOCK)]
+        # pass 1: mean and spread per block.  A component only has a
+        # variance estimate when EVERY observed task produced it;
+        # partially-supported components stay NaN
+        d = 0
+        for lo, hi in blocks:
+            _block_mean_sd(thetas, lo, hi, value, sd if k > 1 else None,
+                           acc[:hi - lo], tmp[:hi - lo])
+            if k > 1:
+                d += hi - lo - int(np.count_nonzero(np.isnan(sd[lo:hi])))
+        # simultaneous band: Bonferroni over the D valid components
+        # (D=1 reduces to the plain two-sided z)
+        z = (normal_ppf(1.0 - (1.0 - self.confidence) / (2.0 * d))
+             if d else self._z)
+        # pass 2: the band, and its widest half over non-NaN components
+        width = np.nan
+        for lo, hi in blocks:
+            half = tmp[:hi - lo]
+            if k < 2:
+                half.fill(np.inf)
+            else:
+                np.multiply(z, sd[lo:hi], out=half)
+                np.true_divide(half, math.sqrt(k), out=half)
+            width = np.fmax(width, np.fmax.reduce(half))
+            np.subtract(value[lo:hi], half, out=ci_low[lo:hi])
+            np.add(value[lo:hi], half, out=ci_high[lo:hi])
         return EstimateSnapshot(
-            value=value, ci_low=value - half, ci_high=value + half,
-            half_width=width, tasks_in=k, confidence=self.confidence)
+            value=value, ci_low=ci_low, ci_high=ci_high,
+            half_width=math.inf if np.isnan(width) else float(width),
+            tasks_in=k, confidence=self.confidence)
 
 
 # ---------------------------------------------------------------------------

@@ -3,13 +3,17 @@ estimator math, reduce-tree streaming estimates under concurrency and
 mid-job cancellation, scheduler cancel plumbing, and early termination
 end-to-end through the driver and the service."""
 
+import math
 import threading
 import time
+import tracemalloc
 
 import numpy as np
 import pytest
 
 from repro.core.estimator import (
+    _BLOCK,
+    EXTRACTORS,
     EstimateSnapshot,
     ReplayStopper,
     StoppingController,
@@ -138,6 +142,81 @@ def test_simultaneous_band_widens_with_dimensionality():
     w1 = one.estimate().ci_high[0] - one.estimate().ci_low[0]
     w64 = many.estimate().ci_high[0] - many.estimate().ci_low[0]
     assert w64 > w1 * 1.3
+
+
+def _estimate_full_width(thetas, confidence):
+    """The snapshot through the stacked [k, D] array and full-width
+    temporaries: the formula the column-block estimate() must match."""
+    stack = np.stack(thetas)
+    k = stack.shape[0]
+    value = stack.mean(axis=0)
+    if k < 2:
+        half = np.full_like(value, np.inf)
+    else:
+        sd = stack.std(axis=0, ddof=1)
+        d = int(np.count_nonzero(~np.isnan(sd)))
+        z = (normal_ppf(1.0 - (1.0 - confidence) / (2.0 * d))
+             if d else z_for_confidence(confidence))
+        half = z * sd / math.sqrt(k)
+    valid = ~np.isnan(half)
+    width = float(np.max(half[valid])) if valid.any() else math.inf
+    return value, value - half, value + half, width
+
+
+def _stat_partial(statistic, rng, d):
+    """One task's partial with D = d components; ``monthly_mean`` and
+    ``alod`` leave some components undrawn (months no task drew, cells
+    no draw hit), which the snapshot carries as NaN."""
+    if statistic == "moments":
+        return _moments_partial(rng, d=d)
+    counts = rng.integers(0, 4, d).astype(np.float64)
+    counts[::5] = 0.0                       # never drawn by any task
+    sums = rng.normal(3.0, 1.0, d) * counts
+    if statistic == "monthly_mean":
+        return {"sum": sums, "count": counts}
+    return {"sum_curve": np.abs(sums), "hits": counts}
+
+
+@pytest.mark.parametrize("d", [1, 64, 120, _BLOCK - 1, _BLOCK, _BLOCK + 1,
+                               3 * _BLOCK + 5])
+@pytest.mark.parametrize("k", [1, 2, 3, 7, 9])
+@pytest.mark.parametrize("statistic", ["moments", "monthly_mean", "alod"])
+def test_estimate_bit_identical_to_full_width(statistic, k, d):
+    rng = np.random.default_rng(1000 * k + d)
+    est = SubsampleEstimator(statistic, confidence=0.9)
+    partials = {tid: _stat_partial(statistic, rng, d)
+                for tid in rng.permutation(3 * k)[:k].tolist()}
+    for tid, partial in partials.items():
+        est.observe(tid, partial)
+    thetas = [EXTRACTORS[statistic](partials[t]).reshape(-1)
+              for t in sorted(partials)]
+    value, ci_low, ci_high, width = _estimate_full_width(thetas, 0.9)
+    snap = est.estimate()
+    for got, want in ((snap.value, value), (snap.ci_low, ci_low),
+                      (snap.ci_high, ci_high)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got, want, equal_nan=True)
+    assert snap.half_width == width
+    assert snap.tasks_in == k and snap.confidence == 0.9
+
+
+def test_estimate_does_not_stack_the_task_estimates():
+    # the outputs (three D-vectors) are allowed; a [k, D] float64 copy
+    # of the per-task estimates, or full-width temporaries, are not
+    k, d = 7, 1 << 20
+    rng = np.random.default_rng(12)
+    est = SubsampleEstimator("moments")
+    for tid in range(k):
+        est.observe(tid, {"sum": rng.standard_normal(d),
+                          "count": np.asarray(1.0, np.float32)})
+    tracemalloc.start()
+    try:
+        snap = est.estimate()
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert snap.tasks_in == k and np.isfinite(snap.half_width)
+    assert peak < k * d * np.dtype(np.float64).itemsize
 
 
 def test_stopping_controller_latches_and_reports():
