@@ -643,6 +643,7 @@ def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
         key, rows = arena.slots(tasks)
         b = len(rows)
         b_pad = max(pad_to, b) if pad_to is not None else pow2_ceil(b)
+        clock.lanes = b_pad
         seeds = np.asarray(seeds, np.int32)
         if b_pad != b:
             rows = np.concatenate([rows, np.repeat(rows[:1], b_pad - b)])
@@ -713,6 +714,7 @@ def run_map_wave_sharded(arena: ShardedBlockArena, tasks: Sequence,
         occupancy = np.bincount(devs, minlength=n_dev)
         if occupancy.max() > width:
             width = pow2_ceil(int(occupancy.max()))
+        clock.lanes = n_dev * width
 
         rows = np.zeros((n_dev, width), np.int32)
         lane_seeds = np.full((n_dev, width), seeds[0], np.int32)

@@ -138,7 +138,7 @@ class QueryClass:
     wave_ctx: Optional[WaveContext] = None
     blocks: Dict[int, Tuple[np.ndarray, np.ndarray]] = dataclasses.field(
         default_factory=dict)
-    arena_bytes: float = 0.0           # charged to the job that built it
+    arena_bytes: float = 0.0           # device bytes: data + months planes
 
     def fuse_key(self, task: sch.Task) -> Tuple:
         return (self.uid, self.plan.task_shape(task))
@@ -288,6 +288,12 @@ class JobTicket:
         self.finished_at: Optional[float] = None
         self.bytes_uploaded = 0.0
         self.device_dispatches = 0               # waves this job rode in
+        # device bytes of the query class's arena (data and months planes,
+        # padding included), the same for every job of the class
+        self.arena_bytes = 0.0
+        # lanes dispatched for this job's tasks, padding lanes included (a
+        # shared wave's split by task share)
+        self.wave_lanes = 0.0
         # host seconds of this job's spans per span name (a shared wave's
         # split by task share); finalize lands just after completion
         self.span_seconds: Dict[str, float] = {}
@@ -400,6 +406,8 @@ class JobTicket:
             "queue_wait_s": self.queue_wait,
             "bytes_uploaded": self.bytes_uploaded,
             "device_dispatches": self.device_dispatches,
+            "arena_bytes": self.arena_bytes,
+            "wave_lanes": self.wave_lanes,
             "epsilon": self.epsilon,
             "tasks_executed": self.tasks_executed,
             "tasks_cancelled": self.tasks_cancelled,
@@ -657,6 +665,7 @@ class PlatformService:
                                len(qc.plan.tasks), workload.statistic, seed)
             ticket.epsilon, ticket.confidence = eff_epsilon, eff_conf
             ticket.min_tasks = eff_min
+            ticket.arena_bytes = qc.arena_bytes
             if built_now:
                 self.telemetry.emit("arena_upload", nbytes=qc.arena_bytes,
                                     job_id=ticket.job_id)
@@ -926,6 +935,7 @@ class PlatformService:
                     if t is not None:
                         t.device_dispatches += 1
                         t.bytes_uploaded += nbytes
+                        t.wave_lanes += clock.lanes * n / len(items)
                         t.charge(clock.seconds, n / len(items))
                 return values
             return run_batch

@@ -132,11 +132,14 @@ class SpanClock:
     tree): each is entered with the unit's ``ids``, and the seconds each
     span name took are summed in :attr:`seconds` whether a trace is
     active or not — the spans' aggregation path, as ``_aggregate`` is
-    the events'.  One thread enters a clock's spans at a time."""
+    the events'.  One thread enters a clock's spans at a time.  A wave
+    also records in :attr:`lanes` the lanes it dispatched, padding
+    included."""
 
     def __init__(self, **ids: Any):
         self.ids = ids
         self.seconds: Dict[str, float] = {}
+        self.lanes = 0
 
     @contextlib.contextmanager
     def span(self, name: str):
