@@ -609,11 +609,14 @@ class MultiJobScheduler:
       under ``deadline_headroom ×`` its estimated remaining runtime
       jumps the round-robin order (earliest deadline first among the
       urgent).
-    * **Cross-job wave fusion** — a claimed batch starts FIFO from the
-      chosen job and is then *filled* with ready tasks from other jobs
-      whose ``fuse_key`` matches (same dataset arena + engine + block
-      shape), so one device dispatch serves several jobs.  Fused tasks
-      are charged to their own job's deficit, keeping fairness intact.
+    * **Cross-job wave fusion** — a claimed batch starts at the chosen
+      job's queue head and is *filled* with ready tasks whose
+      ``fuse_key`` matches (same dataset arena + engine + block shape),
+      taken FIFO from anywhere in that job's queue and then in each
+      peer's, so interleaved shape buckets still fill whole waves and
+      one device dispatch serves several jobs.  Tasks passed over keep
+      their order.  Fused tasks are charged to their own job's deficit,
+      keeping fairness intact.
     """
 
     def __init__(self, n_workers: int,
@@ -629,6 +632,7 @@ class MultiJobScheduler:
         self._rr: deque[int] = deque()      # active round-robin order
         self.avg_task_seconds: Optional[float] = None
         self.fused_dispatches = 0           # batches spanning >1 job
+        self.tasks_pulled_forward = 0       # claimed past a key mismatch
         self.claims = 0
         self.speculative_launches = 0
         self.speculation_wins = 0
@@ -809,15 +813,7 @@ class MultiJobScheduler:
         cap = max(int(job.cap(first)), 1)
         if max_n is not None:
             cap = min(cap, max_n)
-        batch: List[Tuple[ServiceJob, Task]] = []
-        while (job.pending and len(batch) < cap
-               and job.fuse_key(job.pending[0]) == key):
-            t = job.pending.popleft()
-            # a lease-reclaimed duplicate whose original settled is
-            # dropped at claim time, never re-executed
-            if t.task_id in job.completed_ids:
-                continue
-            batch.append((job, t))
+        batch = [(job, t) for t in self._take_matching(job, key, cap)]
         # debit what was actually served; cap the carried credit at one
         # quantum so an idle-ish job cannot hoard turns
         job.deficit = min(job.deficit - len(batch), self.cfg.quantum)
@@ -831,16 +827,10 @@ class MultiJobScheduler:
                 peer = self.jobs.get(jid)
                 if peer is None or peer is job:
                     continue
-                took = 0
-                while (peer.pending and len(batch) < cap
-                       and peer.fuse_key(peer.pending[0]) == key):
-                    t = peer.pending.popleft()
-                    if t.task_id in peer.completed_ids:
-                        continue
-                    batch.append((peer, t))
-                    took += 1
+                took = self._take_matching(peer, key, cap - len(batch))
                 if took:
-                    peer.deficit -= took    # fused service still counts
+                    batch.extend((peer, t) for t in took)
+                    peer.deficit -= len(took)   # fused service still counts
         if len({j.job_id for j, _ in batch}) > 1:
             self.fused_dispatches += 1
         for j, t in batch:
@@ -856,6 +846,27 @@ class MultiJobScheduler:
                 self.telemetry.emit("task_claimed", job_id=jid,
                                     task_ids=tuple(tids), worker=worker)
         return batch
+
+    def _take_matching(self, job: ServiceJob, key: Any,
+                       room: int) -> List[Task]:
+        """Take up to ``room`` tasks whose fuse key is ``key`` from
+        anywhere in ``job.pending``, FIFO; the tasks passed over keep
+        their relative order at the queue's front.  A lease-reclaimed
+        duplicate whose original settled is dropped, never re-executed.
+        Tasks taken from behind a key mismatch count in
+        ``tasks_pulled_forward``."""
+        taken: List[Task] = []
+        passed: List[Task] = []
+        while job.pending and len(taken) < room:
+            t = job.pending.popleft()
+            if job.fuse_key(t) != key:
+                passed.append(t)
+            elif t.task_id not in job.completed_ids:
+                taken.append(t)
+                if passed:
+                    self.tasks_pulled_forward += 1
+        job.pending.extendleft(reversed(passed))
+        return taken
 
     def _record_claim(self, worker: Optional[int], job_id: int,
                       task: Task, now: float) -> None:

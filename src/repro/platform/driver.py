@@ -478,11 +478,19 @@ def plan_job(samples: Dict[int, np.ndarray],
     pad_len = (0 if map_fn is not None else
                pc.partial_pad_len(workload.statistic, samples))
 
+    shapes: Dict[Tuple[int, ...], Tuple[int, int]] = {}
+
     def task_shape(task: sch.Task) -> Tuple[int, int]:
         """Padded block shape, derived from row lengths without
-        materializing the block (same policy as pad_to_common)."""
-        longest = max(samples[ids[i]].shape[0] for i in task.sample_ids)
-        return (max_count, pc.padded_len(longest, pad_len))
+        materializing the block (same policy as pad_to_common), once per
+        task: every claim's fuse-key scan asks for it under the pool's
+        lock."""
+        shape = shapes.get(task.sample_ids)
+        if shape is None:
+            longest = max(samples[ids[i]].shape[0] for i in task.sample_ids)
+            shape = shapes[task.sample_ids] = (
+                max_count, pc.padded_len(longest, pad_len))
+        return shape
 
     def build_block(task: sch.Task) -> Tuple[np.ndarray, np.ndarray]:
         return pc.build_block(samples, months, ids, task.sample_ids,

@@ -1195,6 +1195,7 @@ class PlatformService:
             }
         if pool is not None:
             out["fused_dispatches"] = pool.sched.fused_dispatches
+            out["tasks_pulled_forward"] = pool.sched.tasks_pulled_forward
             out["pending_tasks"] = pool.pending_tasks()
             out["speculative_launches"] = pool.sched.speculative_launches
             out["speculation_wins"] = pool.sched.speculation_wins

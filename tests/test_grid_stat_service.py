@@ -11,11 +11,13 @@ and its waves carry padding lanes."""
 import json
 import pathlib
 import types
+from collections import Counter
 
 import numpy as np
 import pytest
 
 from bench import data, reference
+from repro.core import scheduler as sch
 from repro.core.subsample import SubsampleWorkload
 from repro.platform import PlatformService, PlatformSpec, WaveOptions
 from repro.platform.service import JobTicket
@@ -57,13 +59,75 @@ def served():
     return ds, knee, qc, tickets, results, waves
 
 
-def test_the_data_set_spans_shape_buckets_and_padded_lanes(served):
-    ds, knee, qc, tickets, _, _ = served
+def serve_alone(seed: int):
+    """One job alone on a fresh service over the fixture's data set:
+    ``(query class, ticket, result, the service's stats)``."""
+    ds, knee = netflix(90, 7)
+    with PlatformService(PlatformSpec(knee_bytes=knee)) as svc:
+        handle = svc.register_dataset(ds.samples, ds.months, knee_bytes=knee)
+        ticket = svc.submit(handle, WL, seed=seed)
+        result = ticket.result(timeout=300)
+        stats = svc.stats()
+    (qc,) = handle._classes.values()
+    return qc, ticket, result, stats
+
+
+@pytest.fixture(scope="module")
+def alone():
+    return serve_alone(SEEDS[0])
+
+
+def bucket_lanes(qc) -> int:
+    """The lanes a lone job dispatches when each claim fills its wave
+    from the whole queue: every bucket in ``ceil(n / cap)`` full-width
+    waves."""
+    sizes = Counter(qc.plan.task_shape(t) for t in qc.plan.tasks)
+    return sum(-(-n // qc.wave_ctx.wave_pad[key]) * qc.wave_ctx.wave_pad[key]
+               for key, n in sizes.items())
+
+
+def test_the_data_set_spans_shape_buckets_and_padded_lanes(served, alone):
+    ds, knee, qc, _, _, _ = served
     groups = reference.task_groups(ds.lengths, knee)
     assert len(groups[-1]) < len(groups[0])           # a task repeats rows
     assert qc.wave_ctx is not None and qc.engine == "jnp"
     assert len(qc.wave_ctx.arena.keys()) >= 2
-    assert all(t.wave_lanes > t.n_tasks for t in tickets)
+    qc, ticket, _, _ = alone
+    assert ticket.wave_lanes == bucket_lanes(qc) > ticket.n_tasks
+
+
+def test_a_lone_job_fills_each_bucket_from_the_whole_queue(alone):
+    ds, knee = netflix(90, 7)
+    qc, ticket, got, stats = alone
+    # the buckets alternate in task order: a claim reached past another
+    # bucket's task for some of its wave
+    assert stats["tasks_pulled_forward"] > 0
+    assert ticket.wave_lanes == bucket_lanes(qc)
+    want = reference.job_result(ds, knee, ticket.seed, QUERY)
+    assert np.array_equal(np.asarray(got["count"], np.float64),
+                          want["count"])
+    np.testing.assert_allclose(
+        np.asarray(got["monthly_mean"], np.float64), want["monthly_mean"],
+        rtol=0, atol=RTOL * np.max(np.abs(want["monthly_mean"])))
+
+
+def test_grouped_claims_give_the_head_run_orders_bits(alone, monkeypatch):
+    def head_run(self, job, key, room):
+        taken = []
+        while (job.pending and len(taken) < room
+               and job.fuse_key(job.pending[0]) == key):
+            t = job.pending.popleft()
+            if t.task_id not in job.completed_ids:
+                taken.append(t)
+        return taken
+
+    monkeypatch.setattr(sch.MultiJobScheduler, "_take_matching", head_run)
+    _, grouped, want, _ = alone
+    _, ticket, got, stats = serve_alone(grouped.seed)
+    assert stats["tasks_pulled_forward"] == 0
+    assert ticket.wave_lanes > grouped.wave_lanes     # the orders differ
+    for name in ("monthly_mean", "count"):
+        assert np.array_equal(np.asarray(got[name]), np.asarray(want[name]))
 
 
 @pytest.mark.parametrize("job", range(len(SEEDS)))

@@ -152,6 +152,8 @@ def test_concurrent_jobs_fuse_waves_across_jobs():
             t.result(timeout=120)
         stats = svc.stats()
     assert stats["fused_dispatches"] > 0
+    # one shape bucket: no claim ever reaches past a mismatch
+    assert stats["tasks_pulled_forward"] == 0
     # 8 jobs x 10 tasks in far fewer dispatches than tasks
     post_warm = [w for w in stats["wave_sizes"]]
     assert sum(post_warm) == 90
@@ -173,6 +175,37 @@ def test_fused_results_match_sequential_results():
                 np.testing.assert_array_equal(
                     np.asarray(seq[s][key]), np.asarray(got[key]),
                     err_msg=f"seed {s} diverged on {key!r}")
+
+
+@pytest.mark.parametrize(
+    "queues, cap, settled, claimed, left, pulled", [
+        # alternating buckets: four As, the Bs keep their order
+        ({1: "ABABABABAB"}, 4, (), [0, 2, 4, 6],
+         {1: [1, 3, 5, 7, 8, 9]}, 3),
+        # cap 1: the head only
+        ({1: "ABABABABAB"}, 1, (), [0], {1: list(range(1, 10))}, 0),
+        # one bucket: the head run, nothing pulled forward
+        ({1: "AAAAAA"}, 4, (), [0, 1, 2, 3], {1: [4, 5]}, 0),
+        # a settled lease-reclaimed duplicate met in the scan is dropped
+        ({1: "ABABABABAB"}, 4, (2,), [0, 4, 6, 8], {1: [1, 3, 5, 7, 9]}, 3),
+        # the peer fill reaches behind the peer's head
+        ({1: "AA", 2: "BABAAB"}, 4, (), [0, 1, 101, 103],
+         {1: [], 2: [100, 102, 104, 105]}, 2),
+    ], ids=["alternating", "cap1", "one-bucket", "duplicate", "peer"])
+def test_claim_fills_the_wave_from_the_whole_queue(queues, cap, settled,
+                                                   claimed, left, pulled):
+    msched = sch.MultiJobScheduler(1)
+    for jid, keys in queues.items():
+        msched.add_job(jid, [sch.Task(100 * (jid - 1) + i, (i,), 1.0,
+                                      payload=k)
+                             for i, k in enumerate(keys)],
+                       fuse_key=lambda t: t.payload, cap=cap)
+    msched.jobs[1].completed_ids.update(settled)
+    batch = msched.claim(now=0.0)
+    assert [t.task_id for _, t in batch] == claimed
+    assert {jid: [t.task_id for t in job.pending]
+            for jid, job in msched.jobs.items()} == left
+    assert msched.tasks_pulled_forward == pulled
 
 
 # -- fairness / deadlines -----------------------------------------------------
