@@ -64,7 +64,7 @@ def main():
           f"{bts.throughput_bps / reports['BTT'].throughput_bps:.2f}x")
     print(f"phase timings: "
           f"{ {k: round(v, 3) for k, v in bts.phases.items()} }")
-    print(f"queue-depth trace (dynamic k): {bts.queue_depths[:8]} ... "
+    print(f"ready tasks at each settle: {bts.queue_depths[:8]} ... "
           f"stragglers: {bts.stragglers}")
     print(f"datastore: {store.stats()}")
     mean = bts.result["monthly_mean"]

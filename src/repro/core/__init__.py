@@ -20,7 +20,6 @@ from repro.core.scheduler import (  # noqa: F401
     SimWorker,
     Task,
     TaskResult,
-    ThreadedRunner,
     TwoPhaseScheduler,
     simulate_job,
 )

@@ -28,8 +28,8 @@ owning datastore emits ``cache_hit``/``cache_miss``/``cache_evict``
 events on the platform :class:`~repro.platform.telemetry.TelemetryBus`.
 ``on_change`` fires (outside the lock) on admission, eviction and
 invalidation — residency transitions only, never plain hits — which the
-drivers wire to the schedulers' ``request_rerank()`` so cache locality
-re-ranks ready tasks exactly like a data-node state change does.
+service wires to its pool scheduler's ``request_rerank()`` so cache
+locality re-ranks ready tasks exactly like a data-node state change does.
 """
 
 from __future__ import annotations
@@ -94,7 +94,7 @@ class BlockCache:
                  on_change: Optional[Callable[[], None]] = None):
         self.options = options
         # residency-transition callback (admission/eviction/invalidation,
-        # never hits) — the drivers point this at request_rerank()
+        # never hits) — the service points this at request_rerank()
         self.on_change = on_change
         self._lock = threading.Lock()
         # insertion/recency order: leftmost = coldest (LRU victim)

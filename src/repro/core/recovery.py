@@ -112,7 +112,7 @@ def should_speculate(age_seconds: float, exec_ema: Optional[float], *,
 
 class WorkerCrash(RuntimeError):
     """A worker thread died (injected or detected) while holding claimed
-    tasks.  The runner/pool reclaims the worker's claims back to the
+    tasks.  The pool reclaims the worker's claims back to the
     scheduler and respawns the thread; first-completion-wins dedup keeps
     settlement at-most-once, so recovery is bit-identical."""
 

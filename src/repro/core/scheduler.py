@@ -16,14 +16,11 @@ restarts the *whole job* (`JobFailure`), which the driver retries; optional
 task-level mode re-queues the failed task but charges every task the
 monitoring overhead ``cost_tl``.
 
-Two drivers share this policy object:
-  * :func:`simulate_job` — single-threaded discrete-event simulation with
-    virtual time (used for scale-out/elasticity/heterogeneity benchmarks:
-    this container has one physical core, so >1-worker wall-clock
-    parallelism must be simulated; per-task durations are *measured* on the
-    real workload first).
-  * :class:`ThreadedRunner` — real threads + queues, real wall time (used
-    for overhead microbenchmarks and the runnable examples).
+:func:`simulate_job` drives this policy in virtual time: a single-threaded
+discrete-event simulation for scale-out, elasticity and heterogeneity
+studies, over per-task costs measured on the real workload first.  Real
+threads run every job through :class:`MultiJobScheduler` and the
+service's resident pool (``repro.platform.backend.ServicePool``).
 """
 
 from __future__ import annotations
@@ -31,7 +28,6 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
-import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -116,12 +112,6 @@ class SchedulerConfig:
     speculative_factor: float = 2.0        # legacy name for the age factor
     straggler_factor: Optional[float] = None   # overrides when set
     seed: int = 0
-    # lease-based task reclamation (DESIGN.md §12): a claimed task whose
-    # lease expires is requeued for another worker — the safety net for
-    # workers that die without reporting.  First-completion-wins dedup
-    # keeps a late original settlement harmless (at-most-once, results
-    # bit-identical).  None disables leasing entirely.
-    lease_seconds: Optional[float] = None
 
     def effective_straggler_factor(self) -> float:
         return (self.straggler_factor if self.straggler_factor is not None
@@ -129,19 +119,15 @@ class SchedulerConfig:
 
 
 class TwoPhaseScheduler:
-    """Pure scheduling policy — no clock, no threads.  Drivers call
-    :meth:`on_worker_idle` / :meth:`on_task_complete` and execute whatever
-    assignments come back.
+    """The thesis' two-phase policy in virtual time — no clock, no
+    threads.  :func:`simulate_job` calls :meth:`on_worker_idle` /
+    :meth:`on_task_complete` and executes whatever assignments come back.
 
     ``locality_score(task)`` — when provided — is the predicted fetch
     latency of the task's best available data-node replica (the
     datastore's :meth:`~repro.core.datastore.ReplicatedDataStore.
-    predicted_task_fetch`); ready tasks are ranked so workers drain
-    cheap-data tasks first, at whole ``bucket_key`` granularity so
-    same-shape wave fusion survives the reordering.  The ranking is
-    recomputed lazily after :meth:`request_rerank` (wired to the
-    datastore's node state-change callback), under whatever lock the
-    driver already holds for scheduler calls."""
+    predicted_task_fetch`); the backlog is ranked once so workers drain
+    cheap-data tasks first, at whole ``bucket_key`` granularity."""
 
     def __init__(self, n_workers: int, tasks: Sequence[Task],
                  cfg: SchedulerConfig = SchedulerConfig(), *,
@@ -154,12 +140,6 @@ class TwoPhaseScheduler:
         self.queues: List[deque[Task]] = [deque() for _ in range(n_workers)]
         self.inflight: Dict[int, Task] = {}
         self.inflight_by_worker: Dict[int, Task] = {}
-        # EVERY claimed-but-unsettled task per worker (a wave claim is
-        # many tasks) — what crash/lease reclamation recovers.  The
-        # single-task ``inflight_by_worker`` keeps its legacy straggler
-        # semantics alongside.
-        self.claims_by_worker: Dict[int, Dict[int, Task]] = {}
-        self._lease: Dict[int, float] = {}   # task_id -> lease expiry
         self._started_at: Dict[int, float] = {}
         self._first_worker: Dict[int, int] = {}
         self._speculated: set = set()
@@ -167,9 +147,6 @@ class TwoPhaseScheduler:
         self.speculative_launches = 0
         self.speculation_wins = 0          # clone finished before original
         self.cancelled_tasks = 0           # dropped by cancel_pending()
-        self.worker_crashes = 0            # crashed workers reclaimed
-        self.reclaimed_tasks = 0           # tasks requeued by crash/lease
-        self.lost_tasks = 0                # dropped permanently (degraded)
         self.results: List[TaskResult] = []
         self.depth_trace: List[int] = []   # dynamic-k after each completion
         # one aggregation path (DESIGN.md §13): the bus's aggregator owns
@@ -185,31 +162,10 @@ class TwoPhaseScheduler:
         self._rng = np.random.default_rng(cfg.seed)
         self._phase2 = False
         self._alive = [True] * n_workers
-        self.locality_score = locality_score
-        self.bucket_key = bucket_key or (lambda t: len(t.sample_ids))
-        self._rank_dirty = False
-        self.reranks = 0
-        if locality_score is not None:
-            self._rank_backlog()
-
-    # -- response-time-aware claim ordering ----------------------------------
-    def request_rerank(self) -> None:
-        """Mark the ready ranking stale (safe from any thread — the
-        re-sort itself happens inside the next scheduler call, under the
-        driver's lock)."""
-        self._rank_dirty = True
-
-    def _maybe_rerank(self) -> None:
-        if self._rank_dirty:
-            self._rank_dirty = False
-            self._rank_backlog()
-
-    def _rank_backlog(self) -> None:
-        if self.locality_score is None or len(self.backlog) <= 1:
-            return
-        self.backlog = rank_by_bucket(self.backlog, self.bucket_key,
-                                      self.locality_score)
-        self.reranks += 1
+        if locality_score is not None and len(self.backlog) > 1:
+            self.backlog = rank_by_bucket(
+                self.backlog, bucket_key or (lambda t: len(t.sample_ids)),
+                locality_score)
 
     # -- feedback loop -------------------------------------------------------
     def _observe(self, result: TaskResult) -> None:
@@ -254,10 +210,7 @@ class TwoPhaseScheduler:
                       now: Optional[float] = None) -> None:
         self.inflight[task.task_id] = task
         self.inflight_by_worker[worker] = task
-        self.claims_by_worker.setdefault(worker, {})[task.task_id] = task
         t_now = time.perf_counter() if now is None else now
-        if self.cfg.lease_seconds is not None:
-            self._lease[task.task_id] = t_now + self.cfg.lease_seconds
         self._first_worker.setdefault(task.task_id, worker)
         # a speculative clone's start must not reset the straggler clock
         if task.task_id not in self._started_at:
@@ -272,8 +225,6 @@ class TwoPhaseScheduler:
         ``ts`` stamps the settle event in virtual time (simulated
         backend); wall-time drivers leave it unset."""
         self.inflight_by_worker.pop(result.worker_id, None)
-        self.claims_by_worker.get(result.worker_id, {}).pop(
-            result.task_id, None)
         if result.task_id in self._completed:
             return []
         self._completed.add(result.task_id)
@@ -282,12 +233,10 @@ class TwoPhaseScheduler:
                 != result.worker_id):
             self.speculation_wins += 1     # the clone beat the original
         self.inflight.pop(result.task_id, None)
-        self._lease.pop(result.task_id, None)
         self._started_at.pop(result.task_id, None)
         self.results.append(result)
         self._observe(result)
         self._phase2 = True
-        self._maybe_rerank()
         w = result.worker_id
         out: List[Tuple[int, Task]] = []
         depth = self.queue_depth()
@@ -313,9 +262,8 @@ class TwoPhaseScheduler:
         speculative re-execution of the longest-running straggler."""
         if not self._alive[worker]:
             return None
-        self._maybe_rerank()
-        # lease-reclaimed duplicates: a requeued copy whose original
-        # settled in the meantime is dropped at claim time, not run again
+        # a requeued copy whose original settled in the meantime is
+        # dropped at claim time, not run again
         q = self.queues[worker]
         while q:
             t = q.popleft()
@@ -376,37 +324,6 @@ class TwoPhaseScheduler:
             return None
         return min(times) + 1e-9       # strict-inequality epsilon
 
-    def claim_batch(self, worker: int, first: Task, max_n: int,
-                    key_fn: Callable[[Task], Any]) -> List[Task]:
-        """Wave draining: extend ``first`` (already claimed via
-        :meth:`on_worker_idle`) with more ready tasks whose shape key
-        matches, popped FIFO from this worker's own queue and then the
-        backlog.  The first key mismatch stops the drain so waves stay
-        same-shape (one compiled kernel per wave); the caller bounds
-        ``max_n`` (the driver sizes it per shape bucket so every worker
-        gets a fair share and one worker cannot swallow the backlog).
-        The caller must :meth:`on_task_start` every claimed task.
-
-        Crash recovery tracks the FULL wave: every claimed task lands in
-        ``claims_by_worker`` at :meth:`on_task_start`, so
-        :meth:`on_worker_crash` / :meth:`reclaim_expired` recover every
-        wave member of a dead worker, not just the last one (the legacy
-        single-slot ``inflight_by_worker`` only feeds straggler
-        speculation)."""
-        q = self.queues[worker]
-        out = [first]
-        key = key_fn(first)
-        while len(out) < max_n and q and key_fn(q[0]) == key:
-            t = q.popleft()
-            if t.task_id not in self._completed:
-                out.append(t)
-        while (len(out) < max_n and self.backlog
-               and key_fn(self.backlog[0]) == key):
-            t = self.backlog.popleft()
-            if t.task_id not in self._completed:
-                out.append(t)
-        return out
-
     def cancel_pending(self) -> List[Task]:
         """DRAINING (DESIGN.md §10): drop every not-yet-started task —
         the backlog and all per-worker queues — leaving in-flight tasks
@@ -434,13 +351,9 @@ class TwoPhaseScheduler:
         reclaimed = list(self.queues[worker])
         self.queues[worker].clear()
         own = self.inflight_by_worker.pop(worker, None)
-        claims = self.claims_by_worker.pop(worker, {})
         if own is not None:
-            claims.setdefault(own.task_id, own)
-        for t in claims.values():
-            self.inflight.pop(t.task_id, None)
-            self._lease.pop(t.task_id, None)
-            reclaimed.append(t)
+            self.inflight.pop(own.task_id, None)
+            reclaimed.append(own)
         for t in reclaimed:
             # reset the straggler clock: the re-execution must not
             # inherit the dead worker's elapsed time (it would be
@@ -449,92 +362,6 @@ class TwoPhaseScheduler:
             self._first_worker.pop(t.task_id, None)
         self.backlog.extend(reclaimed)
         return reclaimed
-
-    def on_worker_crash(self, worker: int, *,
-                        respawn: bool = True) -> List[Task]:
-        """A worker thread died mid-task (detected or injected): requeue
-        EVERY claimed-but-unsettled task it held — the whole wave, plus
-        its queued work — at the FRONT of the backlog so recovery work
-        drains first.  Unlike :meth:`on_worker_failure` this never
-        aborts the job: the runner respawns the worker under the same id
-        (``respawn=True`` keeps it alive in the scheduler) and
-        first-completion-wins dedup keeps any late settlement from the
-        dead thread harmless.  Idempotent per crash."""
-        self.worker_crashes += 1
-        reclaimed = [t for t in self.queues[worker]
-                     if t.task_id not in self._completed]
-        self.queues[worker].clear()
-        self.inflight_by_worker.pop(worker, None)
-        claims = self.claims_by_worker.pop(worker, {})
-        for tid, t in claims.items():
-            if tid not in self._completed:
-                reclaimed.append(t)
-        seen: set = set()
-        requeue: List[Task] = []
-        for t in reclaimed:
-            if t.task_id in seen:
-                continue
-            seen.add(t.task_id)
-            self.inflight.pop(t.task_id, None)
-            self._lease.pop(t.task_id, None)
-            self._started_at.pop(t.task_id, None)
-            self._first_worker.pop(t.task_id, None)
-            requeue.append(t)
-        self.backlog.extendleft(reversed(requeue))
-        self.reclaimed_tasks += len(requeue)
-        self.telemetry.emit("worker_crash", worker=worker,
-                            reclaimed=len(requeue), respawn=respawn)
-        if not respawn:
-            self._alive[worker] = False
-        return requeue
-
-    def reclaim_expired(self, now: Optional[float] = None) -> List[Task]:
-        """Lease expiry sweep (drivers call this from idle workers): any
-        claimed task whose lease has lapsed is requeued at the front of
-        the backlog for re-execution — the safety net for workers that
-        die without a detectable crash.  The original claim stays live
-        (a slow-but-alive worker may still settle first; dedup keeps it
-        at-most-once), so the re-execution behaves exactly like a
-        speculative clone with the task's own seed: bit-identical."""
-        if self.cfg.lease_seconds is None or not self._lease:
-            return []
-        t_now = time.perf_counter() if now is None else now
-        expired = [tid for tid, exp in self._lease.items()
-                   if exp <= t_now and tid not in self._completed]
-        out: List[Task] = []
-        for tid in expired:
-            self._lease.pop(tid, None)
-            task = self.inflight.get(tid)
-            if task is None:
-                continue
-            # reset the straggler clock for the re-execution
-            self._started_at.pop(tid, None)
-            self.backlog.appendleft(task)
-            self.reclaimed_tasks += 1
-            out.append(task)
-        if out:
-            self.telemetry.emit(
-                "lease_reclaimed", n=len(out),
-                task_ids=tuple(t.task_id for t in out))
-        return out
-
-    def on_tasks_lost(self, worker: int, tasks: Sequence[Task]) -> None:
-        """Permanently drop claimed tasks whose data is gone (every
-        replica down, retry budget spent): settle them OUT of the
-        in-flight accounting without marking them completed, so a
-        degraded drain can finish from what actually executed instead of
-        hanging on tasks that can never settle."""
-        claims = self.claims_by_worker.get(worker, {})
-        for t in tasks:
-            if t.task_id in self._completed:
-                continue
-            claims.pop(t.task_id, None)
-            self.inflight.pop(t.task_id, None)
-            self._lease.pop(t.task_id, None)
-            self._started_at.pop(t.task_id, None)
-            self._first_worker.pop(t.task_id, None)
-            self.lost_tasks += 1
-        self.inflight_by_worker.pop(worker, None)
 
     def done(self) -> bool:
         return (not self.backlog and not self.inflight
@@ -1277,252 +1104,3 @@ def _simulate_once(tasks, workers, params, cfg, restarts, *,
                       queue_depths=list(sched.depth_trace),
                       speculative_launches=sched.speculative_launches,
                       speculation_wins=sched.speculation_wins)
-
-
-# ---------------------------------------------------------------------------
-# Threaded driver (real wall time)
-# ---------------------------------------------------------------------------
-
-
-class ThreadedRunner:
-    """Executes tasks with real threads; one queue per worker.  The worker
-    callable receives (task) and returns a value; fetch is performed by the
-    optional datastore before execution (overlapped via the queue).
-
-    Wave mode: with ``run_batch`` set (and ``max_batch > 1``), an idle
-    worker drains up to ``max_batch`` ready tasks of the same ``batch_key``
-    shape in one claim and executes them through ``run_batch(tasks) ->
-    values`` — one device dispatch per wave instead of per task.  Each
-    task still yields its own :class:`TaskResult` (exec time split evenly)
-    so the feedback loop and straggler accounting are unchanged."""
-
-    def __init__(self, n_workers: int,
-                 run_task: Callable[[Task], Any],
-                 fetch: Optional[Callable[[Task], Any]] = None,
-                 cfg: SchedulerConfig = SchedulerConfig(),
-                 run_batch: Optional[Callable[[List[Task]],
-                                              List[Any]]] = None,
-                 batch_key: Optional[Callable[[Task], Any]] = None,
-                 max_batch: int = 1,
-                 batch_cap: Optional[Callable[[Task], int]] = None,
-                 locality_score: Optional[Callable[[Task], float]] = None,
-                 prefetcher=None, stopper=None,
-                 crash_hook: Optional[Callable[[int], None]] = None,
-                 max_respawns: int = 2,
-                 telemetry=None):
-        self.n_workers = n_workers
-        if telemetry is None:
-            from repro.platform.telemetry import null_bus
-            telemetry = null_bus()
-        self.telemetry = telemetry
-        self.run_task = run_task
-        self.fetch = fetch
-        self.cfg = cfg
-        self.run_batch = run_batch
-        self.batch_key = batch_key or (lambda t: len(t.sample_ids))
-        self.max_batch = max_batch
-        # fault injection (repro.platform.faults): called with the
-        # worker id right after each claim; raises WorkerCrash to
-        # simulate the thread dying mid-task
-        self.crash_hook = crash_hook
-        # per-worker respawn budget: a crashed worker thread is
-        # restarted under the same id until the budget runs out, after
-        # which its work is reclaimed and the pool shrinks
-        self.max_respawns = max_respawns
-        self.worker_respawns = 0
-        # per-shape wave-size cap (the driver pins one padded wave width
-        # per shape bucket; claims must not exceed it)
-        self.batch_cap = batch_cap
-        # response-time-aware ranking + dynamic-k ahead-fetch (the
-        # balanced scheduling loop, DESIGN.md §9)
-        self.locality_score = locality_score
-        self.prefetcher = prefetcher       # core.prefetch.TaskPrefetcher
-        # error-bounded early termination (DESIGN.md §10): a
-        # core.estimator.StoppingController consulted at every wave
-        # settlement; on convergence the scheduler drains
-        self.stopper = stopper
-        # called with the live scheduler before workers start (drivers
-        # wire data-plane state changes to request_rerank here)
-        self.on_scheduler: Optional[Callable[[TwoPhaseScheduler],
-                                             None]] = None
-        self.last_scheduler: Optional[TwoPhaseScheduler] = None
-
-    def run_job(self, tasks: Sequence[Task]) -> List[TaskResult]:
-        sched = TwoPhaseScheduler(self.n_workers, tasks, self.cfg,
-                                  locality_score=self.locality_score,
-                                  bucket_key=self.batch_key,
-                                  telemetry=self.telemetry)
-        self.last_scheduler = sched
-        if self.on_scheduler is not None:
-            self.on_scheduler(sched)
-        lock = threading.Lock()
-        results: List[TaskResult] = []
-        errors: List[BaseException] = []
-        use_waves = self.run_batch is not None and self.max_batch > 1
-
-        prefetcher = self.prefetcher if self.fetch is not None else None
-
-        def worker_loop(wid: int):
-            while True:
-                batch = None
-                upcoming: List[Task] = []
-                with lock:
-                    if errors:                 # a peer died: job-level
-                        return                 # abort (thesis §3.3)
-                    t = sched.on_worker_idle(wid)
-                    if t is not None:
-                        if use_waves:
-                            cap = (min(self.max_batch, self.batch_cap(t))
-                                   if self.batch_cap else self.max_batch)
-                            batch = sched.claim_batch(wid, t, cap,
-                                                      self.batch_key)
-                            for x in batch:
-                                sched.on_task_start(wid, x)
-                        else:
-                            sched.on_task_start(wid, t)
-                        sched.telemetry.emit(
-                            "task_claimed",
-                            task_ids=tuple(x.task_id for x in batch)
-                            if batch is not None else (t.task_id,),
-                            worker=wid)
-                        if prefetcher is not None:
-                            # snapshot the next wave's tasks under the
-                            # lock; their fetches go in flight while THIS
-                            # wave executes (thesis §3.5 pipeline)
-                            upcoming = list(itertools.islice(
-                                itertools.chain(sched.queues[wid],
-                                                sched.backlog),
-                                prefetcher.lookahead()))
-                if t is None:
-                    with lock:
-                        if sched.done():
-                            return
-                        # lease sweep while idle: requeue claims whose
-                        # lease lapsed (a peer died without reporting)
-                        sched.reclaim_expired()
-                    time.sleep(1e-4)
-                    continue
-                claimed = batch if batch is not None else [t]
-                try:
-                    if self.crash_hook is not None:
-                        self.crash_hook(wid)
-                    t0 = time.perf_counter()
-                    if prefetcher is not None:
-                        # admit() drops cache-resident tasks: with
-                        # cache-aware ranking they sort FIRST in the
-                        # backlog, so the peeked look-ahead would be
-                        # exactly the tasks that need no fetch (§14)
-                        prefetcher.prefetch(
-                            [(x.task_id, lambda _x=x: self.fetch(_x))
-                             for x in upcoming if prefetcher.admit(x)])
-                        for x in claimed:
-                            prefetcher.ensure(
-                                x.task_id, lambda _x=x: self.fetch(_x))
-                    elif self.fetch is not None:
-                        for x in claimed:
-                            self.fetch(x)
-                    t1 = time.perf_counter()
-                    if batch is not None:
-                        values = self.run_batch(batch)
-                    else:
-                        values = [self.run_task(t)]
-                    t2 = time.perf_counter()
-                except rec.WorkerCrash:
-                    # this worker "died" mid-task: reclaim its whole
-                    # claimed wave and exit the thread — the supervisor
-                    # respawns it under the same id (DESIGN.md §12)
-                    with lock:
-                        sched.on_worker_crash(wid)
-                    return
-                except BaseException as e:     # noqa: BLE001
-                    if (getattr(e, "permanent", False)
-                            and self.stopper is not None):
-                        # graceful degradation: this wave's data is
-                        # permanently gone, but the job is epsilon-
-                        # capable — drop the lost tasks, latch the stop
-                        # at the achieved CI, and drain what's in flight
-                        with lock:
-                            sched.on_tasks_lost(wid, claimed)
-                            self.stopper.force_stop(f"degraded: {e}")
-                            sched.cancel_pending()
-                        continue
-                    if getattr(e, "permanent", False):
-                        # exact job: fail with a structured partial-
-                        # result report instead of a bare traceback
-                        with lock:
-                            sched.on_tasks_lost(wid, claimed)
-                            e = rec.DegradedJobError(
-                                f"job degraded: {e}", reason=str(e),
-                                n_tasks=len(tasks),
-                                completed=len(sched._completed),
-                                completed_ids=sched._completed)
-                            errors.append(e)
-                        return
-                    with lock:
-                        errors.append(e)
-                    return
-                fetch_each = (t1 - t0) / len(claimed)
-                exec_each = (t2 - t1) / len(claimed)
-                if prefetcher is not None:
-                    prefetcher.observe_exec(exec_each)
-                with lock:
-                    for x, value in zip(claimed, values):
-                        res = TaskResult(x.task_id, wid, t0, fetch_each,
-                                         exec_each, value)
-                        results.append(res)
-                        sched.on_task_complete(res)
-                    # wave-settlement stopping check (DESIGN.md §10):
-                    # once the estimate has converged, drop the ready
-                    # work; peers' in-flight waves settle and done()
-                    # flips when the last one lands
-                    if (self.stopper is not None
-                            and self.stopper.should_stop()):
-                        sched.cancel_pending()
-
-        sched.initial_assignments()
-        threads: Dict[int, threading.Thread] = {
-            w: threading.Thread(target=worker_loop, args=(w,))
-            for w in range(self.n_workers)}
-        respawns = {w: 0 for w in range(self.n_workers)}
-        for th in threads.values():
-            th.start()
-        # supervision loop: join with a timeout and respawn dead worker
-        # threads while the job is unfinished — a thread that exits
-        # before done() is a crash (normal exits only happen at done()
-        # or after parking an error), so its claims were (or are now)
-        # reclaimed and a fresh thread under the same id picks them up
-        while True:
-            any_alive = False
-            for w, th in list(threads.items()):
-                th.join(0.02)
-                if th.is_alive():
-                    any_alive = True
-                    continue
-                with lock:
-                    finished = bool(errors) or sched.done()
-                if finished:
-                    continue
-                if respawns[w] < self.max_respawns:
-                    respawns[w] += 1
-                    self.worker_respawns += 1
-                    sched.telemetry.emit("worker_respawn", worker=w,
-                                         respawn_no=respawns[w])
-                    nth = threading.Thread(target=worker_loop, args=(w,))
-                    threads[w] = nth
-                    nth.start()
-                    any_alive = True
-                else:
-                    # respawn budget exhausted: reclaim (idempotent) and
-                    # shrink the pool — survivors absorb the work
-                    with lock:
-                        sched.on_worker_crash(w, respawn=False)
-            if not any_alive:
-                break
-        if errors:
-            raise errors[0]
-        if not sched.done() and (self.stopper is None
-                                 or not self.stopper.stopped):
-            raise JobFailure(
-                "job incomplete: every worker exhausted its respawn "
-                "budget")
-        return results

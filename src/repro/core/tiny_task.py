@@ -18,13 +18,12 @@ from repro.platform.compute import pad_to_common
 from repro.platform.driver import (  # noqa: F401  (re-exported API)
     BASH_STARTUP,
     PLATFORMS,
-    JobReport,
-    Platform,
     PlatformConfig,
     PlatformSpec,
     make_tasks,
     measure_kneepoint,
 )
+from repro.platform.facade import JobReport, Platform  # noqa: F401
 
 
 def run_subsampling_job(

@@ -1,10 +1,11 @@
 """``repro.platform`` — the end-to-end tiny-task platform driver.
 
 Composes the thesis' pieces (kneepoint task sizing → replicated datastore →
-two-phase dynamic scheduler → streaming reduce) into one pipeline behind
-:class:`Platform`, with threaded (real wall time) and simulated
-(virtual-time scale-out) execution backends behind one protocol.  See
-DESIGN.md §1-§2 and the thesis §3 (arXiv:1404.4653).
+dynamic scheduler → streaming reduce) into one pipeline with one
+real-time execution path, the multi-tenant :class:`PlatformService`, and
+a virtual-time model beside it (scale-out studies).  :class:`Platform`
+runs one job on either.  See DESIGN.md §1-§2 and the thesis §3
+(arXiv:1404.4653).
 
 This module is the stable import surface: ``__all__`` below is the
 curated public API — the driver (:class:`Platform`, :class:`PlatformSpec`
@@ -18,11 +19,9 @@ import it from its home module if you need it.
 from repro.core.blockcache import BlockCache, CacheOptions  # noqa: F401
 from repro.platform.backend import (  # noqa: F401
     BackendOutcome,
-    PlatformBackend,
     PoolJob,
     ServicePool,
     SimulatedBackend,
-    ThreadedBackend,
 )
 from repro.platform.compute import (  # noqa: F401
     MOMENTS,
@@ -42,8 +41,6 @@ from repro.platform.driver import (  # noqa: F401
     ApproxOptions,
     FaultOptions,
     JobPlan,
-    JobReport,
-    Platform,
     PlatformConfig,
     PlatformSpec,
     ScheduleOptions,
@@ -57,6 +54,7 @@ from repro.platform.driver import (  # noqa: F401
     resolve_platform_config,
     wave_enabled,
 )
+from repro.platform.facade import JobReport, Platform  # noqa: F401
 from repro.platform.monitor import (  # noqa: F401
     SLO,
     MonitorOptions,

@@ -1,25 +1,22 @@
-"""Execution backends behind one protocol (thesis §4 evaluation drivers).
+"""The two execution substrates (thesis §4 evaluation drivers).
 
-``PlatformBackend`` is the seam between the driver's job plan (tasks +
-compute + fetch closures) and an execution substrate:
-
-  * :class:`ThreadedBackend` — real threads, real wall time, the two-phase
-    scheduler's :class:`~repro.core.scheduler.ThreadedRunner` (thesis §3.4
-    phase 1/2 with work stealing).  Platform overheads (startup, per-task
-    launch, DFS tax, task-level monitoring — Fig 5/6) are *spent* as real
-    sleeps.
+  * :class:`ServicePool` — resident real threads in real wall time, the
+    one real-time path: every threaded job, one-shot or served, runs on
+    it under :class:`~repro.core.scheduler.MultiJobScheduler`.  Platform
+    overheads (startup, per-dispatch launch, DFS tax, task-level
+    monitoring — Fig 5/6) are *spent* as real sleeps.
   * :class:`SimulatedBackend` — the discrete-event simulator
     (:func:`~repro.core.scheduler.simulate_job`) under virtual time, for
     scale-out / elasticity / heterogeneity studies on a one-core container.
     Per-task costs are **measured on the real compute** first (all tasks,
-    or one representative per block shape), then the same scheduler policy
+    or one representative per block shape), then the two-phase policy
     runs against those costs at any worker count.  Overheads are *charged*
     in virtual time (monitoring via the scheduler's ``cost_tl`` when the
     platform uses task-level recovery, DFS as an execution-time factor).
 
-Both backends call the identical compute closure with the identical
-per-task seed and stream partials into the same deterministic reduce tree,
-so job statistics are bit-identical across backends for a fixed seed.
+Both call the same per-task compute with the same per-task seed and
+stream partials into the same deterministic reduce tree, so job
+statistics are bit-identical across the two for a fixed seed.
 """
 
 from __future__ import annotations
@@ -28,16 +25,11 @@ import contextlib
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Protocol, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core import recovery as rec
 from repro.core import scheduler as sch
 from repro.platform import telemetry as tel
-
-Emit = Callable[[int, Any], None]
-Compute = Callable[[sch.Task], Any]
-ComputeWave = Callable[[List[sch.Task]], List[Any]]
-Fetch = Optional[Callable[[sch.Task], Any]]
 
 
 @dataclasses.dataclass
@@ -53,50 +45,8 @@ class BackendOutcome:
     calibration_seconds: float = 0.0     # sim: real compute spent measuring
 
 
-class PlatformBackend(Protocol):
-    name: str
-
-    def run(self, tasks: Sequence[sch.Task], *, compute: Optional[Compute],
-            fetch: Fetch, plat, cfg: sch.SchedulerConfig, emit: Emit,
-            shape_key: Optional[Callable[[sch.Task], Any]] = None,
-            compute_wave: Optional[ComputeWave] = None,
-            max_wave: int = 1,
-            wave_cap: Optional[Callable[[sch.Task], int]] = None,
-            locality_score: Optional[Callable[[sch.Task], float]] = None,
-            prefetcher=None,
-            on_scheduler: Optional[Callable[[Any], None]] = None,
-            stopper=None,
-            crash_hook: Optional[Callable[[int], None]] = None,
-            telemetry=None,
-            ) -> BackendOutcome:
-        """Execute ``tasks``; stream each task's partial through ``emit``.
-        ``shape_key(task)`` identifies the task's compiled block shape
-        (per-shape cost calibration in the simulator; same-shape wave
-        draining in the threaded backend).  ``compute_wave(batch)`` — when
-        a backend supports it — executes up to ``max_wave`` same-shape
-        tasks in one device dispatch, returning per-task partials;
-        ``wave_cap(task)`` further bounds the wave size for that task's
-        shape bucket (the driver's fixed padded wave width).
-        ``locality_score(task)`` ranks ready tasks by predicted
-        best-replica fetch latency (balanced scheduling, DESIGN.md §9);
-        ``prefetcher`` is a :class:`~repro.core.prefetch.TaskPrefetcher`
-        overlapping upcoming fetches with execution; ``on_scheduler`` is
-        called with the live scheduler so the driver can wire data-plane
-        state changes to :meth:`request_rerank`; ``stopper`` is a
-        :class:`~repro.core.estimator.StoppingController` consulted at
-        wave settlement — on convergence the scheduler cancels its
-        pending tasks and the job drains (DESIGN.md §10);
-        ``crash_hook(worker_id)`` is a fault-injection tick called per
-        claim — it may raise :class:`~repro.core.recovery.WorkerCrash`
-        to kill that worker mid-task (DESIGN.md §12);
-        ``telemetry`` is a
-        :class:`~repro.platform.telemetry.TelemetryBus` the backend
-        threads scheduler events through (disabled bus = no-op sink)."""
-        ...
-
-
 # ---------------------------------------------------------------------------
-# Real threads, real wall time
+# Resident multi-job worker pool (service substrate, DESIGN.md §8)
 # ---------------------------------------------------------------------------
 
 
@@ -112,78 +62,6 @@ def _tax_seconds(plat, took: float) -> float:
     """The emulated runtime taxes that scale with a dispatch's real
     compute: the DFS tax and task-level monitoring (Fig 6)."""
     return plat.dfs_tax * took + (0.20 * took if plat.monitoring else 0.0)
-
-
-class ThreadedBackend:
-    name = "threaded"
-
-    def __init__(self, n_workers: int):
-        self.n_workers = n_workers
-
-    def run(self, tasks, *, compute, fetch, plat, cfg, emit,
-            shape_key=None, compute_wave=None, max_wave=1, wave_cap=None,
-            locality_score=None, prefetcher=None, on_scheduler=None,
-            stopper=None, crash_hook=None, max_respawns=2,
-            telemetry=None):
-        assert compute is not None, "threaded backend needs real compute"
-
-        def run_task(task: sch.Task):
-            _emulated_sleep(plat.launch_overhead)
-            t0 = time.perf_counter()
-            value = compute(task)
-            _emulated_sleep(_tax_seconds(plat, time.perf_counter() - t0))
-            emit(task.task_id, value)
-            return value
-
-        run_wave = None
-        if compute_wave is not None and max_wave > 1:
-            # one launch + one device dispatch amortized over the wave;
-            # runtime taxes (DFS, monitoring) still scale with real compute
-            def run_wave(batch: List[sch.Task]) -> List[Any]:
-                _emulated_sleep(plat.launch_overhead)
-                t0 = time.perf_counter()
-                values = compute_wave(batch)
-                _emulated_sleep(_tax_seconds(plat,
-                                             time.perf_counter() - t0))
-                # one partial per claimed task, in claim order — the
-                # sharded wave path pads per-device lanes, and a
-                # mis-stripped pad would otherwise emit a wrong partial
-                # under a real task id via this zip
-                assert len(values) == len(batch), \
-                    f"wave returned {len(values)} partials for " \
-                    f"{len(batch)} tasks"
-                for task, value in zip(batch, values):
-                    emit(task.task_id, value)
-                return values
-
-        runner = sch.ThreadedRunner(self.n_workers, run_task, fetch=fetch,
-                                    cfg=cfg, run_batch=run_wave,
-                                    batch_key=shape_key,
-                                    max_batch=max_wave,
-                                    batch_cap=wave_cap,
-                                    locality_score=locality_score,
-                                    prefetcher=prefetcher,
-                                    stopper=stopper,
-                                    crash_hook=crash_hook,
-                                    max_respawns=max_respawns,
-                                    telemetry=telemetry)
-        runner.on_scheduler = on_scheduler
-        t0 = time.perf_counter()
-        time.sleep(plat.startup_time)
-        results = runner.run_job(tasks)
-        makespan = time.perf_counter() - t0
-        sched = runner.last_scheduler
-        return BackendOutcome(
-            makespan=makespan, results=results,
-            queue_depths=list(sched.depth_trace) if sched else [],
-            speculative_launches=sched.speculative_launches if sched else 0,
-            speculation_wins=sched.speculation_wins if sched else 0,
-            restarts=runner.worker_respawns)
-
-
-# ---------------------------------------------------------------------------
-# Resident multi-job worker pool (service substrate, DESIGN.md §8)
-# ---------------------------------------------------------------------------
 
 
 @dataclasses.dataclass
@@ -227,16 +105,12 @@ class PoolJob:
 class ServicePool:
     """Resident worker threads draining a multi-job ready queue.
 
-    Unlike :class:`ThreadedBackend` — which builds a thread pool, pays
-    job startup, runs ONE job and tears everything down — the service
-    pool starts once, sleeps ``plat.startup_time`` once, and then serves
-    every job the service admits.  Scheduling policy lives in
+    The pool starts once, sleeps ``plat.startup_time`` once, and then
+    serves every job the service admits.  Scheduling policy lives in
     :class:`~repro.core.scheduler.MultiJobScheduler` (deficit-round-robin
     fairness, deadline boost, cross-job wave fusion); this class owns the
     threads, the per-dispatch platform taxes (launch overhead, DFS,
-    monitoring — identical to the single-job backend so service and
-    standalone execution cost the same per dispatch), and job-completion
-    fan-out."""
+    monitoring), and job-completion fan-out."""
 
     name = "service-pool"
 
@@ -783,20 +657,22 @@ class SimulatedBackend:
                 emit(task.task_id, value)
         return exec_s, fetch_s, time.perf_counter() - t_cal
 
-    def run(self, tasks, *, compute, fetch, plat, cfg, emit,
-            shape_key=None, compute_wave=None, max_wave=1, wave_cap=None,
-            locality_score=None, prefetcher=None, on_scheduler=None,
-            stopper=None, crash_hook=None, max_respawns=2,
-            telemetry=None):
-        # calibration measures per-task costs; waves don't apply, and the
-        # §3.5 fetch/execute overlap is already modeled in virtual time
-        # (queue-warm cost = max(exec, fetch)), so the real prefetcher is
-        # unused; locality ranking applies — replica scores reorder the
-        # virtual-time backlog exactly as they do the threaded one
-        # crash injection is a real-thread concern (virtual-time failure
-        # studies use SimWorker.fail_at instead)
-        del compute_wave, max_wave, wave_cap, prefetcher, on_scheduler
-        del crash_hook, max_respawns
+    def run(self, tasks: Sequence[sch.Task], *,
+            compute: Optional[Callable[[sch.Task], Any]],
+            fetch: Optional[Callable[[sch.Task], Any]], plat,
+            cfg: sch.SchedulerConfig, emit: Callable[[int, Any], None],
+            shape_key: Optional[Callable[[sch.Task], Any]] = None,
+            locality_score: Optional[Callable[[sch.Task], float]] = None,
+            stopper=None, telemetry=None) -> BackendOutcome:
+        """Execute ``tasks`` in virtual time; stream each calibrated
+        partial through ``emit``.  ``shape_key(task)`` buckets tasks by
+        compiled block shape for per-shape cost calibration;
+        ``locality_score(task)`` ranks the backlog by predicted fetch
+        latency (DESIGN.md §9); ``stopper`` is fed each virtual
+        completion and cancels the backlog once it converges (§10).
+        The §3.5 fetch/execute overlap is modelled in virtual time
+        (queue-warm cost = max(exec, fetch)), and virtual-time failure
+        studies use ``SimWorker.fail_at``."""
         calibration = 0.0
         if self.exec_model is not None:
             exec_time = self.exec_model

@@ -622,9 +622,8 @@ def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
     next power of two.
 
     A :class:`ShardedBlockArena` routes to the multi-device dispatch —
-    same signature, bit-identical results — so the wave closures in the
-    driver, service pool and threaded backend need not know whether the
-    arena is sharded.  Both time their stages in host spans of ``clock``:
+    same signature, bit-identical results — so the service's wave
+    closure need not know whether the arena is sharded.  Both time their stages in host spans of ``clock``:
     upload (the slot and seed vectors, built and sent), dispatch, device
     wait, and result fetch (the copy and the split).
     """

@@ -1,19 +1,20 @@
-"""End-to-end tiny-task platform driver (thesis §3, Fig 1/4).
+"""The platform's job substrate (thesis §3, Fig 1/4): what every job is
+configured and planned with, whichever clock it runs on.
 
-One object composes the pieces the thesis argues only win *together*:
+The pieces the thesis argues only win *together*:
 
   kneepoint task sizing (§3.2)  →  datastore distribution (§3.5)
-      →  two-phase dynamic scheduling (§3.4)  →  streaming reduce (§3.1)
+      →  dynamic scheduling (§3.4)  →  streaming reduce (§3.1)
 
-:class:`Platform` takes a dataset (sample dict) + a stats workload (or a
-custom map callable), runs the offline kneepoint phase to size tasks,
-partitions them through the replicated :class:`~repro.core.datastore`
-shards, executes them on a pluggable backend — real threads
-(:class:`~repro.platform.backend.ThreadedBackend`) or virtual-time
-scale-out (:class:`~repro.platform.backend.SimulatedBackend`) — streams
-partials through the deterministic async reduce tree, and emits a
-structured :class:`JobReport` (per-phase timings, queue-depth trace,
-cache-proxy miss curve, straggler counts).
+This module holds the configuration (:class:`PlatformSpec` and its
+option groups), the plan phase (:func:`plan_job`: the kneepoint, the task
+partition, the block shapes), the device-resident wave state
+(:func:`build_wave_context`), the checkpoint of reduce partials
+(:class:`JobCheckpointer`), and the mode resolvers.  Real-time jobs run
+through :class:`~repro.platform.service.PlatformService`; virtual-time
+jobs run through :func:`run_virtual`, the discrete-event model of the
+thesis' scale-out studies.  :class:`~repro.platform.facade.Platform` is
+the one-shot front for both.
 
 The platform *configurations* of the evaluation (§4.1.3) select overhead
 profiles:
@@ -51,18 +52,8 @@ from repro.core.blockcache import BlockCache, CacheOptions
 from repro.core.prefetch import TaskPrefetcher
 from repro.platform import compute as pc
 from repro.platform import telemetry as tel
-from repro.platform.monitor import (
-    MonitorOptions,
-    PlatformMonitor,
-    resolve_monitor_options,
-    write_monitor_report as _write_monitor_report,
-)
-from repro.platform.backend import (
-    BackendOutcome,
-    PlatformBackend,
-    SimulatedBackend,
-    ThreadedBackend,
-)
+from repro.platform.backend import BackendOutcome, SimulatedBackend
+from repro.platform.monitor import resolve_monitor_options
 from repro.platform.reduce import StreamingReduceTree, finalize_stats
 
 
@@ -295,54 +286,6 @@ class PlatformSpec:
                            resolve_monitor_options(self.monitor))
 
 
-@dataclasses.dataclass
-class JobReport:
-    """Structured job outcome — superset of the legacy tiny_task report."""
-
-    platform: str
-    n_tasks: int
-    task_size_bytes: float
-    makespan: float
-    throughput_bps: float      # input bytes / second
-    startup_time: float
-    result: Optional[dict] = None
-    kneepoint: Optional[kp.KneepointResult] = None
-    # platform-driver extensions
-    backend: str = "threaded"
-    engine: str = "auto"
-    phases: Dict[str, float] = dataclasses.field(default_factory=dict)
-    queue_depths: List[int] = dataclasses.field(default_factory=list)
-    miss_curve: Tuple[kp.CurvePoint, ...] = ()
-    max_task_bytes: float = 0.0
-    stragglers: int = 0
-    speculative_launches: int = 0
-    restarts: int = 0
-    calibration_seconds: float = 0.0
-    datastore_stats: Optional[Dict[str, float]] = None
-    reduce_info: Optional[Dict[str, float]] = None
-    # wave-execution observability (execute-phase map dispatches only;
-    # warmup/kneepoint compiles are startup cost and are not counted)
-    device_dispatches: int = 0
-    bytes_uploaded: float = 0.0
-    wave_sizes: List[int] = dataclasses.field(default_factory=list)
-    # balanced-scheduling observability (DESIGN.md §9)
-    speculation_wins: int = 0
-    scale_decision: Optional[str] = None    # slo.choose_workers reasoning
-    n_workers_used: int = 0
-    prefetch_stats: Optional[Dict[str, float]] = None
-    # worker-side block cache observability (DESIGN.md §14)
-    cache_stats: Optional[Dict[str, float]] = None
-    # error-bounded approximate execution (DESIGN.md §10)
-    tasks_executed: int = 0
-    tasks_cancelled: int = 0
-    stop_reason: Optional[str] = None       # None ⇒ ran to completion
-    final_ci: Optional[Dict[str, Any]] = None   # EstimateSnapshot dict
-    # failure model / recovery observability (DESIGN.md §12)
-    tasks_restored: int = 0        # leaves restored from a checkpoint
-    checkpoint_saves: int = 0      # committed checkpoint steps this run
-    fault_events: int = 0          # injected faults that fired this run
-
-
 def make_tasks(sample_sizes: Sequence[int], sizing: str,
                knee_bytes: Optional[float], n_workers: int) -> List[sch.Task]:
     """Partition samples into tasks per the config's sizing policy."""
@@ -364,32 +307,55 @@ def make_tasks(sample_sizes: Sequence[int], sizing: str,
     return out
 
 
+MapFn = Callable[[sch.Task, np.ndarray, np.ndarray, int], Dict[str, Any]]
+
+
+class CustomMap:
+    """A workload computed by a caller's per-task callable
+    ``map_fn(task, block, months, seed) -> partial`` in place of a
+    statistic engine (overhead benchmarks): engine ``"custom"``, per-task
+    dispatch, partials reduced as they are.  Identity is the instance, so
+    two callables never share a query class."""
+
+    statistic = "custom"
+    name = "custom"
+
+    def __init__(self, map_fn: MapFn):
+        self.map_fn = map_fn
+
+
+def resolve_job_engine(workload, engine: str) -> str:
+    """``"custom"`` for a :class:`CustomMap`, else the statistic's engine
+    (:func:`~repro.platform.compute.resolve_engine`)."""
+    if isinstance(workload, CustomMap):
+        return "custom"
+    return pc.resolve_engine(workload.statistic, engine)
+
+
 def measure_kneepoint(samples: Dict[int, np.ndarray],
                       months: Dict[int, np.ndarray],
                       workload,
                       sizes: Sequence[int] = (1, 2, 4, 8, 16, 32, 64),
                       *,
                       engine: str = "auto",
-                      map_fn: Optional["MapFn"] = None,
                       ) -> Tuple[kp.KneepointResult, float]:
     """Offline phase (Fig 3): run isolated map tasks of increasing block
     size, record per-sample wall time (the cost-per-byte miss proxy of
-    DESIGN.md §2), find the knee.  With ``map_fn`` the curve is measured
+    DESIGN.md §2), find the knee.  A :class:`CustomMap` curve is measured
     on the custom compute that will actually execute."""
     ids = sorted(samples)
     sample_bytes = np.mean([samples[i].nbytes for i in ids])
-    eng = (None if map_fn is not None
-           else pc.resolve_engine(workload.statistic, engine))
+    eng = resolve_job_engine(workload, engine)
 
     def exec_task(n: int) -> float:
         n = min(n, len(ids))
         block = np.stack(pc.pad_to_common([samples[i] for i in ids[:n]]))
         mo = np.stack(pc.pad_to_common([months[i] for i in ids[:n]]))
         t0 = time.perf_counter()
-        if map_fn is not None:
+        if eng == "custom":
             probe = sch.Task(task_id=-1, sample_ids=tuple(range(n)),
                              size_bytes=float(n * sample_bytes))
-            map_fn(probe, block, mo, 0)
+            workload.map_fn(probe, block, mo, 0)
         else:
             pc.run_map_task(block, mo, 0, workload, eng)
         return (time.perf_counter() - t0) / n
@@ -407,7 +373,8 @@ def measure_per_sample_cost(samples: Dict[int, np.ndarray],
                             workload, *, block: int = 8,
                             engine: str = "auto", repeats: int = 3) -> float:
     """Median seconds per sample for a ``block``-sized map task — the
-    calibration input for :meth:`Platform.run_scaleout` cost models."""
+    calibration input for
+    :meth:`~repro.platform.facade.Platform.run_scaleout` cost models."""
     ids = sorted(samples)[:block]
     arr = np.stack(pc.pad_to_common([samples[i] for i in ids]))
     mo = np.stack(pc.pad_to_common([months[i] for i in ids]))
@@ -421,12 +388,9 @@ def measure_per_sample_cost(samples: Dict[int, np.ndarray],
     return sorted(times)[len(times) // 2] / len(ids)
 
 
-MapFn = Callable[[sch.Task, np.ndarray, np.ndarray, int], Dict[str, Any]]
-
-
 # ---------------------------------------------------------------------------
-# Reusable job phases (plan → wave/task contexts) — the substrate shared
-# by one-shot Platform.run and the persistent PlatformService
+# Reusable job phases (plan → wave/task contexts) — the substrate of the
+# service's query classes and of the virtual-time run
 # ---------------------------------------------------------------------------
 
 
@@ -459,23 +423,23 @@ def plan_job(samples: Dict[int, np.ndarray],
              n_exec: int,
              knee_bytes: Optional[float] = None,
              kneepoint_sizes: Sequence[int] = (1, 2, 4, 8, 16, 32, 64),
-             map_fn: Optional[MapFn] = None) -> JobPlan:
+             ) -> JobPlan:
     """Phases 1-2 of the data path minus datastore placement: measure the
     kneepoint if the sizing policy needs one, partition samples into
-    tasks, and derive the padded-shape / block-building closures."""
+    tasks, and derive the padded-shape / block-building closures.  A
+    :class:`CustomMap` is calibrated on its own callable."""
     ids = sorted(samples)
     sizes = [samples[i].nbytes for i in ids]
     knee_res = None
     t0 = time.perf_counter()
     if sizing == "kneepoint" and knee_bytes is None:
         knee_res, knee_bytes = measure_kneepoint(
-            samples, months, workload, sizes=kneepoint_sizes,
-            engine="auto" if engine == "custom" else engine, map_fn=map_fn)
+            samples, months, workload, sizes=kneepoint_sizes, engine=engine)
     plan_seconds = time.perf_counter() - t0
     t0 = time.perf_counter()
     tasks = make_tasks(sizes, sizing, knee_bytes, n_exec)
     max_count = max(len(t.sample_ids) for t in tasks)
-    pad_len = (0 if map_fn is not None else
+    pad_len = (0 if engine == "custom" else
                pc.partial_pad_len(workload.statistic, samples))
 
     shapes: Dict[Tuple[int, ...], Tuple[int, int]] = {}
@@ -594,27 +558,24 @@ def resolve_platform_config(spec: PlatformSpec) -> PlatformConfig:
     return dataclasses.replace(plat, **overrides) if overrides else plat
 
 
-def wave_enabled(spec: PlatformSpec, engine: str, workload,
-                 has_map_fn: bool = False) -> bool:
+def wave_enabled(spec: PlatformSpec, engine: str, workload) -> bool:
     """Wave execution needs the threaded backend (the simulator
-    calibrates per-task costs) and a device engine; ``wave="on"``
-    makes an unsupported combination an error instead of a silent
-    per-task fallback.  ``"auto"`` additionally requires the workload
-    to be dispatch-overhead-bound (small per-task draw volume) —
-    batching heavy tasks pays pad compute for nothing."""
+    calibrates per-task costs) and a device engine (not a custom
+    map_fn); ``wave="on"`` makes an unsupported combination an error
+    instead of a silent per-task fallback.  ``"auto"`` additionally
+    requires the workload to be dispatch-overhead-bound (small per-task
+    draw volume) — batching heavy tasks pays pad compute for nothing."""
     if spec.wave not in ("auto", "on", "off"):
         raise ValueError(f"unknown wave mode {spec.wave!r}; "
                          "choose 'auto', 'on' or 'off'")
     if spec.wave == "off" or spec.max_wave <= 1:
         return False
-    supported = (spec.backend == "threaded" and not has_map_fn
-                 and pc.wave_supported(engine))
+    supported = spec.backend == "threaded" and pc.wave_supported(engine)
     if spec.wave == "on" and not supported:
         raise ValueError(
             "wave='on' needs the threaded backend and a device engine "
-            f"(pallas|jnp) with no custom map_fn; got backend="
-            f"{spec.backend!r}, engine={engine!r}, map_fn="
-            f"{'set' if has_map_fn else 'None'}")
+            f"(pallas|jnp); got backend={spec.backend!r}, "
+            f"engine={engine!r}")
     if spec.wave == "auto":
         return supported and pc.wave_profitable(workload)
     return supported
@@ -765,6 +726,10 @@ class JobCheckpointer:
         if not due:
             return
         if self.injector is not None:
+            # a planned crash hits this save where a real one would: the
+            # manager writes one save at a time, so the previous step
+            # has committed by now
+            self.mgr.wait()
             self.injector.checkpoint_tick()
         state: Dict[str, np.ndarray] = {}
         for tid, partial in snap.items():
@@ -787,573 +752,341 @@ class JobCheckpointer:
         self.mgr.wait()
 
     @staticmethod
-    def load(directory: str) -> Tuple[Dict[int, Dict[str, Any]],
-                                      Optional[int]]:
-        """Restore ``({task_id: partial}, n_tasks)`` from the newest
-        committed checkpoint; ``({}, None)`` when none exists."""
+    def load(directory: str, n_tasks: int) -> Dict[int, Dict[str, Any]]:
+        """Restore ``{task_id: partial}`` from the newest committed
+        checkpoint of a job of ``n_tasks`` tasks; ``{}`` when none
+        exists.  A checkpoint of another plan is an error: resume needs
+        the same dataset, workload, sizing and knee."""
         mgr = CheckpointManager(directory)
         flat = mgr.restore_latest()
         if flat is None:
-            return {}, None
+            return {}
         partials: Dict[int, Dict[str, Any]] = {}
-        n_tasks: Optional[int] = None
         for key, arr in flat.items():
             # names are jax keystr forms of flat-dict keys: "['12/sum']"
             if key.startswith("['") and key.endswith("']"):
                 key = key[2:-2]
             if key.startswith("__meta__/"):
-                if key == "__meta__/n_tasks":
-                    n_tasks = int(arr)
+                if key == "__meta__/n_tasks" and int(arr) != n_tasks:
+                    raise ValueError(
+                        f"checkpoint at {directory!r} holds partials for "
+                        f"{int(arr)} tasks but this plan has {n_tasks} — "
+                        "resume needs the same dataset, workload, sizing "
+                        "and knee")
                 continue
             tid, name = key.split("/", 1)
             partials.setdefault(int(tid), {})[name] = np.asarray(arr)
-        return partials, n_tasks
+        return partials
 
 
-class Platform:
-    """The end-to-end driver.  ``datastore`` is an optional
-    :class:`~repro.core.datastore.ReplicatedDataStore`; ``map_fn`` replaces
-    the workload engine with a custom per-task callable
-    ``(task, block, months, seed) -> partial`` (overhead benchmarks);
-    ``fault_injector`` is an optional
-    :class:`~repro.platform.faults.FaultInjector` driving a seeded
-    :class:`~repro.platform.faults.FaultPlan` through the run
-    (DESIGN.md §12)."""
+def scheduler_config(spec: PlatformSpec,
+                     plat: PlatformConfig) -> sch.SchedulerConfig:
+    """The virtual-time two-phase policy a spec selects."""
+    if spec.scheduler is not None:
+        return spec.scheduler
+    return sch.SchedulerConfig(
+        recovery=plat.recovery, seed=spec.seed,
+        speculative=resolve_speculation(spec),
+        straggler_factor=spec.straggler_factor)
 
-    def __init__(self, spec: PlatformSpec = PlatformSpec(), *,
-                 datastore=None, map_fn: Optional[MapFn] = None,
-                 fault_injector=None):
-        self.spec = spec
-        self.datastore = datastore
-        self.map_fn = map_fn
-        self.fault_injector = fault_injector
-        # one bus per driver; the simulated backend emits virtual
-        # timestamps, so its bus must not fall back to wall time
-        self.telemetry = tel.TelemetryBus(
-            tel.resolve_telemetry_config(spec.telemetry),
-            virtual=(spec.backend == "simulated"))
-        # SLO monitor (DESIGN.md §15): a tap-driven bus consumer, built
-        # only when enabled — the default leaves the bus untapped (zero
-        # new events, zero threads, bit-identical results)
-        self.monitor: Optional[PlatformMonitor] = None
-        if spec.monitor.enabled:
-            self.monitor = PlatformMonitor(self.telemetry, spec.monitor,
-                                           wave_capacity=spec.max_wave)
 
-    # -- config plumbing -----------------------------------------------------
-    def _platform_config(self) -> PlatformConfig:
-        return resolve_platform_config(self.spec)
+def run_virtual(spec: PlatformSpec, samples: Dict[int, np.ndarray],
+                months: Dict[int, np.ndarray], workload, *,
+                datastore=None, fault_injector=None,
+                resume_from: Optional[str] = None,
+                telemetry: Optional[tel.TelemetryBus] = None
+                ) -> Dict[str, Any]:
+    """One job in virtual time: kneepoint → distribute →
+    :class:`~repro.platform.backend.SimulatedBackend` (real compute
+    measured once per task, then the two-phase policy replayed at the
+    spec's worker count) → streaming reduce.  Returns the
+    :class:`~repro.platform.facade.JobReport` fields.
 
-    def _n_exec_workers(self) -> int:
-        if self.spec.backend == "simulated" and self.spec.sim_workers:
-            return len(self.spec.sim_workers)
-        return self.spec.n_workers
+    ``resume_from`` names a checkpoint directory written by a prior
+    (interrupted) run of the same job: its committed leaf partials are
+    restored into the reduce tree and only the missing tasks execute —
+    bit-identical to an uninterrupted run (§12).  ``telemetry`` is a
+    virtual bus for the run's events (a disabled one by default)."""
+    bus = (telemetry if telemetry is not None
+           else tel.TelemetryBus(virtual=True))
+    plat = resolve_platform_config(spec)
+    engine = resolve_job_engine(workload, spec.engine)
+    phases: Dict[str, float] = {}
+    # validated up front: balanced="on" without a datastore (and any
+    # bad mode string) must error, never silently run FIFO; the same
+    # for waves, a mesh and prefetch, which virtual time models itself
+    balanced_on = balanced_enabled(spec, datastore is not None)
+    resolve_wave_mesh(spec, wave_enabled(spec, engine, workload))
+    prefetch_enabled(spec, datastore is not None)
+    n_exec = len(spec.sim_workers) if spec.sim_workers else spec.n_workers
 
-    def _scheduler_cfg(self, plat: PlatformConfig) -> sch.SchedulerConfig:
-        if self.spec.scheduler is not None:
-            return self.spec.scheduler
-        return sch.SchedulerConfig(
-            recovery=plat.recovery, seed=self.spec.seed,
-            speculative=resolve_speculation(self.spec),
-            straggler_factor=self.spec.straggler_factor,
-            lease_seconds=self.spec.lease_seconds)
+    # phases 1-2 — offline kneepoint (thesis §3.2: ≈3% of online time),
+    # then partition + distribute onto the data plane
+    plan = plan_job(samples, months, workload, sizing=plat.task_sizing,
+                    engine=engine, n_exec=n_exec,
+                    knee_bytes=spec.knee_bytes,
+                    kneepoint_sizes=spec.kneepoint_sizes)
+    phases["plan"] = plan.plan_seconds
+    bus.emit("job_planned", n_tasks=len(plan.tasks),
+             knee_bytes=plan.knee_bytes, engine=engine)
+    if datastore is not None:
+        datastore.telemetry = bus
+        # worker-side block cache (DESIGN.md §14): attached once and
+        # kept on the store across runs
+        if spec.cache.enabled and datastore.cache is None:
+            datastore.cache = BlockCache(spec.cache)
+    if fault_injector is not None:
+        fault_injector.telemetry = bus
+    t0 = time.perf_counter()
+    if datastore is not None:
+        datastore.put_all({i: samples[i] for i in plan.ids})
+        if balanced_on:
+            # seed the per-node response-time EMAs (phase-1 probe of
+            # the data plane) so the first claims are not blind
+            datastore.probe()
+    phases["distribute"] = (plan.partition_seconds
+                            + time.perf_counter() - t0)
+    tasks, ids = plan.tasks, plan.ids
 
-    def _backend(self, n_workers: Optional[int] = None) -> PlatformBackend:
-        n = n_workers if n_workers is not None else self.spec.n_workers
-        if self.spec.backend == "threaded":
-            return ThreadedBackend(n)
-        if self.spec.backend == "simulated":
-            workers = (list(self.spec.sim_workers) if self.spec.sim_workers
-                       else n)
-            return SimulatedBackend(workers,
-                                    compute_values=self.spec.compute_values,
-                                    startup_scale=self.spec.startup_scale)
-        raise ValueError(f"unknown backend {self.spec.backend!r}")
+    # resume (DESIGN.md §12): restore committed leaf partials and
+    # execute only the missing tasks; the tree keeps its full shape so
+    # the combined result is bit-identical to an unbroken run
+    restored = (JobCheckpointer.load(resume_from, len(tasks))
+                if resume_from is not None else {})
+    run_tasks = ([t for t in tasks if t.task_id not in restored]
+                 if restored else tasks)
 
-    def _wave_enabled(self, engine: str, workload) -> bool:
-        return wave_enabled(self.spec, engine, workload,
-                            has_map_fn=self.map_fn is not None)
+    # SLO-aware pool sizing (slo.choose_workers over the knee-derived
+    # throughput model); explicit sim worker lists are respected
+    decision = (None if spec.sim_workers
+                else slo_worker_decision(spec, plat, plan))
+    n_eff = decision.cores if decision is not None else n_exec
+    # all dispatch accounting flows through the bus's aggregation path
+    # into this one sink (DESIGN.md §13)
+    dispatch = pc.DispatchStats()
+    bus.bind_dispatch(dispatch)
 
-    # -- the full data path --------------------------------------------------
-    def run(self, samples: Dict[int, np.ndarray],
-            months: Dict[int, np.ndarray], workload, *,
-            resume_from: Optional[str] = None) -> JobReport:
-        """Kneepoint → distribute → schedule/execute → streaming reduce.
+    # phase 3 — compile warmup: one kernel per distinct block shape, so
+    # the calibration pass measures execution, not compilation; the
+    # warm blocks are reused by phase 4
+    t0 = time.perf_counter()
+    block_cache: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+    if engine in ("jnp", "pallas"):
+        seen = set()
+        for task in tasks:
+            key = plan.task_shape(task)
+            if key not in seen:
+                seen.add(key)
+                block, mo = block_cache[task.task_id] = \
+                    plan.build_block(task)
+                pc.run_map_task(block, mo, spec.seed + task.task_id,
+                                workload, engine)
+    phases["compile"] = time.perf_counter() - t0
 
-        ``resume_from`` names a checkpoint directory written by a prior
-        (interrupted) run of the same job: its committed leaf partials
-        are restored into the reduce tree and only the missing tasks
-        execute — bit-identical to an uninterrupted run (§12)."""
-        spec = self.spec
-        plat = self._platform_config()
-        engine = ("custom" if self.map_fn is not None
-                  else pc.resolve_engine(workload.statistic, spec.engine))
-        phases: Dict[str, float] = {}
-        # validated up front: balanced="on" without a datastore (and any
-        # bad mode string) must error, never silently run FIFO
-        balanced_on = balanced_enabled(spec, self.datastore is not None)
+    def compute_task(task: sch.Task):
+        cached = block_cache.pop(task.task_id, None)
+        block, mo = cached if cached is not None else \
+            plan.build_block(task)
+        task_seed = spec.seed + task.task_id
+        if engine == "custom":
+            return workload.map_fn(task, block, mo, task_seed)
+        if engine in ("jnp", "pallas"):
+            bus.emit("task_dispatched", task_id=task.task_id,
+                     nbytes=float(block.nbytes) + (
+                         float(mo.nbytes) if engine == "jnp" else 0.0))
+        return pc.run_map_task(block, mo, task_seed, workload, engine)
 
-        # phases 1-2 — offline kneepoint (thesis §3.2: ≈3% of online
-        # time; a custom map_fn is calibrated on itself, not the workload
-        # engine), then partition + distribute onto the data plane
-        plan = plan_job(samples, months, workload,
-                        sizing=plat.task_sizing, engine=engine,
-                        n_exec=self._n_exec_workers(),
-                        knee_bytes=spec.knee_bytes,
-                        kneepoint_sizes=spec.kneepoint_sizes,
-                        map_fn=self.map_fn)
-        phases["plan"] = plan.plan_seconds
-        bus = self.telemetry
-        bus.emit("job_planned", n_tasks=len(plan.tasks),
-                 knee_bytes=plan.knee_bytes, engine=engine)
-        if self.datastore is not None:
-            self.datastore.telemetry = bus
-            # worker-side block cache (DESIGN.md §14): attached once and
-            # kept on the store across runs so repeat queries over the
-            # same dataset hit warm blocks
-            if spec.cache.enabled and self.datastore.cache is None:
-                self.datastore.cache = BlockCache(spec.cache)
-        if self.fault_injector is not None:
-            self.fault_injector.telemetry = bus
-        t0 = time.perf_counter()
-        if self.datastore is not None:
-            self.datastore.put_all({i: samples[i] for i in plan.ids})
-            if balanced_on:
-                # seed the per-node response-time EMAs (phase-1 probe of
-                # the data plane) so the first claims are not blind
-                self.datastore.probe()
-        phases["distribute"] = (plan.partition_seconds
-                                + time.perf_counter() - t0)
-        tasks, ids, task_shape = plan.tasks, plan.ids, plan.task_shape
+    fetch = None
+    locality_score = None
+    if datastore is not None:
+        def fetch(task: sch.Task):
+            datastore.fetch_many([ids[sid] for sid in task.sample_ids])
 
-        # resume (DESIGN.md §12): restore committed leaf partials and
-        # execute only the missing tasks; the tree keeps its full shape
-        # so the combined result is bit-identical to an unbroken run
-        restored: Dict[int, Dict[str, Any]] = {}
-        if resume_from is not None:
-            restored, ckpt_n = JobCheckpointer.load(resume_from)
-            if ckpt_n is not None and ckpt_n != len(tasks):
-                raise ValueError(
-                    f"checkpoint at {resume_from!r} holds partials for "
-                    f"{ckpt_n} tasks but this plan produced {len(tasks)}"
-                    " — resume needs the same dataset, sizing and knee")
-        run_tasks = ([t for t in tasks if t.task_id not in restored]
-                     if restored else tasks)
+        if balanced_on:
+            def locality_score(task: sch.Task) -> float:
+                return datastore.predicted_task_fetch(
+                    [ids[sid] for sid in task.sample_ids])
 
-        # SLO-aware pool sizing (slo.choose_workers over the knee-derived
-        # throughput model); explicit sim worker lists are respected
-        decision = (None if spec.sim_workers
-                    else slo_worker_decision(spec, plat, plan))
-        n_eff = decision.cores if decision is not None \
-            else self._n_exec_workers()
+    # phase 4 — execute; partials stream into the reduce tree.  With an
+    # epsilon target (DESIGN.md §10) the calibration computes EVERY
+    # partial (that is how the simulator measures costs); they are
+    # captured so the replay stopper observes only virtually-completed
+    # tasks and the final reduce covers only those
+    statistic = workload.statistic
+    approx = spec.epsilon is not None
+    # validated before the tree exists: a constructor ValueError below
+    # would leak the tree's combiner thread
+    est_mod.validate_error_target(spec.epsilon, spec.confidence)
+    if approx and not spec.compute_values:
+        raise ValueError(
+            "epsilon needs computed partials to estimate from; "
+            "simulated specs must keep compute_values=True")
+    tree, stopper, sim_partials = None, None, None
+    emit: Callable[[int, Any], None] = lambda tid, v: None
+    if spec.compute_values:
+        tree = StreamingReduceTree(len(tasks))
+        emit = tree.offer
+        if approx:
+            sim_partials = {}
 
-        wave_on = self._wave_enabled(engine, workload)
-        mesh = resolve_wave_mesh(spec, wave_on)
-        # all dispatch accounting flows through the bus's aggregation
-        # path into this one sink (DESIGN.md §13)
-        dispatch = pc.DispatchStats()
-        bus.bind_dispatch(dispatch)
-        block_cache: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+            def emit(tid, v, _offer=tree.offer, _cap=sim_partials):
+                _cap[tid] = v
+                _offer(tid, v)
 
-        def compute_task(task: sch.Task):
-            # warmup already built this task's block: reuse, don't rebuild
-            cached = block_cache.pop(task.task_id, None)
-            block, mo = cached if cached is not None else \
-                plan.build_block(task)
-            task_seed = spec.seed + task.task_id
-            if self.map_fn is not None:
-                return self.map_fn(task, block, mo, task_seed)
-            if engine in ("jnp", "pallas"):
-                bus.emit("task_dispatched", task_id=task.task_id,
-                         nbytes=float(block.nbytes) + (
-                             float(mo.nbytes) if engine == "jnp" else 0.0))
-            return pc.run_map_task(block, mo, task_seed, workload, engine)
+            stopper = est_mod.ReplayStopper(
+                est_mod.SubsampleEstimator(statistic, spec.confidence),
+                spec.epsilon, partials=sim_partials,
+                min_tasks=spec.min_tasks)
+    # restored leaves enter the tree (and any estimator) first, exactly
+    # as if those tasks had just completed — BEFORE the checkpoint/
+    # injector wraps so they neither re-save nor tick the injector's
+    # completion clock
+    for tid in sorted(restored):
+        emit(tid, restored[tid])
+    if restored:
+        bus.emit("checkpoint_restored", n=len(restored),
+                 task_ids=tuple(sorted(restored)))
+    ckpt: Optional[JobCheckpointer] = None
+    if spec.checkpoint_dir is not None and tree is not None:
+        ckpt = JobCheckpointer(
+            spec.checkpoint_dir, len(tasks), every=spec.checkpoint_every,
+            restored=restored, injector=fault_injector, telemetry=bus)
+        prev_emit = emit
 
-        fetch = None
-        locality_score = None
-        on_scheduler = None
-        if self.datastore is not None:
-            store = self.datastore
+        def emit(tid, v, _prev=prev_emit, _c=ckpt):
+            _prev(tid, v)
+            _c.offer(tid, v)
 
-            def fetch(task: sch.Task):
-                store.fetch_many([ids[sid] for sid in task.sample_ids])
-
-            if balanced_on:
-                def locality_score(task: sch.Task) -> float:
-                    return store.predicted_task_fetch(
-                        [ids[sid] for sid in task.sample_ids])
-
-                def on_scheduler(live) -> None:
-                    # a node turning degraded/down re-ranks ready tasks
-                    store.on_state_change = \
-                        lambda node: live.request_rerank()
-                    # cache admissions/evictions shift locality scores
-                    # the same way (DESIGN.md §14)
-                    if store.cache is not None:
-                        store.cache.on_change = \
-                            lambda: live.request_rerank()
-        prefetcher = (build_prefetcher(n_eff)
-                      if prefetch_enabled(spec, fetch is not None)
-                      else None)
-        if prefetcher is not None and self.datastore is not None \
-                and self.datastore.cache is not None:
-            # cache-resident tasks need no background fetch: their
-            # claim-time ensure() is served worker-side for free
-            prefetcher.resident = (
-                lambda task, _s=self.datastore, _ids=ids:
-                _s.cache_covers([_ids[sid] for sid in task.sample_ids]))
-
-        # phase 3 — compile warmup: one kernel per distinct block shape
-        # (precompiled task binaries are startup cost, Fig 5).  Wave mode
-        # packs the whole job into the device-resident block arena here —
-        # one upload for the job — and warms one full-size wave per shape;
-        # per-task mode builds one block per distinct shape and caches it
-        # so phase 4 does not rebuild it (the numpy engine skips warmup
-        # entirely: there is nothing to compile).
-        t0 = time.perf_counter()
-        ctx: Optional[WaveContext] = None
-        compute_wave = None
-        if wave_on:
-            ctx = build_wave_context(plan, workload,
-                                     n_exec=n_eff,
-                                     max_wave=spec.max_wave,
-                                     warm_seed=spec.seed,
-                                     mesh=mesh)
-            bus.emit("arena_upload", nbytes=float(ctx.arena.nbytes))
-
-            def compute_wave(batch: List[sch.Task]):
-                seeds = np.asarray([spec.seed + t.task_id for t in batch],
-                                   np.int32)
-                t_wave = bus.now()
-                values = ctx.run(batch, seeds)
-                # the arena is resident; a wave uploads only its slot
-                # and seed vectors
-                bus.emit("wave_dispatched", ts=t_wave,
-                         wave_size=len(batch),
-                         nbytes=ctx.wave_bytes(len(batch)),
-                         task_ids=tuple(t.task_id for t in batch),
-                         seconds=bus.now() - t_wave)
-                return values
-        elif engine in ("jnp", "pallas"):
-            seen = set()
-            for task in tasks:
-                key = task_shape(task)
-                if key not in seen:
-                    seen.add(key)
-                    block, mo = plan.build_block(task)
-                    block_cache[task.task_id] = (block, mo)
-                    pc.run_map_task(block, mo, spec.seed + task.task_id,
-                                    workload, engine)
-        phases["compile"] = time.perf_counter() - t0
-
-        # phase 4 — execute; partials stream into the reduce tree.  With
-        # an epsilon target (DESIGN.md §10) an estimator rides along: the
-        # threaded combiner feeds it leaf by leaf, the simulator replays
-        # the calibration partials in virtual completion order; either
-        # way the backend's scheduler DRAINs once the CI converges.
-        want_values = (spec.backend == "threaded" or spec.compute_values)
-        statistic = getattr(workload, "statistic", "custom")
-        approx = spec.epsilon is not None
-        # validated before the tree exists: a constructor ValueError
-        # below would leak the tree's combiner thread
-        est_mod.validate_error_target(spec.epsilon, spec.confidence)
-        if approx and not want_values:
-            raise ValueError(
-                "epsilon needs computed partials to estimate from; "
-                "simulated specs must keep compute_values=True")
-        tree, stopper, sim_partials = None, None, None
-        emit: Callable[[int, Any], None] = lambda tid, v: None
-        if want_values:
-            if approx:
-                estimator = est_mod.SubsampleEstimator(
-                    statistic, spec.confidence)
-                if spec.backend == "threaded":
-                    tree = StreamingReduceTree(len(tasks),
-                                               estimator=estimator)
-                    emit = tree.offer
-                    stopper = est_mod.StoppingController(
-                        estimator, spec.epsilon, min_tasks=spec.min_tasks)
-                else:
-                    # calibration computes EVERY partial (that is how the
-                    # simulator measures costs); capture them so the
-                    # replay stopper observes only virtually-completed
-                    # tasks and the final reduce covers only those
-                    sim_partials = {}
-                    tree = StreamingReduceTree(len(tasks))
-
-                    def emit(tid, v, _offer=tree.offer,
-                             _cap=sim_partials):
-                        _cap[tid] = v
-                        _offer(tid, v)
-
-                    stopper = est_mod.ReplayStopper(
-                        estimator, spec.epsilon, partials=sim_partials,
-                        min_tasks=spec.min_tasks)
-            else:
-                tree = StreamingReduceTree(len(tasks))
-                emit = tree.offer
-        # restored leaves enter the tree (and any estimator) first,
-        # exactly as if those tasks had just completed — BEFORE the
-        # checkpoint/injector wraps so they neither re-save nor tick the
-        # injector's completion clock
-        for tid in sorted(restored):
-            emit(tid, restored[tid])
-        if restored:
-            bus.emit("checkpoint_restored", n=len(restored),
-                     task_ids=tuple(sorted(restored)))
-        ckpt: Optional[JobCheckpointer] = None
-        if spec.checkpoint_dir is not None and tree is not None:
-            ckpt = JobCheckpointer(
-                spec.checkpoint_dir, len(tasks),
-                every=spec.checkpoint_every, restored=restored,
-                injector=self.fault_injector, telemetry=bus)
-            prev_emit = emit
-
-            def emit(tid, v, _prev=prev_emit, _c=ckpt):
-                _prev(tid, v)
-                _c.offer(tid, v)
-
-        injector = self.fault_injector
-        if injector is not None:
-            if self.datastore is not None:
-                injector.attach_store(self.datastore)
-            emit = injector.wrap_emit(emit)
-        # execute-window anchor for the critical-path analyzer: bus time
-        # just before the backend starts (0.0 on a virtual bus — the sim
-        # clock opens at startup_time, so the window equals the virtual
-        # makespan)
-        t_execute = bus.now()
-        t0 = time.perf_counter()
-        try:
-            outcome = self._backend(n_eff).run(
-                run_tasks, compute=compute_task, fetch=fetch, plat=plat,
-                cfg=self._scheduler_cfg(plat), emit=emit,
-                shape_key=task_shape, compute_wave=compute_wave,
-                max_wave=spec.max_wave if wave_on else 1,
-                wave_cap=(ctx.cap if wave_on else None),
-                locality_score=locality_score,
-                prefetcher=prefetcher,
-                on_scheduler=on_scheduler,
-                stopper=stopper,
-                crash_hook=(injector.worker_tick
-                            if injector is not None else None),
-                max_respawns=spec.max_respawns,
-                telemetry=bus)
-            phases["execute"] = time.perf_counter() - t0
-            if ckpt is not None:
-                # surface any parked async-save error: a job that "ran"
-                # but silently failed to persist its restore point must
-                # not report success (§12 durability contract)
-                ckpt.finish()
-
-            # phase 5 — drain the reduce tree, finalize the statistic.
-            # An early-stopped job finalizes over its executed subset in
-            # the same fixed tree order (deterministic for the set).
-            t0 = time.perf_counter()
-            result, reduce_info = None, None
-            if tree is not None:
-                if stopper is not None and stopper.stopped:
-                    executed = ({r.task_id for r in outcome.results}
-                                | set(restored))
-                    if sim_partials is not None:
-                        root = StreamingReduceTree.combine_subset(
-                            len(tasks),
-                            {tid: sim_partials[tid]
-                             for tid in sorted(executed)})
-                        tree.close()       # full-leaf stream, unused now
-                    else:
-                        tree.wait_leaves(len(executed), timeout=600.0)
-                        root = tree.snapshot()
-                        tree.close()
-                    result = finalize_stats(root, statistic)
-                else:
-                    root = tree.result(timeout=600.0)
-                    result = finalize_stats(root, statistic)
-                reduce_info = tree.stats()
-            phases["reduce"] = time.perf_counter() - t0
-        except BaseException:
-            if tree is not None:
-                tree.close()           # unblock the combiner thread
-            raise
-        finally:
-            if prefetcher is not None:
-                stats = prefetcher.stats()
-                bus.emit("prefetch_stats",
-                         hits=int(stats["prefetch_hits"]),
-                         misses=int(stats["prefetch_misses"]))
-                prefetcher.close()
-            if self.datastore is not None:
-                self.datastore.on_state_change = None
-                self.datastore.telemetry = None
-                if self.datastore.cache is not None:
-                    # the cache (and its contents) outlives the run; the
-                    # rerank hook must not — it closes over this run's
-                    # scheduler
-                    self.datastore.cache.on_change = None
-
-        if self.datastore is not None:
-            for r in outcome.results:
-                self.datastore.report_exec_time(r.exec_time)
-
-        if stopper is not None:
-            ci = stopper.snapshot()
-            if ci is not None:
-                bus.emit("ci_snapshot", **ci.as_dict())
-        bus.emit("job_done", makespan=outcome.makespan,
-                 tasks_executed=len({r.task_id for r in outcome.results}),
-                 t_execute=t_execute,
-                 startup_seconds=(plat.startup_time * spec.startup_scale
-                                  if spec.backend == "simulated"
-                                  else plat.startup_time),
-                 reduce_seconds=phases.get("reduce", 0.0))
-        return self._report(plat, outcome, tasks, plan.total_bytes,
-                            plan.knee_bytes, plan.knee_res, engine, phases,
-                            result, reduce_info, dispatch=dispatch,
-                            scale_decision=decision, n_workers_used=n_eff,
-                            prefetch_stats=(stats if prefetcher is not None
-                                            else None),
-                            stopper=stopper,
-                            tasks_restored=len(restored),
-                            checkpoint_saves=(ckpt.saves
-                                              if ckpt is not None else 0),
-                            fault_events=(len(injector.fired)
-                                          if injector is not None else 0))
-
-    # -- virtual-time scale-out over a cost model ----------------------------
-    def run_scaleout(self, sample_sizes: Sequence[int], *,
-                     per_sample_exec: Optional[float] = None,
-                     exec_model: Optional[Callable[[sch.Task], float]] = None,
-                     fetch_model: Optional[Callable[[sch.Task], float]] = None,
-                     ) -> JobReport:
-        """Run the scheduling/distribution layers in virtual time over a
-        calibrated cost model (datasets too large to materialize: Fig
-        10-13 sweeps).  No statistics are computed (``result=None``)."""
-        assert (per_sample_exec is None) != (exec_model is None), \
-            "pass exactly one of per_sample_exec / exec_model"
-        spec = self.spec
-        plat = self._platform_config()
-        decision = None
-        if (spec.slo_seconds is not None and per_sample_exec is not None
-                and not spec.sim_workers and len(sample_sizes)):
-            # SLO-aware sizing from the calibrated cost model (Fig 12/13)
-            mean_bytes = float(np.mean(np.asarray(sample_sizes)))
-            decision = slo_mod.choose_workers(
-                max(spec.n_workers, 1),
-                bytes_per_second_per_worker=(mean_bytes
-                                             / float(per_sample_exec)),
-                startup_seconds=plat.startup_time * spec.startup_scale,
-                slo_seconds=spec.slo_seconds)
-        if exec_model is None:
-            rate = float(per_sample_exec)
-            exec_model = lambda t: rate * len(t.sample_ids)   # noqa: E731
-        n_eff = decision.cores if decision is not None \
-            else self._n_exec_workers()
-        t0 = time.perf_counter()
-        tasks = make_tasks(list(sample_sizes), plat.task_sizing,
-                           spec.knee_bytes, n_eff)
-        phases = {"plan": 0.0, "distribute": time.perf_counter() - t0,
-                  "compile": 0.0}
-        workers = (list(spec.sim_workers) if spec.sim_workers else n_eff)
-        backend = SimulatedBackend(workers, exec_model=exec_model,
-                                   fetch_model=fetch_model,
-                                   startup_scale=spec.startup_scale)
-        t0 = time.perf_counter()
-        outcome = backend.run(tasks, compute=None, fetch=None, plat=plat,
-                              cfg=self._scheduler_cfg(plat),
-                              emit=lambda tid, v: None)
+    if fault_injector is not None:
+        if datastore is not None:
+            fault_injector.attach_store(datastore)
+        emit = fault_injector.wrap_emit(emit)
+    # execute-window anchor for the critical-path analyzer: 0.0 on the
+    # virtual bus — the sim clock opens at startup_time, so the window
+    # equals the virtual makespan
+    t_execute = bus.now()
+    backend = SimulatedBackend(
+        list(spec.sim_workers) if spec.sim_workers else n_eff,
+        compute_values=spec.compute_values,
+        startup_scale=spec.startup_scale)
+    t0 = time.perf_counter()
+    try:
+        outcome = backend.run(
+            run_tasks, compute=compute_task, fetch=fetch, plat=plat,
+            cfg=scheduler_config(spec, plat), emit=emit,
+            shape_key=plan.task_shape, locality_score=locality_score,
+            stopper=stopper, telemetry=bus)
         phases["execute"] = time.perf_counter() - t0
-        phases["reduce"] = 0.0
-        return self._report(plat, outcome, tasks, float(sum(sample_sizes)),
-                            spec.knee_bytes, None, "cost-model", phases,
-                            None, None, backend_name="simulated",
-                            scale_decision=decision, n_workers_used=n_eff)
+        if ckpt is not None:
+            # surface any parked async-save error: a job that "ran" but
+            # silently failed to persist its restore point must not
+            # report success (§12 durability contract)
+            ckpt.finish()
 
-    # -- monitor surface (DESIGN.md §15) -------------------------------------
-    def monitor_snapshot(self) -> Dict[str, Any]:
-        """SLIs, alerts, per-job critical paths, and ranked findings —
-        requires ``monitor=MonitorOptions(enabled=True)`` on the spec."""
-        if self.monitor is None:
-            raise RuntimeError(
-                "monitor disabled; construct the Platform with "
-                "PlatformSpec(monitor=MonitorOptions(enabled=True))")
-        return self.monitor.snapshot()
+        # phase 5 — drain the reduce tree, finalize the statistic.  An
+        # early-stopped job finalizes over its executed subset in the
+        # same fixed tree order (deterministic for the set).
+        t0 = time.perf_counter()
+        result, reduce_info = None, None
+        if tree is not None:
+            if stopper is not None and stopper.stopped:
+                executed = ({r.task_id for r in outcome.results}
+                            | set(restored))
+                root = StreamingReduceTree.combine_subset(
+                    len(tasks), {tid: sim_partials[tid]
+                                 for tid in sorted(executed)})
+                tree.close()           # full-leaf stream, unused now
+            else:
+                root = tree.result(timeout=600.0)
+            result = finalize_stats(root, statistic)
+            reduce_info = tree.stats()
+        phases["reduce"] = time.perf_counter() - t0
+    except BaseException:
+        if tree is not None:
+            tree.close()               # unblock the combiner thread
+        raise
+    finally:
+        if datastore is not None:
+            datastore.telemetry = None
 
-    def write_monitor_report(self, path: str,
-                             title: str = "platform monitor") -> None:
-        """Self-contained HTML: alert timeline + per-job critical-path
-        waterfall (requires the monitor to be enabled)."""
-        if self.monitor is None:
-            raise RuntimeError(
-                "monitor disabled; construct the Platform with "
-                "PlatformSpec(monitor=MonitorOptions(enabled=True))")
-        _write_monitor_report(self.monitor, path, title)
+    if datastore is not None:
+        for r in outcome.results:
+            datastore.report_exec_time(r.exec_time)
+    if stopper is not None:
+        ci = stopper.snapshot()
+        if ci is not None:
+            bus.emit("ci_snapshot", **ci.as_dict())
+    bus.emit("job_done", makespan=outcome.makespan,
+             tasks_executed=len({r.task_id for r in outcome.results}),
+             t_execute=t_execute,
+             startup_seconds=plat.startup_time * spec.startup_scale,
+             reduce_seconds=phases["reduce"])
+    return report_fields(
+        spec, plat, outcome, tasks, plan.total_bytes, plan.knee_bytes,
+        plan.knee_res, engine, phases, result, reduce_info,
+        datastore=datastore, dispatch=dispatch, scale_decision=decision,
+        n_workers_used=n_eff, stopper=stopper,
+        tasks_restored=len(restored),
+        checkpoint_saves=ckpt.saves if ckpt is not None else 0,
+        fault_events=(len(fault_injector.fired)
+                      if fault_injector is not None else 0))
 
-    # -- report assembly -----------------------------------------------------
-    def _report(self, plat: PlatformConfig, outcome: BackendOutcome,
-                tasks: List[sch.Task], total_bytes: float,
-                knee_bytes: Optional[float],
-                knee_res: Optional[kp.KneepointResult], engine: str,
-                phases: Dict[str, float], result, reduce_info, *,
-                backend_name: Optional[str] = None,
-                dispatch: Optional[pc.DispatchStats] = None,
-                scale_decision: Optional[slo_mod.ScaleDecision] = None,
-                n_workers_used: Optional[int] = None,
-                prefetch_stats: Optional[Dict[str, float]] = None,
-                stopper=None,
-                tasks_restored: int = 0,
-                checkpoint_saves: int = 0,
-                fault_events: int = 0,
-                ) -> JobReport:
-        backend_name = backend_name or self.spec.backend
-        dispatch = dispatch or pc.DispatchStats()
-        execs = sorted(r.exec_time for r in outcome.results)
-        median = execs[len(execs) // 2] if execs else 0.0
-        stragglers = sum(1 for e in execs if median and e > 2.0 * median)
-        executed = len({r.task_id for r in outcome.results})
-        snap = stopper.snapshot() if stopper is not None else None
-        return JobReport(
-            platform=plat.name,
-            n_tasks=len(tasks),
-            task_size_bytes=(knee_bytes if knee_bytes is not None
-                             else total_bytes / max(len(tasks), 1)),
-            makespan=outcome.makespan,
-            throughput_bps=total_bytes / max(outcome.makespan, 1e-12),
-            startup_time=plat.startup_time * (
-                self.spec.startup_scale
-                if backend_name == "simulated" else 1.0),
-            result=result,
-            kneepoint=knee_res,
-            backend=backend_name,
-            engine=engine,
-            phases=phases,
-            queue_depths=outcome.queue_depths,
-            miss_curve=knee_res.curve if knee_res is not None else (),
-            max_task_bytes=max((t.size_bytes for t in tasks), default=0.0),
-            stragglers=stragglers,
-            speculative_launches=outcome.speculative_launches,
-            restarts=outcome.restarts,
-            calibration_seconds=outcome.calibration_seconds,
-            datastore_stats=(self.datastore.stats()
-                             if self.datastore is not None else None),
-            reduce_info=reduce_info,
-            device_dispatches=dispatch.device_dispatches,
-            bytes_uploaded=dispatch.bytes_uploaded,
-            wave_sizes=list(dispatch.wave_sizes),
-            speculation_wins=outcome.speculation_wins,
-            scale_decision=(f"{scale_decision.cores} cores: "
-                            f"{scale_decision.reason}"
-                            if scale_decision is not None else None),
-            n_workers_used=(n_workers_used if n_workers_used is not None
-                            else self._n_exec_workers()),
-            prefetch_stats=prefetch_stats,
-            cache_stats=(self.datastore.cache.stats()
-                         if self.datastore is not None
-                         and self.datastore.cache is not None else None),
-            tasks_executed=executed + tasks_restored,
-            tasks_cancelled=max(len(tasks) - executed - tasks_restored, 0),
-            stop_reason=(stopper.stop_reason if stopper is not None
-                         else None),
-            final_ci=(snap.as_dict() if snap is not None else None),
-            tasks_restored=tasks_restored,
-            checkpoint_saves=checkpoint_saves,
-            fault_events=fault_events)
+
+def report_fields(spec: PlatformSpec, plat: PlatformConfig,
+                  outcome: BackendOutcome, tasks: List[sch.Task],
+                  total_bytes: float, knee_bytes: Optional[float],
+                  knee_res: Optional[kp.KneepointResult], engine: str,
+                  phases: Dict[str, float], result, reduce_info, *,
+                  datastore=None,
+                  dispatch: Optional[pc.DispatchStats] = None,
+                  scale_decision: Optional[slo_mod.ScaleDecision] = None,
+                  n_workers_used: int = 0, stopper=None,
+                  tasks_restored: int = 0, checkpoint_saves: int = 0,
+                  fault_events: int = 0) -> Dict[str, Any]:
+    """The :class:`~repro.platform.facade.JobReport` fields of one
+    virtual-time run."""
+    dispatch = dispatch or pc.DispatchStats()
+    execs = sorted(r.exec_time for r in outcome.results)
+    median = execs[len(execs) // 2] if execs else 0.0
+    executed = len({r.task_id for r in outcome.results})
+    snap = stopper.snapshot() if stopper is not None else None
+    cache = datastore.cache if datastore is not None else None
+    return dict(
+        platform=plat.name,
+        n_tasks=len(tasks),
+        task_size_bytes=(knee_bytes if knee_bytes is not None
+                         else total_bytes / max(len(tasks), 1)),
+        makespan=outcome.makespan,
+        throughput_bps=total_bytes / max(outcome.makespan, 1e-12),
+        startup_time=plat.startup_time * spec.startup_scale,
+        result=result,
+        kneepoint=knee_res,
+        backend="simulated",
+        engine=engine,
+        phases=phases,
+        queue_depths=outcome.queue_depths,
+        miss_curve=knee_res.curve if knee_res is not None else (),
+        max_task_bytes=max((t.size_bytes for t in tasks), default=0.0),
+        stragglers=sum(1 for e in execs if median and e > 2.0 * median),
+        speculative_launches=outcome.speculative_launches,
+        restarts=outcome.restarts,
+        calibration_seconds=outcome.calibration_seconds,
+        datastore_stats=(datastore.stats()
+                         if datastore is not None else None),
+        reduce_info=reduce_info,
+        device_dispatches=dispatch.device_dispatches,
+        bytes_uploaded=dispatch.bytes_uploaded,
+        wave_sizes=list(dispatch.wave_sizes),
+        speculation_wins=outcome.speculation_wins,
+        scale_decision=(f"{scale_decision.cores} cores: "
+                        f"{scale_decision.reason}"
+                        if scale_decision is not None else None),
+        n_workers_used=n_workers_used,
+        cache_stats=cache.stats() if cache is not None else None,
+        tasks_executed=executed + tasks_restored,
+        tasks_cancelled=max(len(tasks) - executed - tasks_restored, 0),
+        stop_reason=stopper.stop_reason if stopper is not None else None,
+        final_ci=snap.as_dict() if snap is not None else None,
+        tasks_restored=tasks_restored,
+        checkpoint_saves=checkpoint_saves,
+        fault_events=fault_events)

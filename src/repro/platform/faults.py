@@ -119,7 +119,7 @@ class FaultInjector:
     * :meth:`attach_store` — give node events their target store;
     * :meth:`on_progress` — observe task completions (drivers wrap their
       ``emit`` with :meth:`wrap_emit`); due node events fire inline;
-    * :meth:`worker_tick` — called by runner/pool workers right after a
+    * :meth:`worker_tick` — called by pool workers right after a
       claim; raises :class:`~repro.core.recovery.WorkerCrash` when a
       planned crash is due (once per event — the respawned worker reuses
       the id and must not crash again);

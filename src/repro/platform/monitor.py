@@ -413,10 +413,16 @@ class PlatformMonitor:
                 self._rejected.append(dict(f, ts=ts))
             elif kind == "job_queued":
                 self._queued.append(dict(f, ts=ts))
+            elif kind == "job_admitted":
+                # a served job's execute window opens at its admission
+                # (a virtual run's job_done names its own t_execute)
+                self._job_meta.setdefault(f.get("job_id"), {})[
+                    "t_execute"] = ts
             elif kind in ("job_done", "job_failed"):
                 job = f.get("job_id")
                 meta = self._job_meta.setdefault(job, {})
                 meta["status"] = kind
+                meta["done_ts"] = ts
                 for key in ("makespan", "t_execute", "startup_seconds",
                             "reduce_seconds", "tasks_executed"):
                     if f.get(key) is not None:
@@ -470,7 +476,9 @@ class PlatformMonitor:
         settle charges ``queue``, and the pre-first-claim head splits
         into ``startup`` (up to the backend's startup seconds) then
         ``queue``.  The phases partition ``[t_execute, last_settle]``,
-        so their sum (+ the reduce drain) reconstructs the makespan."""
+        so their sum (+ the reduce drain) reconstructs the makespan.  A
+        served job's window opens at its ``job_admitted`` event, and its
+        reduce drain runs from its last settle to its ``job_done``."""
         with self._lock:
             jobs = ([job_id] if job_id is not ... else
                     sorted(self._settles, key=lambda j: (j is None, j)))
@@ -494,8 +502,12 @@ class PlatformMonitor:
                         if k[0] == job]
             t_exec = min(claim_ts) if claim_ts else settles[0][0]
         startup_budget = float(meta.get("startup_seconds") or 0.0)
+        reduce = meta.get("reduce_seconds")
+        if reduce is None and "done_ts" in meta:
+            # a served job's finalize: its last settle to its job_done
+            reduce = max(meta["done_ts"] - settles[-1][0], 0.0)
         phases = {"startup": 0.0, "queue": 0.0, "fetch": 0.0, "exec": 0.0,
-                  "reduce": float(meta.get("reduce_seconds") or 0.0)}
+                  "reduce": float(reduce or 0.0)}
         path: List[Dict[str, Any]] = []
         visited = set()
         cur = settles[-1]

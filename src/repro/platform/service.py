@@ -2,11 +2,12 @@
 (DESIGN.md §8).
 
 The thesis motivates subsampling as *interactive* analytics — "processed
-in real time, in interactive fashion" — but :meth:`Platform.run` is
-one-shot: every query re-measures the kneepoint, re-partitions, re-packs
-and re-uploads the block arena, and spins up (then tears down) a worker
-pool.  The wave engine amortized the platform tax *within* a job; this
-module amortizes it *between* jobs:
+in real time, in interactive fashion".  A one-shot job re-measures the
+kneepoint, re-partitions, re-packs and re-uploads the block arena, and
+spins up (then tears down) a worker pool.  The wave engine amortized the
+platform tax *within* a job; this module amortizes it *between* jobs, and
+is the one real-time execution path (a one-shot job runs as the only job
+of a service):
 
 * **Dataset registry** — :meth:`PlatformService.register_dataset` places
   a dataset on the data plane once and returns a :class:`DatasetHandle`.
@@ -32,12 +33,12 @@ module amortizes it *between* jobs:
   throughput is rejected up front instead of burning capacity it cannot
   use.
 
-For a fixed seed, ``submit(...).result()`` is bit-identical to a
-standalone ``Platform.run(...)`` with the same spec — the service reuses
-the exact plan/compute/reduce substrate, only the scheduling around it
-changes.  ``backend="simulated"`` specs run each submitted job inline
-through the one-shot driver in virtual time (a resident pool has no
-meaning there), still reusing the handle's cached kneepoint.
+For a fixed seed, ``submit(...).result()`` is bit-identical to the
+virtual-time run of the same spec — per-task seeds and the fixed
+reduce-tree order make the answer independent of the schedule.
+``backend="simulated"`` specs run each submitted job inline through
+:func:`~repro.platform.driver.run_virtual` (a resident pool has no
+meaning in virtual time), still reusing the handle's cached kneepoint.
 """
 
 from __future__ import annotations
@@ -63,17 +64,19 @@ from repro.platform.driver import (
     ApproxOptions,
     JobCheckpointer,
     JobPlan,
-    Platform,
     PlatformSpec,
     WaveContext,
     balanced_enabled,
     build_prefetcher,
     build_wave_context,
+    measure_kneepoint,
     plan_job,
     prefetch_enabled,
+    resolve_job_engine,
     resolve_platform_config,
     resolve_speculation,
     resolve_wave_mesh,
+    run_virtual,
     slo_worker_decision,
     wave_enabled,
 )
@@ -187,7 +190,6 @@ class DatasetHandle:
         key = workload_key(workload)
         with self._lock:
             if key not in self._knee:
-                from repro.platform.driver import measure_kneepoint
                 self._knee[key] = measure_kneepoint(
                     self.samples, self.months, workload,
                     sizes=kneepoint_sizes, engine=engine)
@@ -210,9 +212,11 @@ class DatasetHandle:
         # set-up, once per class: the knee, plan_job, the arena's pack
         # and upload, and the warm-up compile
         with tel.span("platform.query_class"):
+            t0 = time.perf_counter()
             knee_res, knee = self.cached_knee(
                 workload, engine=engine, sizing=sizing,
                 kneepoint_sizes=spec.kneepoint_sizes)
+            knee_seconds = time.perf_counter() - t0
             with self._lock:
                 qc = self._classes.get(key)
                 if qc is not None:                     # raced: peer built it
@@ -222,6 +226,7 @@ class DatasetHandle:
                                 knee_bytes=knee,
                                 kneepoint_sizes=spec.kneepoint_sizes)
                 plan.knee_res = plan.knee_res or knee_res
+                plan.plan_seconds += knee_seconds
                 qc = QueryClass(uid=next(_CLASS_UID), plan=plan,
                                 workload=workload, engine=engine)
                 if wave_on:
@@ -283,6 +288,7 @@ class JobTicket:
         self.reason: Optional[str] = None       # rejection/failure detail
         self.error: Optional[BaseException] = None
         self.submitted_at = time.monotonic()
+        self.plan: Optional[JobPlan] = None      # the query class's plan
         self.admitted_at: Optional[float] = None
         self.started_at: Optional[float] = None
         self.finished_at: Optional[float] = None
@@ -299,6 +305,7 @@ class JobTicket:
         self.span_seconds: Dict[str, float] = {}
         self._span_lock = threading.Lock()
         self.tree: Optional[StreamingReduceTree] = None
+        self.reduce_info: Optional[Dict[str, float]] = None   # tree stats
         self.cancel_requested = False      # set before pool.cancel fires
         # error-bounded approximate execution (DESIGN.md §10)
         self.epsilon: Optional[float] = None
@@ -427,7 +434,8 @@ class PlatformService:
 
     def __init__(self, spec: PlatformSpec = PlatformSpec(), *,
                  admission: AdmissionPolicy = AdmissionPolicy(),
-                 datastore=None, fault_injector=None):
+                 datastore=None, fault_injector=None,
+                 _bus: Optional[tel.TelemetryBus] = None):
         if spec.backend not in ("threaded", "simulated"):
             raise ValueError(
                 f"service backend must be threaded|simulated, "
@@ -453,11 +461,12 @@ class PlatformService:
         self.jobs_completed = 0
         self.jobs_rejected = 0
         self.scale_decision: Optional[str] = None   # slo.choose_workers hint
-        # unified telemetry (DESIGN.md §13): one bus per service session;
-        # the dispatch counters above are derived from its events through
-        # the bus's single aggregation path
-        self.telemetry = tel.TelemetryBus(
-            tel.resolve_telemetry_config(spec.telemetry))
+        # unified telemetry (DESIGN.md §13): one bus per service session
+        # (a one-shot job's driver passes its own, which outlives the
+        # service); the dispatch counters above are derived from its
+        # events through the bus's single aggregation path
+        self.telemetry = (_bus if _bus is not None else tel.TelemetryBus(
+            tel.resolve_telemetry_config(spec.telemetry)))
         self.telemetry.bind_dispatch(self.dispatch)
         self.sampler = tel.TelemetrySampler(self.telemetry)
         # SLO monitor (DESIGN.md §15): tap-driven, built only when
@@ -626,7 +635,7 @@ class PlatformService:
         # admission slot was reserved) would leak the slot and hang the
         # ticket — and kill a pool worker on the queued-drain path
         est_mod.validate_error_target(eff_epsilon, eff_conf)
-        engine = pc.resolve_engine(workload.statistic, self.spec.engine)
+        engine = resolve_job_engine(workload, self.spec.engine)
 
         if self.spec.backend == "simulated":
             return self._submit_simulated(handle, workload, seed,
@@ -652,17 +661,12 @@ class PlatformService:
             # resume (DESIGN.md §12): restore committed leaf partials up
             # front — a stale checkpoint must fail the submit, not a pool
             # worker — and hand only the missing tasks to the pool
-            restored: Dict[int, Dict[str, Any]] = {}
-            if resume_from is not None:
-                restored, ckpt_n = JobCheckpointer.load(resume_from)
-                if ckpt_n is not None and ckpt_n != len(qc.plan.tasks):
-                    raise ValueError(
-                        f"checkpoint at {resume_from!r} holds partials for "
-                        f"{ckpt_n} tasks but this query class has "
-                        f"{len(qc.plan.tasks)} — resume needs the same "
-                        "dataset, workload, sizing and knee")
+            restored = (JobCheckpointer.load(resume_from,
+                                             len(qc.plan.tasks))
+                        if resume_from is not None else {})
             ticket = JobTicket(job_id, handle, workload,
                                len(qc.plan.tasks), workload.statistic, seed)
+            ticket.plan = qc.plan
             ticket.epsilon, ticket.confidence = eff_epsilon, eff_conf
             ticket.min_tasks = eff_min
             ticket.arena_bytes = qc.arena_bytes
@@ -944,6 +948,10 @@ class PlatformService:
             out = []
             for pj, task in items:
                 block, mo = qc.block(task)
+                if qc.engine == "custom":
+                    out.append(qc.workload.map_fn(task, block, mo,
+                                                  pj.seed + task.task_id))
+                    continue
                 if qc.engine in ("jnp", "pallas"):
                     nbytes = float(block.nbytes) + (
                         float(mo.nbytes) if qc.engine == "jnp" else 0.0)
@@ -1051,6 +1059,8 @@ class PlatformService:
             # dict — partial()/final_ci never read them after DONE, and
             # a caller-held ticket would otherwise pin ~n_tasks×D floats
             # for its lifetime
+            if ticket.tree is not None:
+                ticket.reduce_info = ticket.tree.stats()
             ticket.tree = None
             ticket.estimator = None
             ticket.stopper = None
@@ -1119,11 +1129,11 @@ class PlatformService:
                           min_tasks: int = 8,
                           checkpoint_dir: Optional[str] = None,
                           resume_from: Optional[str] = None) -> JobTicket:
-        """Virtual-time spec: run the job inline through the one-shot
-        driver (a resident pool has no meaning in virtual time), reusing
-        the handle's cached kneepoint so repeat queries still skip the
-        offline phase."""
-        engine = pc.resolve_engine(workload.statistic, self.spec.engine)
+        """Virtual-time spec: run the job inline through
+        :func:`~repro.platform.driver.run_virtual` (a resident pool has
+        no meaning in virtual time), reusing the handle's cached
+        kneepoint so repeat queries still skip the offline phase."""
+        engine = resolve_job_engine(workload, self.spec.engine)
         _res, knee = handle.cached_knee(
             workload, engine=engine, sizing=self.plat.task_sizing,
             kneepoint_sizes=self.spec.kneepoint_sizes)
@@ -1159,21 +1169,21 @@ class PlatformService:
                 ticket.status = RUNNING
         ticket.admitted_at = ticket.started_at = time.monotonic()
         try:
-            report = Platform(spec).run(handle.samples, handle.months,
-                                        workload, resume_from=resume_from)
+            report = run_virtual(spec, handle.samples, handle.months,
+                                 workload, resume_from=resume_from)
         except BaseException as e:         # noqa: BLE001
             ticket.error = e
             self._finish(ticket, FAILED, reason=repr(e))
             return ticket
-        ticket.n_tasks = report.n_tasks
-        ticket.tasks_restored = report.tasks_restored
-        ticket._result = report.result
-        ticket.device_dispatches = report.device_dispatches
-        ticket.bytes_uploaded = report.bytes_uploaded
-        ticket.tasks_executed = report.tasks_executed
-        ticket.tasks_cancelled = report.tasks_cancelled
-        ticket.stop_reason = report.stop_reason
-        ticket.final_ci = report.final_ci
+        ticket.n_tasks = report["n_tasks"]
+        ticket.tasks_restored = report["tasks_restored"]
+        ticket._result = report["result"]
+        ticket.device_dispatches = report["device_dispatches"]
+        ticket.bytes_uploaded = report["bytes_uploaded"]
+        ticket.tasks_executed = report["tasks_executed"]
+        ticket.tasks_cancelled = report["tasks_cancelled"]
+        ticket.stop_reason = report["stop_reason"]
+        ticket.final_ci = report["final_ci"]
         self._finish(ticket, DONE)
         return ticket
 

@@ -31,6 +31,7 @@ from repro.core.scheduler import (
 )
 from repro.platform import Platform, PlatformService, PlatformSpec
 from repro.platform.compute import MomentsSpec
+from repro.platform.faults import FaultEvent, FaultInjector, FaultPlan
 
 WL = MomentsSpec(draws=4, draw_size=16)
 
@@ -197,19 +198,30 @@ def test_two_phase_ranking_moves_cheap_bucket_first_keeping_fifo():
     assert order == [1, 3, 5, 7, 0, 2, 4, 6]   # B first, FIFO inside
 
 
-def test_two_phase_rerank_on_state_change():
-    tasks = _bucketed_tasks()
-    score = {"A": 1.0, "B": 5.0}
-    sched = TwoPhaseScheduler(
-        1, tasks, SchedulerConfig(),
-        locality_score=lambda t: score[t.payload],
-        bucket_key=lambda t: t.payload)
-    assert sched.backlog[0].payload == "A"
-    score["A"], score["B"] = 5.0, 1.0          # node serving A degraded
-    sched.request_rerank()
-    t = sched.on_worker_idle(0)                # applies the pending rerank
-    assert t.payload == "B"
-    assert sched.reranks == 2
+def test_platform_run_reranks_when_a_node_goes_down_mid_job(monkeypatch):
+    """A data node lost mid-job re-ranks the ready queue through the
+    pool's scheduler, and the answer is unchanged."""
+    samples, months = _dataset(n=48)
+    clean = Platform(_spec()).run(samples, months, WL)
+    requested = []
+    real = MultiJobScheduler.request_rerank
+
+    def spy(self):
+        requested.append(self)
+        real(self)
+
+    monkeypatch.setattr(MultiJobScheduler, "request_rerank", spy)
+    store = _store()
+    inj = FaultInjector(FaultPlan(events=[
+        FaultEvent(kind="node_kill", target=0, at_completions=2)]))
+    rep = Platform(_spec(balanced="on"), datastore=store,
+                   fault_injector=inj).run(samples, months, WL)
+    assert store.node_states()[0] == DOWN
+    assert requested
+    assert requested[0].reranks >= 2        # at admission, then again
+    for key in clean.result:
+        np.testing.assert_array_equal(np.asarray(clean.result[key]),
+                                      np.asarray(rep.result[key]))
 
 
 def test_prefetch_on_requires_datastore_and_threaded_backend():

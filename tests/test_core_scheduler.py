@@ -12,7 +12,6 @@ from repro.core.scheduler import (
     SimWorker,
     Task,
     TaskResult,
-    ThreadedRunner,
     TwoPhaseScheduler,
     simulate_job,
 )
@@ -117,13 +116,6 @@ def test_prefetch_overlap_hides_fetch_time():
                             uniform_params(exec_s=0.01, fetch_s=0.0))
     # fetch ≤ exec ⇒ almost fully hidden
     assert with_fetch.makespan < 1.15 * no_fetch.makespan
-
-
-def test_threaded_runner_executes_everything():
-    seen = []
-    runner = ThreadedRunner(3, lambda t: seen.append(t.task_id) or t.task_id)
-    results = runner.run_job(mk_tasks(50))
-    assert sorted(r.value for r in results) == list(range(50))
 
 
 @given(st.integers(min_value=1, max_value=16),

@@ -1,6 +1,7 @@
 """Fault-injection framework + recovery-layer tests (DESIGN.md §12):
-seeded FaultPlan determinism, lease-based reclamation in both
-schedulers, worker-crash respawn bit-identity, probe-driven datastore
+seeded FaultPlan determinism, lease-based reclamation in the pool's
+scheduler, worker-crash respawn bit-identity and checkpoint resume
+through ``Platform.run`` and the service, probe-driven datastore
 auto-revival, the unified RetryPolicy, and checkpoint error surfacing.
 """
 
@@ -20,15 +21,14 @@ from repro.core.datastore import (
     ReplicatedDataStore,
     ReplicationPolicy,
 )
+from repro.core import subsample as ss
 from repro.core.scheduler import (
     MultiJobConfig,
     MultiJobScheduler,
-    SchedulerConfig,
     Task,
-    TaskResult,
-    ThreadedRunner,
-    TwoPhaseScheduler,
 )
+from repro.data.synthetic import NetflixSpec, netflix_dataset
+from repro.platform import FaultOptions, Platform, PlatformSpec
 from repro.platform.faults import (
     FaultEvent,
     FaultInjector,
@@ -117,55 +117,6 @@ def test_node_latency_spike_and_revive_restore_latency_model():
 
 
 # ---------------------------------------------------------------------------
-# TwoPhaseScheduler: crash + lease reclamation
-# ---------------------------------------------------------------------------
-
-
-def test_two_phase_worker_crash_requeues_claims():
-    sched = TwoPhaseScheduler(2, mk_tasks(6))
-    sched.initial_assignments()
-    t = sched.on_worker_idle(0)
-    sched.on_task_start(0, t)
-    before = sched.queue_depth()
-    lost = sched.on_worker_crash(0)
-    assert [x.task_id for x in lost] == [t.task_id]
-    assert sched.worker_crashes == 1
-    assert sched.reclaimed_tasks == 1
-    assert sched.queue_depth() >= before  # claim is back in the queues
-    # the requeued copy is claimable again and completes the job path
-    t2 = sched.on_worker_idle(1)
-    assert t2 is not None
-
-
-def test_two_phase_lease_expiry_requeues_and_dedups():
-    cfg = SchedulerConfig(lease_seconds=0.01)
-    sched = TwoPhaseScheduler(2, mk_tasks(4), cfg)
-    sched.initial_assignments()
-    t = sched.on_worker_idle(0)
-    sched.on_task_start(0, t, now=0.0)
-    expired = sched.reclaim_expired(now=0.005)
-    assert expired == []                # lease still live
-    expired = sched.reclaim_expired(now=0.02)
-    assert [x.task_id for x in expired] == [t.task_id]
-    # the original still settles: first completion wins, the duplicate
-    # never double-counts
-    sched.on_task_complete(TaskResult(t.task_id, 0, 0.0, 0.0, 0.01))
-    assert t.task_id in sched._completed
-    # reclaim is idempotent — the settled task's lease is gone
-    assert sched.reclaim_expired(now=1.0) == []
-
-
-def test_two_phase_crash_without_respawn_shrinks_pool():
-    sched = TwoPhaseScheduler(2, mk_tasks(4))
-    sched.initial_assignments()
-    t = sched.on_worker_idle(0)
-    sched.on_task_start(0, t)
-    sched.on_worker_crash(0, respawn=False)
-    # the dead worker never gets new work; the survivor still drains
-    assert sched.on_worker_idle(1) is not None
-
-
-# ---------------------------------------------------------------------------
 # MultiJobScheduler: dead workers, leases, lost tasks
 # ---------------------------------------------------------------------------
 
@@ -215,42 +166,111 @@ def test_multi_job_on_task_lost_shrinks_job():
 
 
 # ---------------------------------------------------------------------------
-# ThreadedRunner: crash respawn is bit-identical
+# Platform.run: crash respawn, lease reclamation and resume on the pool
 # ---------------------------------------------------------------------------
 
 
-def _task_value(t):
-    time.sleep(0.003)       # keep every worker claiming for a while
-    return t.task_id * 10 + 1
+@pytest.fixture(scope="module")
+def netflix12():
+    return netflix_dataset(NetflixSpec(n_movies=12, mean_ratings=512))
 
 
-def _run_threaded(crash_hook=None, max_respawns=2, n=12):
-    runner = ThreadedRunner(
-        3, run_task=_task_value,
-        cfg=SchedulerConfig(lease_seconds=0.5),
-        crash_hook=crash_hook, max_respawns=max_respawns)
-    results = runner.run_job(mk_tasks(n))
-    return {r.task_id: r.value for r in results}, runner
+def _platform_spec(**kw):
+    base = dict(platform="BTS", n_workers=3, backend="threaded",
+                knee_bytes=512 * 4, seed=5)
+    base.update(kw)
+    return PlatformSpec(**base)
 
 
-def test_threaded_runner_crash_respawn_bit_identical():
-    clean, _ = _run_threaded()
+def _same(a, b):
+    assert set(a) == set(b)
+    for k in a:
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+
+
+@pytest.mark.parametrize("targets", [(1,), (0, 2)],
+                         ids=["one_crash", "two_crashes"])
+def test_platform_run_crash_respawn_bit_identical(netflix12, targets):
+    samples, months = netflix12
+    clean = Platform(_platform_spec()).run(samples, months, ss.NETFLIX_HIGH)
+    inj = FaultInjector(FaultPlan(events=tuple(
+        FaultEvent(kind="worker_crash", target=w, at_claims=1)
+        for w in targets)))
+    rep = Platform(_platform_spec(), fault_injector=inj).run(
+        samples, months, ss.NETFLIX_HIGH)
+    assert [e.kind for e in inj.fired] == ["worker_crash"] * len(targets)
+    assert rep.restarts == len(targets)
+    assert rep.fault_events == len(targets)
+    _same(clean.result, rep.result)
+
+
+def test_platform_run_crash_without_respawn_shrinks_pool(netflix12):
+    samples, months = netflix12
+    clean = Platform(_platform_spec()).run(samples, months, ss.NETFLIX_HIGH)
     inj = FaultInjector(FaultPlan(events=[
-        FaultEvent(kind="worker_crash", target=1, at_claims=1)]))
-    faulty, runner = _run_threaded(crash_hook=inj.worker_tick)
+        FaultEvent(kind="worker_crash", target=0, at_claims=1)]))
+    rep = Platform(_platform_spec(faults=FaultOptions(max_respawns=0)),
+                   fault_injector=inj).run(samples, months, ss.NETFLIX_HIGH)
     assert [e.kind for e in inj.fired] == ["worker_crash"]
-    assert runner.worker_respawns == 1
-    assert faulty == clean
+    assert rep.restarts == 0            # the survivors finish the job
+    assert rep.tasks_executed == rep.n_tasks
+    _same(clean.result, rep.result)
 
 
-def test_threaded_runner_survives_multiple_crashes():
-    inj = FaultInjector(FaultPlan(events=[
-        FaultEvent(kind="worker_crash", target=0, at_claims=1),
-        FaultEvent(kind="worker_crash", target=2, at_claims=1)]))
-    clean, _ = _run_threaded()
-    faulty, runner = _run_threaded(crash_hook=inj.worker_tick)
-    assert runner.worker_respawns == 2
-    assert faulty == clean
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_platform_run_worker_death_between_claim_and_settlement(netflix12):
+    """A worker that dies holding its claim, without reporting, under a
+    lease: the job still completes, bit-identical to a clean run."""
+    samples, months = netflix12
+    spec = _platform_spec(faults=FaultOptions(lease_seconds=0.5))
+    clean = Platform(spec).run(samples, months, ss.NETFLIX_HIGH)
+    died = threading.Event()
+
+    class DeathInjector:
+        """Only the crash hook matters: worker 0 dies at its first claim
+        with an unexpected error, not a reported WorkerCrash."""
+
+        fired: list = []
+
+        def worker_tick(self, wid):
+            if wid == 0 and not died.is_set():
+                died.set()
+                raise RuntimeError("thread dies silently")
+
+        def wrap_emit(self, emit):
+            return emit
+
+        def attach_store(self, store):
+            pass
+
+    rep = Platform(spec, fault_injector=DeathInjector()).run(
+        samples, months, ss.NETFLIX_HIGH)
+    assert died.is_set()
+    assert rep.restarts == 1
+    _same(clean.result, rep.result)
+
+
+def test_platform_run_resumes_after_checkpoint_crash(netflix12, tmp_path):
+    """The benchmarks' resume scenario: a checkpoint-write crash at the
+    second save, then a run with ``resume_from`` restores the committed
+    partials, executes only the missing tasks, and is bit-identical."""
+    samples, months = netflix12
+    clean = Platform(_platform_spec()).run(samples, months, ss.NETFLIX_HIGH)
+    ckdir = str(tmp_path / "ck")
+    crash = FaultInjector(FaultPlan(events=[
+        FaultEvent(kind="checkpoint_crash", at_saves=2)]))
+    with pytest.raises(InjectedCrash):
+        Platform(_platform_spec(faults=FaultOptions(
+            checkpoint_dir=ckdir, checkpoint_every=3)),
+            fault_injector=crash).run(samples, months, ss.NETFLIX_HIGH)
+    p = Platform(_platform_spec())
+    resumed = p.run(samples, months, ss.NETFLIX_HIGH, resume_from=ckdir)
+    assert 0 < resumed.tasks_restored < resumed.n_tasks
+    # the pool settled exactly the tasks the checkpoint did not hold
+    settled = p.telemetry.metrics.snapshot()["counters"]["tasks_settled"]
+    assert settled == resumed.n_tasks - resumed.tasks_restored
+    _same(clean.result, resumed.result)
 
 
 # ---------------------------------------------------------------------------

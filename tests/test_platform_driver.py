@@ -2,6 +2,8 @@
 (bit-identical statistics), report structure, kneepoint working-set
 bounds, the streaming reduce tree, and engine fallbacks."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -216,6 +218,25 @@ def test_custom_map_fn_with_overhead_config():
     assert rep.n_tasks == 10
     assert rep.result["count"] == 10.0
     assert rep.engine == "custom"
+
+
+def test_custom_map_fn_runs_each_task_once_on_the_pool():
+    samples = {i: np.zeros(8, np.float32) for i in range(24)}
+    months = {i: np.zeros(8, np.int32) for i in range(24)}
+    calls = []
+    lock = threading.Lock()
+
+    def map_fn(task, block, mo, seed):
+        with lock:
+            calls.append(task.task_id)
+        return {"count": np.asarray(1.0, np.float32)}
+
+    rep = Platform(PlatformSpec(platform="BTT", n_workers=3,
+                                backend="threaded"),
+                   map_fn=map_fn).run(samples, months, None)
+    assert sorted(calls) == list(range(24))
+    assert rep.tasks_executed == rep.n_tasks == 24
+    assert rep.result["count"] == 24.0
 
 
 # -- eaglet end-to-end through the driver -------------------------------------

@@ -105,6 +105,32 @@ def test_submit_bit_identical_moments_wave_class(netflix):
             err_msg=f"service wave diverged on {key!r}")
 
 
+def test_platform_run_rides_the_same_waves_as_submit_on_mixed_buckets():
+    """``Platform.run`` is a one-job service: on interleaved shape
+    buckets it fills each wave from the whole queue, as a submit does."""
+    rng = np.random.default_rng(3)
+    lengths = [100 if i % 3 == 0 else 40 for i in range(16)]
+    samples = {i: rng.standard_normal(n).astype(np.float32)
+               for i, n in enumerate(lengths)}
+    months = {i: rng.integers(0, 12, n).astype(np.int32)
+              for i, n in enumerate(lengths)}
+    # one task a sample: a 128-wide bucket every third task, 64 between
+    spec = _spec(platform="BTT", knee_bytes=None, engine="jnp",
+                 wave="on", seed=4)
+    rep = Platform(spec).run(samples, months, ss.NETFLIX_LOW)
+    with PlatformService(spec) as svc:
+        handle = svc.register_dataset(samples, months)
+        ticket = svc.submit(handle, ss.NETFLIX_LOW, seed=4)
+        got = ticket.result(timeout=120)
+        waves = svc.stats()["wave_sizes"]
+    assert rep.device_dispatches == ticket.device_dispatches == len(waves)
+    assert sorted(rep.wave_sizes) == sorted(waves)
+    assert sum(waves) == rep.n_tasks == 16
+    for key in rep.result:
+        np.testing.assert_array_equal(np.asarray(rep.result[key]),
+                                      np.asarray(got[key]))
+
+
 # -- registry / arena caching -------------------------------------------------
 
 

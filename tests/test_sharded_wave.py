@@ -31,7 +31,8 @@ from repro.core.subsample import EAGLET, NETFLIX_HIGH  # noqa: E402
 from repro.kernels.subsample_gather import to_lane_layout  # noqa: E402
 from repro.platform import compute as pc  # noqa: E402
 from repro.platform.compute import MomentsSpec  # noqa: E402
-from repro.platform.driver import Platform, PlatformSpec  # noqa: E402
+from repro.platform.driver import PlatformSpec  # noqa: E402
+from repro.platform.facade import Platform  # noqa: E402
 from repro.platform.reduce import StreamingReduceTree, tree_add  # noqa: E402
 from tests._hypothesis_compat import given, settings, st  # noqa: E402
 

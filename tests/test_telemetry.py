@@ -157,9 +157,11 @@ def test_metrics_registry_counters_gauges_histograms():
 @pytest.mark.parametrize("backend", ["threaded", "simulated"])
 def test_disabled_bus_records_nothing_results_identical(backend):
     samples, months = _dataset()
-    p_off = Platform(_spec(backend=backend))
+    # one worker: on real threads the depth at each settle counts the
+    # tasks still ready, which only a fixed schedule repeats exactly
+    p_off = Platform(_spec(backend=backend, n_workers=1))
     r_off = p_off.run(samples, months, WL)
-    p_on = Platform(_spec(backend=backend, telemetry=True))
+    p_on = Platform(_spec(backend=backend, n_workers=1, telemetry=True))
     r_on = p_on.run(samples, months, WL)
 
     assert p_off.telemetry.events() == []

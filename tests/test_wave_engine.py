@@ -328,25 +328,26 @@ def test_pad_to_common_roundtrip(lengths, min_len):
 # -- scheduler wave draining --------------------------------------------------
 
 
-def test_claim_batch_drains_same_key_fifo():
-    tasks = _make_tasks([(0,), (1,), (2,), (3, 4), (5,), (6,)])
-    key_fn = lambda t: len(t.sample_ids)          # noqa: E731
-    sched = sch.TwoPhaseScheduler(1, tasks, sch.SchedulerConfig())
-    first = sched.on_worker_idle(0)
-    batch = sched.claim_batch(0, first, max_n=8, key_fn=key_fn)
-    # drains task 1, 2 then stops at the 2-sample task 3
-    assert [t.task_id for t in batch] == [0, 1, 2]
-    assert [t.task_id for t in sched.backlog] == [3, 4, 5]
+def _one_job(tasks, cap):
+    sched = sch.MultiJobScheduler(1)
+    sched.add_job(0, tasks, fuse_key=lambda t: len(t.sample_ids), cap=cap)
+    return sched
 
 
-def test_claim_batch_respects_max():
-    tasks = _make_tasks([(i,) for i in range(10)])
-    sched = sch.TwoPhaseScheduler(1, tasks, sch.SchedulerConfig())
-    first = sched.on_worker_idle(0)
-    batch = sched.claim_batch(0, first, max_n=4,
-                              key_fn=lambda t: len(t.sample_ids))
-    assert len(batch) == 4
-    assert len(sched.backlog) == 6
+def test_claim_drains_same_key_fifo_from_the_whole_queue():
+    sched = _one_job(_make_tasks([(0,), (1,), (2,), (3, 4), (5,), (6,)]),
+                     cap=8)
+    batch = sched.claim(now=0.0)
+    # every 1-sample task, FIFO, including those behind the 2-sample one
+    assert [t.task_id for _, t in batch] == [0, 1, 2, 4, 5]
+    assert [t.task_id for t in sched.jobs[0].pending] == [3]
+
+
+def test_claim_respects_max():
+    sched = _one_job(_make_tasks([(i,) for i in range(10)]), cap=8)
+    batch = sched.claim(now=0.0, max_n=4)
+    assert [t.task_id for _, t in batch] == [0, 1, 2, 3]
+    assert len(sched.jobs[0].pending) == 6
 
 
 def test_warmup_blocks_not_rebuilt_in_execute_phase(monkeypatch):
