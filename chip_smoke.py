@@ -268,8 +268,9 @@ def identical(a: dict, b: dict) -> bool:
 
 def inspect_wave_program(label: str, qc) -> None:
     """Lower the query class's wave program for its first bucket, print
-    its compiled sizes, and for MOMENTS assert that it holds the Mosaic
-    kernel (``tpu_custom_call``): compiled, not interpreted."""
+    its compiled sizes, and for MOMENTS and ``monthly_mean`` assert that
+    it holds the Mosaic kernel (``tpu_custom_call``): compiled, not
+    interpreted."""
     from repro.platform import compute as pc
 
     ctx, wl = qc.wave_ctx, qc.workload
@@ -283,10 +284,14 @@ def inspect_wave_program(label: str, qc) -> None:
             f"{label}: MOMENTS wave is not a Mosaic kernel")
         kernel = "tpu_custom_call present; "
     else:
-        lowered = pc._wave_fn("jnp").lower(
-            data, months, slots, slots, draws=wl.draws,
-            draw_size=wl.draw_size, grid=wl.grid, statistic=wl.statistic)
+        kind, static = pc._jnp_wave_kind(wl, ctx.arena.width(key))
+        lowered = pc._wave_fn(kind).lower(data, months, slots, slots,
+                                          **static)
         kernel = ""
+        if kind == "grid":
+            assert "tpu_custom_call" in lowered.as_text(), (
+                f"{label}: monthly_mean wave is not a Mosaic kernel")
+            kernel = "tpu_custom_call present; "
     mem = lowered.compile().memory_analysis()
     say(f"wave program {label}: {kernel}compiled arguments "
         f"{mem.argument_size_in_bytes} B, temporaries "

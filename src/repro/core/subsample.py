@@ -8,8 +8,11 @@ into the job result (the ALOD curve / per-month rating means).
 
 The random index gather is the cache-hostile access pattern the whole
 thesis is about — task (block) size controls the working set it rampages
-over.  ``repro.kernels.subsample_gather`` is the TPU Pallas version of the
-gather+statistic hot spot; this module is the pure-jnp engine and oracle.
+over.  Two TPU Pallas kernels compute the hot spot without gathering
+element by element: ``repro.kernels.subsample_gather`` the row-subsample
+``moments`` statistic, and ``repro.kernels.grid_stat`` ``monthly_mean``
+(the same draws as :func:`map_task`).  This module is the jnp engine of
+``alod`` and the oracle of both.
 """
 
 from __future__ import annotations

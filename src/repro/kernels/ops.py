@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import grid_stat as _gs
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import rwkv6_scan as _rw
 from repro.kernels import subsample_gather as _sg
@@ -97,3 +98,14 @@ def subsample_stats(data, indices):
     slots = jnp.arange(data.shape[0], dtype=jnp.int32)
     stats = subsample_stats_lanes(_sg.to_lane_layout(data), slots, indices)
     return stats.reshape(*stats.shape[:2], -1)[..., :d]
+
+
+def grid_stat_lanes(data, months, slots, indices, *, grid):
+    """The platform's ``monthly_mean`` wave kernel on blocks in the lane
+    layout: ratings and months ``[S, N, L, 128]`` + ``slots [B]`` +
+    ``indices [B, N, D]`` (drawn columns of each row) → per-task
+    ``[B, 2, grid]`` (sum of the drawn ratings, count of draws) per
+    month bin.  Not jitted: the platform's wave programs trace it inside
+    their own jit (and inside ``shard_map``)."""
+    return _lowered(_gs.grid_stat_wave, data, months, slots, indices,
+                    grid=grid)

@@ -7,8 +7,12 @@ backends bit-identical for a fixed seed):
   ``pallas``  — the Pallas ``subsample_stats_wave`` kernel (per-row HBM→VMEM
                 DMAs + moment accumulation) for the row-subsampling
                 ``moments`` statistic; compiled on TPU, interpreted on CPU.
-  ``jnp``     — the jitted ``repro.core.subsample.map_task`` engine for the
-                paper workloads (ALOD / monthly means).
+  ``jnp``     — the paper workloads: ``monthly_mean`` in the Pallas
+                ``grid_stat_wave`` kernel (the rows read in place, the
+                draws selected on the MXU), ``alod`` in the jitted
+                ``repro.core.subsample.map_task``.  The statistic picks
+                the path (:func:`grid_kernel`); both draw the same
+                indices.
   ``numpy``   — pure-NumPy path, only when asked for by name.  Mirrors
                 the jnp semantics but draws indices from NumPy's RNG, so
                 it is statistically — not bitwise — equivalent to ``jnp``.
@@ -20,8 +24,8 @@ compilation is startup cost (thesis Fig 5), never a per-task cost.
 Wave execution (DESIGN.md §7): a *wave* is a batch of same-shape ready
 tasks executed in ONE device dispatch.  :class:`BlockArena` packs the
 job's padded blocks into a device-resident ``[n_tasks, count, len]`` array
-per distinct shape (uploaded once; the ``pallas`` engine's arena is kept
-in the kernel's ``[n_tasks, count, len/128, 128]`` lane layout);
+per distinct shape (uploaded once; the kernels' arenas are kept in their
+``[n_tasks, count, len/128, 128]`` lane layout);
 :func:`run_map_wave` folds per-task seeds in with ``jax.vmap`` / a batched Pallas grid so one compiled kernel
 serves the whole wave.  Per-task accumulation order is independent of the
 wave partition, so wave and per-task execution are bit-identical for a
@@ -83,6 +87,8 @@ class DispatchStats:
     # were already in flight when their wave executed vs fetched inline
     prefetch_hits: int = 0
     prefetch_misses: int = 0
+    # tasks whose partial came from the grid-statistic kernel
+    grid_kernel_tasks: int = 0
 
     @classmethod
     def bounded(cls, max_wave_history: int) -> "DispatchStats":
@@ -114,15 +120,35 @@ def wave_profitable(workload) -> bool:
 
 
 def resolve_engine(statistic: str, prefer: str = "auto") -> str:
-    """Pick the compute engine once per job (never per task)."""
+    """Pick the compute engine once per job (never per task).  Engine
+    ``pallas`` is the row-subsample ``moments`` kernel; the grid
+    statistics run on engine ``jnp``, which computes ``monthly_mean`` in
+    its own Pallas kernel (:func:`grid_kernel`)."""
     if prefer != "auto":
         if prefer == "pallas" and statistic != "moments":
             raise ValueError(
-                "engine 'pallas' computes the row-subsample 'moments' "
-                f"statistic; workload statistic is {statistic!r} — use "
-                "engine 'jnp' (or 'auto')")
+                "engine 'pallas' is the row-subsample 'moments' kernel; "
+                f"workload statistic is {statistic!r} — use engine 'jnp' "
+                "(or 'auto'), which computes 'monthly_mean' in the Pallas "
+                "grid kernel")
         return prefer
     return "pallas" if statistic == "moments" else "jnp"
+
+
+def grid_kernel(statistic: str, engine: str) -> bool:
+    """Whether a task's partial comes from the Pallas grid-statistic
+    kernel: ``monthly_mean`` on the ``jnp`` engine.  ``alod`` stays on
+    ``map_task``: its per-draw z-score needs each draw's own mean and
+    deviation before it bins."""
+    return engine == "jnp" and statistic == "monthly_mean"
+
+
+def arena_layout(statistic: str, engine: str) -> Tuple[bool, bool]:
+    """``(lanes, months)`` of a wave arena: the kernels read the lane
+    layout, and only the moments kernel has no months plane."""
+    if engine == "pallas":
+        return True, False
+    return grid_kernel(statistic, engine), True
 
 
 # ---------------------------------------------------------------------------
@@ -186,13 +212,14 @@ def build_block(samples: Dict[int, np.ndarray],
 # ---------------------------------------------------------------------------
 
 
-def _stack_blocks(tasks: Sequence, build: Callable, lanes: bool
+def _stack_blocks(tasks: Sequence, build: Callable, lanes: bool,
+                  with_months: bool
                   ) -> Tuple[np.ndarray, Optional[np.ndarray], int]:
     """Materialize ``build(task)`` for each task straight into one
     preallocated bucket array (no per-task copies held alongside it);
-    ``lanes`` stores the blocks in the kernel's lane layout and drops
-    the months plane, which the moments kernel never reads.  Returns
-    ``(data, months, block row length)``."""
+    ``lanes`` stores the blocks in the kernels' lane layout, and
+    ``with_months=False`` drops the months plane.  Returns ``(data,
+    months, block row length)``."""
     data = months = None
     width = 0
     for i, task in enumerate(tasks):
@@ -200,9 +227,10 @@ def _stack_blocks(tasks: Sequence, build: Callable, lanes: bool
         width = block.shape[-1]
         if lanes:
             block = to_lane_layout(block)
+            mo = to_lane_layout(mo) if with_months else None
         if data is None:
             data = np.empty((len(tasks),) + block.shape, block.dtype)
-            if not lanes:
+            if with_months:
                 months = np.empty((len(tasks),) + mo.shape, mo.dtype)
         data[i] = block
         if months is not None:
@@ -213,9 +241,9 @@ def _stack_blocks(tasks: Sequence, build: Callable, lanes: bool
 class BlockArena:
     """The job's padded task blocks, packed per distinct block shape into
     one ``[n_tasks, count, len]`` array and uploaded to the device ONCE.
-    For the ``pallas`` engine (``lanes=True``) each bucket is stored in
-    the wave kernel's lane layout ``[n_tasks, count, len/128, 128]``, so
-    a wave hands the kernel the arena's own rows with no relayout.
+    For the kernels (``lanes=True``) each bucket is stored in their lane
+    layout ``[n_tasks, count, len/128, 128]``, so a wave hands a kernel
+    the arena's own rows with no relayout.
 
     Per-task execution re-uploads every block; the arena replaces that
     with a single upload plus a device-side row gather per wave (the slot
@@ -232,12 +260,13 @@ class BlockArena:
 
     @classmethod
     def pack(cls, tasks: Sequence, shape_key: Callable, build: Callable,
-             lanes: bool = False) -> "BlockArena":
+             lanes: bool = False, months: bool = True) -> "BlockArena":
         """Bucket ``tasks`` by ``shape_key``, materialize each task's
         padded block via ``build(task) -> (block, months)``, stack each
-        bucket and upload it once.  ``lanes=True`` packs for the moments
-        kernel: lane layout, and no months plane (the kernel never reads
-        it — packing it would double the upload and skew
+        bucket and upload it once, laid out as :func:`arena_layout`
+        says: ``lanes=True`` in the kernels' lane layout, and
+        ``months=False`` without the months plane (the moments kernel
+        never reads it — packing it would double the upload and skew
         ``bytes_uploaded``)."""
         import jax.numpy as jnp
 
@@ -246,7 +275,7 @@ class BlockArena:
         for task in tasks:
             buckets.setdefault(shape_key(task), []).append(task)
         for key, group in buckets.items():
-            arena._put(key, *_stack_blocks(group, build, lanes),
+            arena._put(key, *_stack_blocks(group, build, lanes, months),
                        upload=jnp.asarray)
             for row, task in enumerate(group):
                 arena._slot[task.task_id] = (key, row)
@@ -337,7 +366,8 @@ class ShardedBlockArena(BlockArena):
 
     @classmethod
     def pack(cls, tasks: Sequence, shape_key: Callable, build: Callable,
-             mesh=None, lanes: bool = False) -> "ShardedBlockArena":
+             mesh=None, lanes: bool = False,
+             months: bool = True) -> "ShardedBlockArena":
         assert mesh is not None, "ShardedBlockArena.pack needs a mesh"
         import jax
 
@@ -356,7 +386,7 @@ class ShardedBlockArena(BlockArena):
             order = [unshard_slot(dev, local, n_dev) % b
                      for dev in range(n_dev) for local in range(per_dev)]
             arena._put(key, *_stack_blocks([group[i] for i in order],
-                                            build, lanes),
+                                            build, lanes, months),
                        upload=lambda x: jax.device_put(x, sharding))
             arena._per_dev[key] = per_dev
             for i, task in enumerate(group):
@@ -393,6 +423,9 @@ def run_map_task(block: np.ndarray, months: np.ndarray, seed: int,
     The device engines time their stages in host spans of ``clock``:
     upload, dispatch, device wait, result fetch.
     """
+    if grid_kernel(workload.statistic, engine):
+        return _grid_task(block, months, seed, workload,
+                          clock or tel.SpanClock())
     if engine == "jnp":
         return _map_task_jnp(block, months, seed, workload,
                              clock or tel.SpanClock())
@@ -460,6 +493,28 @@ def _moments_pallas(block: np.ndarray, seed: int, workload,
         return _split_moments(np.asarray(stats), n_idx, block.shape[-1])[0]
 
 
+def _grid_task(block: np.ndarray, months: np.ndarray, seed: int,
+               workload, clock: tel.SpanClock) -> Dict[str, np.ndarray]:
+    """``monthly_mean`` of one task through the grid kernel, as a wave
+    of one: the same program as :func:`run_map_wave`, so per-task and
+    wave execution agree to the last bit for the same per-task seed."""
+    import jax
+    import jax.numpy as jnp
+
+    with clock.span("platform.upload"):
+        args = (jnp.asarray(to_lane_layout(block)[None]),
+                jnp.asarray(to_lane_layout(months)[None]),
+                jnp.asarray(np.zeros(1, np.int32)),
+                jnp.asarray(np.asarray([seed], np.int32)))
+    with clock.span("platform.dispatch"):
+        out = _wave_fn("grid")(*args, **_grid_static(workload,
+                                                     block.shape[-1]))
+    out = _wait(clock, args, out)
+    clock.grid_kernel_tasks += 1
+    with clock.span("platform.fetch_result"):
+        return jax.tree.map(lambda a: np.asarray(a)[0], out)
+
+
 def _split_moments(stats: np.ndarray, n_idx: int,
                    width: int) -> List[Dict[str, np.ndarray]]:
     """``[B, 2, L, 128]`` lane-layout kernel stats → per-task
@@ -495,9 +550,10 @@ def _moments_wave_jit():
 
 
 def _jnp_wave_jit():
-    """Module-cached jitted wave for the jnp engine: ``jax.vmap`` over the
-    jitted ``subsample.map_task`` with per-task PRNG keys derived in-graph
-    — bit-identical to per-task calls for the same seeds."""
+    """Module-cached jitted wave for the jnp engine's ``map_task``
+    statistics (``alod``): ``jax.vmap`` over the jitted
+    ``subsample.map_task`` with per-task PRNG keys derived in-graph —
+    bit-identical to per-task calls for the same seeds."""
     import functools
 
     import jax
@@ -517,6 +573,51 @@ def _jnp_wave_jit():
             statistic=statistic))(data, months, keys)
 
     return jnp_wave
+
+
+def _grid_static(workload, width: int) -> Dict[str, int]:
+    """The grid wave's static arguments: the workload's draws and grid,
+    and the blocks' row length, which bounds the drawn columns."""
+    return dict(draws=workload.draws, draw_size=workload.draw_size,
+                grid=workload.grid, width=width)
+
+
+def _grid_draws(seeds, ns: int, draws: int, draw_size: int, width: int):
+    """``map_task``'s draws of each task, ``[B, ns, draws·draw_size]``:
+    row ``n``'s drawn columns, draw by draw."""
+    import jax
+    import jax.numpy as jnp
+
+    idx = jax.vmap(lambda s: jax.random.randint(
+        jax.random.PRNGKey(s), (draws, ns, draw_size), 0, width))(seeds)
+    return jnp.swapaxes(idx, 1, 2).reshape(len(seeds), ns, -1)
+
+
+def _grid_partials(out) -> Dict[str, Any]:
+    """Grid kernel output ``[B, 2, grid]`` → the ``map_task`` partials."""
+    return {"sum": out[:, 0], "count": out[:, 1]}
+
+
+def _grid_wave_jit():
+    """Module-cached jitted ``monthly_mean`` wave: per-task draws derived
+    in-graph (the same threefry indices as ``map_task``) → the Pallas
+    grid kernel, which reads each task's rows straight out of the
+    resident lane-layout arena at the task's slot."""
+    import functools
+
+    import jax
+
+    from repro.kernels import ops
+
+    @functools.partial(jax.jit, static_argnames=("draws", "draw_size",
+                                                 "grid", "width"))
+    def grid_wave(arena, arena_mo, rows, seeds, *, draws, draw_size, grid,
+                  width):
+        idx = _grid_draws(seeds, arena.shape[1], draws, draw_size, width)
+        return _grid_partials(ops.grid_stat_lanes(arena, arena_mo, rows,
+                                                  idx, grid=grid))
+
+    return grid_wave
 
 
 def _moments_wave_sharded_jit(mesh):
@@ -582,6 +683,39 @@ def _jnp_wave_sharded_jit(mesh):
     return jnp_wave_sharded
 
 
+def _grid_wave_sharded_jit(mesh):
+    """Sharded ``monthly_mean`` wave: the per-device body is the SAME
+    pipeline as :func:`_grid_wave_jit` on the local slots, under one
+    ``shard_map`` (``check_vma=False``: Pallas has no varying-manual-axes
+    rule)."""
+    import functools
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import ops
+
+    @functools.partial(jax.jit, static_argnames=("draws", "draw_size",
+                                                 "grid", "width"))
+    def grid_wave_sharded(arena, arena_mo, rows, seeds, *, draws,
+                          draw_size, grid, width):
+        def per_device(a, m, r, s):
+            idx = _grid_draws(s[0], a.shape[1], draws, draw_size, width)
+            out = ops.grid_stat_lanes(a, m, r[0], idx, grid=grid)
+            return jax.tree.map(lambda x: x[None], _grid_partials(out))
+        return jax.shard_map(per_device, mesh=mesh,
+                             in_specs=(P("wave"),) * 4,
+                             out_specs=P("wave"), check_vma=False)(
+            arena, arena_mo, rows, seeds)
+
+    return grid_wave_sharded
+
+
+_WAVE_JITS = {"moments": _moments_wave_jit, "grid": _grid_wave_jit,
+              "jnp": _jnp_wave_jit}
+_SHARDED_WAVE_JITS = {"moments": _moments_wave_sharded_jit,
+                      "grid": _grid_wave_sharded_jit,
+                      "jnp": _jnp_wave_sharded_jit}
 _WAVE_FNS: Dict[str, Any] = {}
 _SHARDED_WAVE_FNS: Dict[Tuple[str, Any], Any] = {}
 
@@ -591,8 +725,7 @@ def _wave_fn(kind: str):
     cache persists across calls (a per-call jit would retrace every
     wave — exactly the per-task overhead waves exist to remove)."""
     if kind not in _WAVE_FNS:
-        _WAVE_FNS[kind] = (_moments_wave_jit() if kind == "moments"
-                           else _jnp_wave_jit())
+        _WAVE_FNS[kind] = _WAVE_JITS[kind]()
     return _WAVE_FNS[kind]
 
 
@@ -602,10 +735,17 @@ def _sharded_wave_fn(kind: str, mesh):
     1-D wave mesh reuses the cached shard_map-wrapped jit."""
     key = (kind, mesh)
     if key not in _SHARDED_WAVE_FNS:
-        _SHARDED_WAVE_FNS[key] = (
-            _moments_wave_sharded_jit(mesh) if kind == "moments"
-            else _jnp_wave_sharded_jit(mesh))
+        _SHARDED_WAVE_FNS[key] = _SHARDED_WAVE_JITS[kind](mesh)
     return _SHARDED_WAVE_FNS[key]
+
+
+def _jnp_wave_kind(workload, width: int) -> Tuple[str, Dict[str, Any]]:
+    """The ``jnp`` engine's wave program for ``workload`` and its static
+    arguments: the grid kernel for ``monthly_mean``, else ``map_task``."""
+    if grid_kernel(workload.statistic, "jnp"):
+        return "grid", _grid_static(workload, width)
+    return "jnp", dict(draws=workload.draws, draw_size=workload.draw_size,
+                       grid=workload.grid, statistic=workload.statistic)
 
 
 def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
@@ -659,14 +799,13 @@ def run_map_wave(arena: BlockArena, tasks: Sequence, seeds: np.ndarray,
         with clock.span("platform.fetch_result"):
             return _split_moments(np.asarray(stats)[:b], n_idx,
                                   arena.width(key))
-    assert months is not None, "jnp waves need pack(lanes=False)"
+    assert months is not None, "jnp waves need their months plane"
+    kind, static = _jnp_wave_kind(workload, arena.width(key))
     with clock.span("platform.dispatch"):
-        out = _wave_fn("jnp")(data, months, rows, seeds,
-                              draws=workload.draws,
-                              draw_size=workload.draw_size,
-                              grid=workload.grid,
-                              statistic=workload.statistic)
+        out = _wave_fn(kind)(data, months, rows, seeds, **static)
     out = _wait(clock, (rows, seeds), out)
+    if kind == "grid":
+        clock.grid_kernel_tasks += b
     with clock.span("platform.fetch_result"):
         out = jax.tree.map(np.asarray, out)
         return [jax.tree.map(lambda a: a[i], out) for i in range(b)]
@@ -742,13 +881,14 @@ def run_map_wave_sharded(arena: ShardedBlockArena, tasks: Sequence,
             stats = col.gather_shards(out)
             picked = np.stack([stats[d, lane] for d, lane in place])
             return _split_moments(picked, n_idx, arena.width(key))
-    assert months is not None, "jnp waves need pack(lanes=False)"
+    assert months is not None, "jnp waves need their months plane"
+    kind, static = _jnp_wave_kind(workload, arena.width(key))
     with clock.span("platform.dispatch"):
-        out = _sharded_wave_fn("jnp", arena.mesh)(
-            data, months, rows_dev, seeds_dev, draws=workload.draws,
-            draw_size=workload.draw_size, grid=workload.grid,
-            statistic=workload.statistic)
+        out = _sharded_wave_fn(kind, arena.mesh)(
+            data, months, rows_dev, seeds_dev, **static)
     out = _wait(clock, (rows_dev, seeds_dev), out)
+    if kind == "grid":
+        clock.grid_kernel_tasks += b
     with clock.span("platform.fetch_result"):
         host = jax.tree.map(col.gather_shards, out)  # leaves [n_dev, w, ...]
         return [jax.tree.map(lambda a, d=d, lane=lane: np.asarray(a[d, lane]),

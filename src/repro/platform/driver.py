@@ -519,14 +519,15 @@ def build_wave_context(plan: JobPlan, workload, *, n_exec: int,
     mesh-invariant for the epsilon early-stop path to settle at the
     same task counts on every mesh size; only the per-device kernel
     width inside the sharded dispatch varies with the mesh."""
+    lanes, months = pc.arena_layout(workload.statistic, plan.engine)
     if mesh is not None:
         arena: pc.BlockArena = pc.ShardedBlockArena.pack(
             plan.tasks, plan.task_shape, plan.build_block, mesh,
-            lanes=(plan.engine == "pallas"))
+            lanes=lanes, months=months)
     else:
         arena = pc.BlockArena.pack(plan.tasks, plan.task_shape,
-                                   plan.build_block,
-                                   lanes=(plan.engine == "pallas"))
+                                   plan.build_block, lanes=lanes,
+                                   months=months)
     by_key: Dict[Any, List[sch.Task]] = {}
     for task in plan.tasks:
         by_key.setdefault(plan.task_shape(task), []).append(task)

@@ -927,6 +927,7 @@ class PlatformService:
                     wave=next(self._wave_seq))
                 t_wave = self.telemetry.now()
                 values = qc.wave_ctx.run(tasks, seeds, clock=clock)
+                self._count_kernel_tasks(clock)
                 nbytes = qc.wave_ctx.wave_bytes(len(items))
                 self.telemetry.emit(
                     "wave_dispatched", ts=t_wave, wave_size=len(items),
@@ -968,11 +969,17 @@ class PlatformService:
                                            pj.seed + task.task_id,
                                            qc.workload, qc.engine,
                                            clock=clock))
+                self._count_kernel_tasks(clock)
                 t = self._tickets.get(pj.job_id)
                 if t is not None:
                     t.charge(clock.seconds)
             return out
         return run_batch
+
+    def _count_kernel_tasks(self, clock: tel.SpanClock) -> None:
+        if clock.grid_kernel_tasks:
+            with self._stats_lock:
+                self.dispatch.grid_kernel_tasks += clock.grid_kernel_tasks
 
     # -- completion fan-in ---------------------------------------------------
     def _on_job_done(self, ticket: JobTicket) -> None:
@@ -1202,6 +1209,7 @@ class PlatformService:
                 "device_dispatches": self.dispatch.device_dispatches,
                 "bytes_uploaded": self.dispatch.bytes_uploaded,
                 "wave_sizes": waves,
+                "grid_kernel_tasks": self.dispatch.grid_kernel_tasks,
             }
         if pool is not None:
             out["fused_dispatches"] = pool.sched.fused_dispatches

@@ -134,12 +134,14 @@ class SpanClock:
     active or not — the spans' aggregation path, as ``_aggregate`` is
     the events'.  One thread enters a clock's spans at a time.  A wave
     also records in :attr:`lanes` the lanes it dispatched, padding
-    included."""
+    included, and a unit of work records in :attr:`grid_kernel_tasks`
+    the tasks whose partial the grid-statistic kernel computed."""
 
     def __init__(self, **ids: Any):
         self.ids = ids
         self.seconds: Dict[str, float] = {}
         self.lanes = 0
+        self.grid_kernel_tasks = 0
 
     @contextlib.contextmanager
     def span(self, name: str):

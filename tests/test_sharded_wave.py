@@ -154,7 +154,8 @@ def test_sharded_arena_physical_layout(mesh_devices):
                     engine="pallas", n_exec=2, knee_bytes=1024.0)
     mesh = make_wave_mesh(4)
     arena = pc.ShardedBlockArena.pack(plan.tasks, plan.task_shape,
-                                      plan.build_block, mesh, lanes=True)
+                                      plan.build_block, mesh, lanes=True,
+                                      months=False)
     for key in arena.keys():
         data = np.asarray(arena.bucket(key)[0])
         per_dev = arena._per_dev[key]
@@ -166,6 +167,30 @@ def test_sharded_arena_physical_layout(mesh_devices):
         want = to_lane_layout(plan.build_block(task)[0])
         got = np.asarray(arena.bucket(key)[0])[dev * per_dev + local]
         np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("workload,engaged", [(WL_NETFLIX, True),
+                                              (WL_EAGLET, False)],
+                         ids=["monthly_mean", "alod"])
+def test_sharded_grid_kernel_tasks(mesh_devices, workload, engaged):
+    """A sharded ``monthly_mean`` wave computes every task's partial in
+    the grid kernel, as ``stats()["grid_kernel_tasks"]`` counts; an
+    ``alod`` job's waves never do."""
+    from repro.platform.service import PlatformService
+
+    samples, months = _dataset(16, seed=5)
+    spec = PlatformSpec(platform="BTS", n_workers=2, backend="threaded",
+                        wave="on", knee_bytes=2048.0, mesh_devices=4)
+    with PlatformService(spec) as svc:
+        handle = svc.register_dataset(samples, months)
+        ticket = svc.submit(handle, workload)
+        ticket.result(timeout=120.0)
+        stats = svc.stats()
+    (qc,) = handle._classes.values()
+    assert isinstance(qc.wave_ctx.arena, pc.ShardedBlockArena)
+    assert ticket.n_tasks > 1
+    assert stats["grid_kernel_tasks"] == (ticket.n_tasks if engaged else 0)
 
 
 # ---------------------------------------------------------------------------

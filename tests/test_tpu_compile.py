@@ -109,6 +109,52 @@ def test_sharded_moments_wave_compiles(topo):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("tasks,width", [(171, 1 << 14), (105, 1 << 15),
+                                         (2, NETFLIX_W)])
+def test_grid_wave_program_fits(one_chip, tasks, width):
+    """The jitted ``monthly_mean`` wave over each Netflix shape bucket's
+    arena, in waves of 32: the grid kernel reads the rows in place, so
+    the program's temporaries stay far below one task block."""
+    shape = (tasks, 64, width // sg.LANES, sg.LANES)
+    arena = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    months = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    compiled = pc._wave_fn("grid").lower(
+        arena, months, vec, vec,
+        **pc._grid_static(ss.NETFLIX_LOW, width)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * width * 4
+
+
+@pytest.mark.parametrize("workload", [ss.NETFLIX_HIGH, ss.NETFLIX_LOW])
+def test_grid_task_program_compiles(one_chip, workload):
+    """A task as a wave of one (the per-task path), at the longest
+    movie's width, for both Netflix draw counts."""
+    shape = (1, 64, NETFLIX_W // sg.LANES, sg.LANES)
+    arena = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    months = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    compiled = pc._wave_fn("grid").lower(
+        arena, months, vec, vec,
+        **pc._grid_static(workload, NETFLIX_W)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_grid_wave_compiles(topo):
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("wave",))
+    wave = NamedSharding(mesh, P("wave"))
+    shape = (4 * 43, 64, (1 << 14) // sg.LANES, sg.LANES)
+    arena = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=wave)
+    months = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=wave)
+    vec = jax.ShapeDtypeStruct((4, 8), jnp.int32, sharding=wave)
+    compiled = pc._grid_wave_sharded_jit(mesh).lower(
+        arena, months, vec, vec,
+        **pc._grid_static(ss.NETFLIX_LOW, 1 << 14)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
 def test_chip_smoke_refuses_cpu():
     import chip_smoke
 
