@@ -204,7 +204,11 @@ def main(argv=None) -> int:
         devices = tracing.read_xplane(path).devices
         spans = read_spans(path)
         out = breakdown(devices, spans)
-        ops = tracing.summarize(tracing.TraceData(devices, spans))
+        # the benchmark's spans only: the operations need none, and
+        # summarize's attribution is gaps x spans in pure Python
+        ops = tracing.summarize(tracing.TraceData(
+            devices, [sp for sp in spans
+                      if sp[0].startswith(tracing.SPAN_PREFIX)]))
         if ops is not None:
             out["device_ops"] = ops.device_ops(6)
     finally:

@@ -16,9 +16,25 @@ from bench import data, harness  # noqa: E402
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 CELLS = tuple(c["name"] for c in json.loads(
     (ROOT / "BENCHMARK.json").read_text())["workloads"])
-TINY = {"netflix-prize": dict(n_movies=96, mean_ratings=48, min_ratings=8),
-        "eaglet-thesis": dict(n_families=40, mean_markers=256)}
 SAMPLES_PER_TASK = 16
+
+
+def _tiny_knots(name: str) -> dict:
+    """A knot-shaped configuration at 96 movies: every knot's count over
+    three (which keeps the fewest at one rating), and the total that the
+    scaled curve gives at that many movies."""
+    config = json.loads(
+        (ROOT / "bench" / "configs" / f"{name}.json").read_text())
+    knots = [[q, count / 3] for q, count in config["size_quantiles"]]
+    curve = data.knot_curve(knots, 96,
+                            np.random.default_rng(config["size_seed"]))
+    return dict(n_movies=96, size_quantiles=knots,
+                n_ratings=int(round(curve.sum())))
+
+
+TINY = {"netflix-prize": dict(n_movies=96, mean_ratings=48, min_ratings=8),
+        "eaglet-thesis": dict(n_families=40, mean_markers=256),
+        "netflix-prize-published": _tiny_knots("netflix-prize-published")}
 
 
 def tiny_overrides(config: dict) -> dict:
